@@ -16,7 +16,7 @@ COPY perceiver_io_tpu ./perceiver_io_tpu
 # TPU runtime: jax[tpu] pulls libtpu from the Google releases index.
 RUN pip install --no-cache-dir \
     --find-links https://storage.googleapis.com/jax-releases/libtpu_releases.html \
-    "jax[tpu]" \
+    "jax[tpu]==0.9.0" \
     && pip install --no-cache-dir ".[text,vision,audio]"
 
 COPY tests ./tests

@@ -26,7 +26,7 @@ fleet-chaos:
 elasticity:
 	$(PY) -m pytest tests/ -q -m elasticity --continue-on-collection-errors
 
-# flash-crowd elasticity A/B at the CPU-fallback shape (docs/serving.md
+# flash-crowd elasticity A/B at the reduced drill shape (docs/serving.md
 # "Elasticity"): the same deterministic FakeClock spike offered to a
 # static fleet and an autoscaled one — goodput-under-SLO both ways, the
 # scale-event timeline, zero-drop / token-identity / pool zero-leak pins
@@ -37,7 +37,7 @@ elasticity-bench:
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params']; \
 	print(json.dumps({'elasticity': bench._bench_elasticity(model, params, cfg)}, indent=2))"
@@ -81,7 +81,7 @@ timeline:
 slo:
 	$(PY) -m pytest tests/ -q -m slo --continue-on-collection-errors
 
-# goodput-under-SLO sweep at the CPU-fallback shape (docs/observability.md):
+# goodput-under-SLO sweep at the reduced drill shape (docs/observability.md):
 # offered-load sweep through the slot engine via the Poisson load generator,
 # printing p95 TTFT / p95 inter-token latency per point and the knee
 slo-bench:
@@ -91,7 +91,7 @@ slo-bench:
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params']; \
 	print(json.dumps({'slo_goodput': bench._bench_slo_goodput(model, params, cfg)}, indent=2))"
@@ -102,7 +102,7 @@ slo-bench:
 gateway:
 	$(PY) -m pytest tests/ -q -m gateway --continue-on-collection-errors
 
-# mid-stream mass-abandonment drill at the CPU-fallback shape
+# mid-stream mass-abandonment drill at the reduced drill shape
 # (docs/serving.md "Streaming"): scripted client abandonment against the
 # paged slot engine under FakeClock — cancelled-slot reclaim latency,
 # pool-page zero-leak, survivor token-identity
@@ -113,7 +113,7 @@ stream-bench:
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params']; \
 	print(json.dumps({'streaming': bench._bench_streaming(model, params, cfg)}, indent=2))"
@@ -136,7 +136,7 @@ cov:
 bench:
 	$(PY) bench.py
 
-# slots-vs-bucket serving A/B at the CPU-fallback shape (docs/serving.md):
+# slots-vs-bucket serving A/B at the reduced drill shape (docs/serving.md):
 # mixed prompt lengths + heterogeneous max_new_tokens through both engines,
 # printing the tokens/s ratio, slot occupancy, and padding-waste split
 serve-bench:
@@ -147,12 +147,12 @@ serve-bench:
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
 	from perceiver_io_tpu.inference import cast_float_params; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = cast_float_params(model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params'], jnp.bfloat16); \
 	print(json.dumps({'serve_ab': bench._bench_serve_ab(model, params, cfg)}, indent=2))"
 
-# dense-vs-paged KV layout A/B at the CPU-fallback shape (docs/serving.md
+# dense-vs-paged KV layout A/B at the reduced drill shape (docs/serving.md
 # "Block-paged KV"): a long-tail mixed-context workload through both slot
 # layouts at ONE simulated HBM budget, printing max concurrent residents,
 # the ratio, tokens/s, and the pool's page-utilization stats
@@ -163,7 +163,7 @@ paged-bench:
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params']; \
 	print(json.dumps({'paged_kv': bench._bench_paged_kv(model, params, cfg)}, indent=2))"
@@ -175,7 +175,7 @@ paged-bench:
 quant-kv:
 	$(PY) -m pytest tests/ -q -m quant_kv --continue-on-collection-errors
 
-# exact-vs-int8 paged-KV A/B at the CPU-fallback shape (docs/serving.md
+# exact-vs-int8 paged-KV A/B at the reduced drill shape (docs/serving.md
 # "Quantized KV"): ONE simulated HBM budget, residents-per-HBM-byte
 # ratio, tokens/s, greedy token-match rate, quality-gate verdict
 quant-bench:
@@ -185,7 +185,7 @@ quant-bench:
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params']; \
 	print(json.dumps({'quant_kv': bench._bench_quant_kv(model, params, cfg)}, indent=2))"
@@ -198,7 +198,7 @@ quant-bench:
 prefix-cache:
 	$(PY) -m pytest tests/ -q -m prefix_cache --continue-on-collection-errors
 
-# prefix-sharing A/B at the CPU-fallback shape (docs/serving.md "Prefix
+# prefix-sharing A/B at the reduced drill shape (docs/serving.md "Prefix
 # sharing"): Zipf-distributed shared prefixes through the paged slot
 # engine, unshared vs COW-shared at ONE simulated HBM budget — TTFT
 # p50/p95 ratio, residents-per-HBM-byte, hit ratio, token identity
@@ -209,7 +209,7 @@ prefix-bench:
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params']; \
 	print(json.dumps({'prefix_cache': bench._bench_prefix_cache(model, params, cfg)}, indent=2))"
@@ -223,7 +223,7 @@ prefix-bench:
 preemption:
 	$(PY) -m pytest tests/ -q -m preemption --continue-on-collection-errors
 
-# strict-vs-optimistic admission A/B at the CPU-fallback shape
+# strict-vs-optimistic admission A/B at the reduced drill shape
 # (docs/serving.md "Preemption & priorities"): long-tail declared-max_new
 # workload at ONE simulated HBM budget — max-resident ratio, residents
 # per HBM byte, goodput-under-SLO both ways, preemption/readmission
@@ -235,7 +235,7 @@ preempt-bench:
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
 	from perceiver_io_tpu.models.text.clm import CausalLanguageModel; \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	model = CausalLanguageModel(cfg); \
 	params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, cfg.max_seq_len), jnp.int32), cfg.max_seq_len - cfg.max_latents)['params']; \
 	print(json.dumps({'preemption': bench._bench_preemption(model, params, cfg)}, indent=2))"
@@ -254,7 +254,7 @@ swap:
 # crossover length where paying transfer beats paying recompute, greedy
 # token-identity vs an unpressured baseline, and the model honesty bars
 # (predicted vs realized advantage sign, auto never picks the worse arm).
-# The CPU lane runs a REDUCED shape (512 ctx), not CPU_SHAPE: the pool
+# The CPU lane runs a REDUCED shape (512 ctx), not DRILL_SHAPE: the pool
 # budget is denominated in full-context slots, so at 2048 ctx a sweep
 # with genuine exhaustion pressure needs 200+-token decodes per request
 # and the recompute arm's replay churn makes the lane hours-scale on
@@ -293,7 +293,7 @@ spec-bench:
 	import importlib.util; \
 	spec = importlib.util.spec_from_file_location('bench', 'bench.py'); \
 	bench = importlib.util.module_from_spec(spec); spec.loader.exec_module(bench); \
-	cfg = bench._mk_config(bench.CPU_SHAPE); \
+	cfg = bench._mk_config(bench.DRILL_SHAPE); \
 	print(json.dumps({'speculative': bench._bench_speculative(None, None, cfg)}, indent=2))"
 
 # sharded serving-runtime suite (docs/serving.md "Sharded serving"):

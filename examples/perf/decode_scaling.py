@@ -11,8 +11,9 @@ generated token pinned into ONE cache phase (``--phase``):
 Under the static right-aligned window formulation both paths' per-token cost
 is a function of the *window* size ``n = max_seq_len`` (left pads are
 computed and masked), so the scaling axis is context length, not prompt
-length. Prints one JSON line per point and a markdown table suitable for
-``docs/benchmarks.md``.
+length. Runs on the backend ``JAX_PLATFORMS`` selects (bf16 on a TPU, the
+default float32 elsewhere) and names it in every point. Prints one JSON line
+per point and a markdown table suitable for ``docs/benchmarks.md``.
 
 Boundary-phase points also feed the decode-strategy registry
 (``inference/decode_strategy.py``): each point records the autotuner's
@@ -35,7 +36,6 @@ Usage::
     python examples/perf/decode_scaling.py                  # boundary, 1k->8k
     python examples/perf/decode_scaling.py --phase latent   # the cache's win
     python examples/perf/decode_scaling.py --ctxs 1024 2048 # subset
-    python examples/perf/decode_scaling.py --tpu            # real chip
     python examples/perf/decode_scaling.py --emit-strategy strategy.json
 """
 from __future__ import annotations
@@ -58,8 +58,6 @@ def main() -> None:
     p.add_argument("--num-heads", type=int, default=8)
     p.add_argument("--batch", type=int, default=1)
     p.add_argument("--new-tokens", type=int, default=8)
-    p.add_argument("--tpu", action="store_true",
-                   help="run on the default accelerator backend (else force CPU)")
     p.add_argument(
         "--phase", choices=["boundary", "latent"], default="boundary",
         help="which cache phase every generated token lands in: 'boundary' "
@@ -94,10 +92,6 @@ def main() -> None:
         )
 
     import jax
-
-    if not args.tpu:
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
     import numpy as np
 
@@ -110,6 +104,7 @@ def main() -> None:
     )
 
     platform = jax.default_backend()
+    on_tpu = platform == "tpu"
     rows = []
     for ctx in args.ctxs:
         cfg = CausalLanguageModelConfig(
@@ -120,13 +115,13 @@ def main() -> None:
             num_heads=args.num_heads,
             num_self_attention_layers=args.num_layers,
         )
-        model = CausalLanguageModel(cfg, dtype=jnp.bfloat16 if args.tpu else None)
+        model = CausalLanguageModel(cfg, dtype=jnp.bfloat16 if on_tpu else None)
         rng = np.random.default_rng(0)
         prefix_len = ctx - args.num_latents
         params = model.init(
             jax.random.PRNGKey(0), jnp.zeros((1, ctx), jnp.int32), prefix_len
         )["params"]
-        if args.tpu:
+        if on_tpu:
             params = cast_float_params(params, jnp.bfloat16)
 
         # Both phases keep the prompt near the window so the recompute path

@@ -955,8 +955,9 @@ def autotune_speculation(
 
 
 def main(argv=None) -> dict:
-    """``make decode-tune``: run the autotune probe on a CLM shape (CPU by
-    default) and print the verdict + measurements as one JSON line."""
+    """``make decode-tune``: run the autotune probe on a CLM shape, on the
+    backend ``JAX_PLATFORMS`` selects, and print the verdict + measurements
+    (with the platform they were taken on) as one JSON line."""
     import argparse
 
     p = argparse.ArgumentParser(description=main.__doc__)
@@ -969,15 +970,9 @@ def main(argv=None) -> dict:
     p.add_argument("--new-tokens", type=int, default=4)
     p.add_argument("--out", default=None,
                    help="persist the registry JSON artifact here")
-    p.add_argument("--tpu", action="store_true",
-                   help="run on the default accelerator backend (else force CPU)")
     args = p.parse_args(argv)
 
     import jax
-
-    if not args.tpu:
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
 
     from perceiver_io_tpu.models.text.clm import (

@@ -44,15 +44,16 @@ Registry families fed (docs/observability.md):
   slot engine publishes at construction (everywhere, device stats or not).
 
 Failure containment: observation must never change execution semantics. If
-AOT compile fails (a backend without AOT support) or the compiled dispatch
-rejects the call signature (``TypeError`` — AOT executables are
-shape/dtype/weak-type strict), the wrapper permanently falls back to the
-plain jitted callable for that executor and counts
+the wrapped callable cannot be lowered (it is not a jitted function) or the
+compiled dispatch rejects the call signature (``TypeError`` — AOT
+executables are shape/dtype/weak-type strict), the wrapper permanently falls
+back to the plain callable for that executor and counts
 ``compile_ledger_fallback_total`` — the run proceeds exactly as before the
-ledger existed, minus one row of attribution. Genuine *execution* errors
-(device OOM, XLA runtime failures) re-raise untouched: retrying a dispatch
-that may already have consumed donated buffers would mask the real
-failure.
+ledger existed, minus one row of attribution. A *compile* error raises: the
+plain jitted callable would only compile the same program a second time.
+Genuine *execution* errors (device OOM, XLA runtime failures) re-raise
+untouched: retrying a dispatch that may already have consumed donated
+buffers would mask the real failure.
 
 Determinism: with an injected clock (``reliability.FakeClock``) the ledger's
 records — ordering, sequence numbers, retrace reasons — are a pure function
@@ -75,9 +76,10 @@ def _sanitize_reason(name: str) -> str:
 class LedgeredExecutor:
     """A jitted executor whose first call is AOT-lowered, compiled, timed,
     and cost/memory-analyzed into the owning ledger; later calls dispatch
-    the compiled executable directly. Any AOT failure (lowering, analysis
-    mismatch, strict-signature drift) permanently falls back to the plain
-    jitted callable — observation never fails the computation."""
+    the compiled executable directly. A callable with nothing to lower, or a
+    call the compiled executable's strict signature rejects, permanently
+    falls back to the plain callable and is counted; a compile error
+    raises."""
 
     __slots__ = ("_fn", "_compiled", "_ledger", "_entry", "_fallback", "_lock")
 
@@ -116,15 +118,23 @@ class LedgeredExecutor:
                     self._ledger._count_fallback(self._entry)
         return self._fn(*args, **kwargs)
 
+    def compiled_text(self) -> Optional[str]:
+        """The compiled program's text (what ``chip_smoke.py`` searches for
+        the Mosaic kernel); None before the first call or after a demotion."""
+        compiled = self._compiled
+        return None if compiled is None else compiled.as_text()
+
     def _aot_compile(self, *args, **kwargs) -> None:
-        clock = self._ledger._clock
-        t0 = clock()
-        try:
-            compiled = self._fn.lower(*args, **kwargs).compile()
-        except Exception:
+        lower = getattr(self._fn, "lower", None)
+        if lower is None:  # a plain callable: nothing to compile ahead of time
             self._fallback = True
             self._ledger._count_fallback(self._entry)
             return
+        clock = self._ledger._clock
+        t0 = clock()
+        # a compile error is the computation's own failure: it raises here
+        # rather than be retried (and hidden) by the plain jitted callable
+        compiled = lower(*args, **kwargs).compile()
         compile_ms = (clock() - t0) * 1e3
         self._compiled = compiled
         cost = _cost_summary(compiled)
@@ -133,14 +143,12 @@ class LedgeredExecutor:
 
 
 def _cost_summary(compiled) -> Dict[str, Optional[float]]:
-    """``cost_analysis()`` across jax versions returns a dict or a 1-list of
-    dicts; normalize to {flops, bytes_accessed} (None when unavailable)."""
+    """``cost_analysis()`` -> {flops, bytes_accessed} (None when the backend
+    reports nothing)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return {"flops": None, "bytes_accessed": None}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return {"flops": None, "bytes_accessed": None}
     flops = ca.get("flops")

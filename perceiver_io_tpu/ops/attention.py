@@ -15,17 +15,27 @@ TPU-first design notes:
 - ``impl='flash'`` dispatches to the Pallas flash kernel
   (:mod:`perceiver_io_tpu.ops.flash_attention`) when shapes permit;
   ``impl='xla'`` is the reference-semantics einsum path. ``'auto'`` picks
-  flash on TPU for long sequences.
+  flash on TPU. A shape with at least one block of query rows that the
+  kernel refuses is counted (``attention_einsum_fallback_total`` on the
+  default registry) and warned about once, so the einsum path never stands
+  in for the kernel in silence; shorter queries (single-token decode steps)
+  are the einsum path's by design.
+- Mosaic kernels are not partitioned automatically, so under a mesh with
+  more than one device the flash call runs inside ``jax.shard_map``: batch
+  over ``data``/``fsdp``, heads over ``model``.
 - ``impl='ring'`` dispatches to ring attention
   (:mod:`perceiver_io_tpu.parallel.ring`): q and k/v sequence dims are
   sharded over the ambient mesh's ``seq`` axis and k/v chunks rotate via
   ``ppermute`` — context parallelism for sequences one device cannot hold.
-  Requires an active ``Mesh`` context with a ``seq`` axis (the trainer's
-  ``shard_seq`` path provides one).
+
+The ambient mesh is the one ``jax.sharding.get_abstract_mesh()`` reports.
+``make_train_step``/``make_eval_step`` and the sharded slot engine publish
+theirs (``jax.sharding.use_abstract_mesh``) while they trace; other callers
+wrap the call in ``jax.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
-import functools
+import math
 from typing import Optional
 
 import jax
@@ -75,10 +85,10 @@ def dot_product_attention(
             import warnings
 
             warnings.warn(
-                "impl='ring' without an active Mesh with a 'seq' axis of "
+                "impl='ring' without an ambient mesh with a 'seq' axis of "
                 "size > 1 — falling back to the XLA einsum path; wrap the "
-                "call in `with make_mesh(MeshConfig(seq=...)):` for "
-                "sequence-parallel execution",
+                "call in `with jax.set_mesh(make_mesh(MeshConfig(seq=...))):` "
+                "for sequence-parallel execution",
                 UserWarning,
                 stacklevel=2,
             )
@@ -89,21 +99,19 @@ def dot_product_attention(
                 q, k, v, mesh, axis_name="seq", pad_mask=pad_mask, causal=causal
             )
 
-    use_flash = False
     if impl == "flash" or (impl == "auto" and _flash_eligible(q, k, v, dropout_rate)):
         from perceiver_io_tpu.ops import flash_attention
 
         if impl == "flash" and dropout_rate > 0.0:
             raise ValueError("flash attention does not support attention dropout")
-        use_flash = flash_attention.supported(q, k, v, causal=causal)
-        if impl == "flash" and not use_flash:
+        if flash_attention.supported(q, k, v, causal=causal):
+            return _flash_over_mesh(q, k, v, pad_mask, causal)
+        if impl == "flash":
             raise ValueError(
                 f"flash attention requested but unsupported for shapes q={q.shape} k={k.shape}"
             )
-    if use_flash:
-        from perceiver_io_tpu.ops import flash_attention
-
-        return flash_attention.flash_attention(q, k, v, pad_mask=pad_mask, causal=causal)
+        if q.shape[2] >= flash_attention.LANES:
+            _count_einsum_fallback(q, k, v, causal)
 
     num_heads = q.shape[1]
     if max_heads_parallel is None or max_heads_parallel >= num_heads:
@@ -124,14 +132,67 @@ def dot_product_attention(
 
 
 def _ambient_mesh():
-    """The physical mesh of the enclosing ``with mesh:`` context, or None."""
-    try:
-        from jax.interpreters import pxla
+    """The mesh of the enclosing ``jax.set_mesh`` /
+    ``jax.sharding.use_abstract_mesh`` context, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
-        mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
+
+def _flash_over_mesh(q, k, v, pad_mask, causal):
+    """The flash kernel, inside ``shard_map`` when the ambient mesh has more
+    than one device: batch over the ``data``/``fsdp`` axes, heads over
+    ``model``. A dim its axes do not divide stays replicated (a batch-1
+    prefill on a data-sharded serving mesh)."""
+    from jax.sharding import PartitionSpec as P
+
+    from perceiver_io_tpu.ops.flash_attention import flash_attention
+    from perceiver_io_tpu.parallel.mesh import AXIS_MODEL, AXIS_SEQ, BATCH_AXES
+
+    mesh = _ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, pad_mask=pad_mask, causal=causal)
+    if mesh.shape.get(AXIS_SEQ, 1) > 1:
+        raise ValueError(
+            "flash attention cannot run on a mesh whose 'seq' axis is sharded "
+            f"({dict(mesh.shape)}); use attention_impl='ring' for sequence "
+            "parallelism"
+        )
+
+    def dividing(axes, size):
+        axes = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+        shards = math.prod(mesh.shape[a] for a in axes)
+        return axes if axes and size % shards == 0 else None
+
+    batch_ax = dividing(BATCH_AXES, q.shape[0])
+    head_ax = dividing((AXIS_MODEL,), q.shape[1])
+    qkv_spec = P(batch_ax, head_ax, None, None)
+    args, in_specs = (q, k, v), (qkv_spec,) * 3
+    if pad_mask is not None:
+        args, in_specs = args + (pad_mask,), in_specs + (P(batch_ax, None),)
+
+    def body(q_, k_, v_, pad_=None):
+        return flash_attention(q_, k_, v_, pad_mask=pad_, causal=causal)
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
+    )(*args)
+
+
+def _count_einsum_fallback(q, k, v, causal) -> None:
+    """Record that ``impl='auto'`` on a TPU left a shape to the einsum path
+    because the flash kernel does not support it. Runs at trace time, so
+    once per traced shape."""
+    import warnings
+
+    from perceiver_io_tpu.observability import default_registry
+
+    default_registry().inc("attention_einsum_fallback_total")
+    warnings.warn(
+        f"flash attention does not support q={q.shape} k={k.shape} v={v.shape} "
+        f"dtype={q.dtype} causal={causal}; this call runs on the einsum path",
+        RuntimeWarning,
+        stacklevel=3,
+    )
 
 
 def _flash_eligible(q, k, v, dropout_rate) -> bool:
@@ -159,11 +220,7 @@ def _flash_eligible(q, k, v, dropout_rate) -> bool:
         min_kv = 0
     if k.shape[2] < min_kv:
         return False
-    try:
-        platform = q.devices().pop().platform if hasattr(q, "devices") else jax.default_backend()
-    except Exception:
-        platform = jax.default_backend()
-    return platform == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def _attention_xla(

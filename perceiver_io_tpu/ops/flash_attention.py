@@ -72,8 +72,23 @@ def _pick_block(n: int) -> Optional[int]:
     return None
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def pallas_call_on_lowering_platform(kernel, *args, **spec):
+    """``pl.pallas_call(kernel, **spec)(*args)``, compiled by Mosaic when the
+    program is lowered for a TPU and run by the Pallas interpreter on any
+    other platform. ``lax.platform_dependent`` makes the choice when the
+    lowering platform is known, so lowering for ``tpu`` on a CPU host goes
+    through Mosaic and a CPU run never depends on what
+    ``jax.default_backend()`` said at trace time. Only the chosen branch is
+    lowered."""
+
+    def call(*args, interpret):
+        return pl.pallas_call(kernel, interpret=interpret, **spec)(*args)
+
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(call, interpret=False),
+        default=functools.partial(call, interpret=True),
+    )
 
 
 def supported(q, k, v, *, causal: bool) -> bool:
@@ -111,6 +126,10 @@ def flash_attention(
     :param pad_mask: optional boolean ``(b, j)``, True marks padding.
     :param causal: right-aligned causal masking (offset ``j - i``).
 
+    Under a mesh with more than one device the caller wraps this in
+    ``jax.shard_map`` (:func:`perceiver_io_tpu.ops.attention.dot_product_attention`
+    does): Mosaic kernels are not partitioned automatically.
+
     Dead-row semantics: a query row whose entire visible window is padded
     gets **zero output and zero gradients** here. The einsum path (like the
     torch reference) instead softmaxes a uniform distribution over the masked
@@ -119,7 +138,9 @@ def flash_attention(
     masked), so the results never differ for real positions — the flash
     behavior is the deliberate one.
     """
-    pad = None if pad_mask is None else pad_mask.astype(jnp.float32)
+    # (b, 1, j): the kernels block it as (1, 1, bj), whose second-to-last dim
+    # equals the array's, so the TPU (8, 128) tiling rule holds at any batch.
+    pad = None if pad_mask is None else pad_mask.astype(jnp.float32)[:, None, :]
     return _flash(q, k, v, pad, causal)
 
 
@@ -183,14 +204,11 @@ def _qk_spec(bi, d, by_dim2=True):
 
 def _pad_spec(bj, by_dim2=False):
     if by_dim2:
-        return pl.BlockSpec((1, bj), lambda b_, h_, x_, y_: (b_, x_))
-    return pl.BlockSpec((1, bj), lambda b_, h_, x_, y_: (b_, y_))
+        return pl.BlockSpec((1, 1, bj), lambda b_, h_, x_, y_: (b_, 0, x_))
+    return pl.BlockSpec((1, 1, bj), lambda b_, h_, x_, y_: (b_, 0, y_))
 
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# whichever the pinned version exposes.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_DIM_SEMANTICS = _COMPILER_PARAMS(
+_DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
 )
 
@@ -224,7 +242,7 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
             )
             allowed = _block_mask(
                 i_idx, j_idx, bi, bj, offset, causal,
-                pad_ref[:] if has_pad else None,
+                pad_ref[0] if has_pad else None,
             )
             if allowed is not None:
                 s = jnp.where(allowed, s, _MASK)
@@ -264,8 +282,9 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
         in_specs.append(_pad_spec(bj))
         args.append(pad)
 
-    out = pl.pallas_call(
+    out = pallas_call_on_lowering_platform(
         kernel,
+        *args,
         grid=(b, h, i // bi, nj),
         in_specs=in_specs,
         out_specs=[
@@ -282,8 +301,7 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
             pltpu.VMEM((bi, dv), jnp.float32),
         ],
         compiler_params=_DIM_SEMANTICS,
-        interpret=_interpret(),
-    )(*args)
+    )
     return out[0], out[1]
 
 
@@ -315,7 +333,7 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
             )
             allowed = _block_mask(
                 i_idx, j_idx, bi, bj, offset, causal,
-                pad_ref[:] if has_pad else None,
+                pad_ref[0] if has_pad else None,
             )
             p = jnp.exp(s - lse_ref[0, 0][:, :1])
             if allowed is not None:
@@ -352,16 +370,16 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
     ]
     args += [lse, delta, do]
 
-    return pl.pallas_call(
+    return pallas_call_on_lowering_platform(
         kernel,
+        *args,
         grid=(b, h, i // bi, nj),
         in_specs=in_specs,
         out_specs=_qk_spec(bi, d, by_dim2=True),
         out_shape=jax.ShapeDtypeStruct((b, h, i, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bi, d), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
-        interpret=_interpret(),
-    )(*args)
+    )
 
 
 def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
@@ -395,7 +413,7 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
             )
             allowed = _block_mask(
                 i_idx, j_idx, bi, bj, offset, causal,
-                pad_ref[:] if has_pad else None,
+                pad_ref[0] if has_pad else None,
             )
             p = jnp.exp(s - lse_ref[0, 0][:, :1])
             if allowed is not None:
@@ -437,8 +455,9 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
     ]
     args += [lse, delta, do]
 
-    return pl.pallas_call(
+    return pallas_call_on_lowering_platform(
         kernel,
+        *args,
         grid=(b, h, j // bj, ni),
         in_specs=in_specs,
         out_specs=[
@@ -454,5 +473,4 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
             pltpu.VMEM((bj, dv), jnp.float32),
         ],
         compiler_params=_DIM_SEMANTICS,
-        interpret=_interpret(),
-    )(*args)
+    )

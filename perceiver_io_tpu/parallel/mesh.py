@@ -93,14 +93,11 @@ def make_mesh(
     devices = list(devices) if devices is not None else jax.devices()
     config = config.resolve(len(devices))
     devices = devices[: math.prod(config.shape)]  # fully-specified smaller mesh
-    try:
-        device_array = mesh_utils.create_device_mesh(
-            config.shape, devices=np.asarray(devices)
-        )
-    except (ValueError, AssertionError):
-        # Fallback for device sets mesh_utils cannot topology-optimize
-        # (e.g. virtual CPU devices in tests).
-        device_array = np.asarray(devices).reshape(config.shape)
+    # topology-aware on TPU devices, where a shape the topology cannot hold
+    # raises; a plain reshape for virtual CPU devices
+    device_array = mesh_utils.create_device_mesh(
+        config.shape, devices=np.asarray(devices)
+    )
     return Mesh(device_array, AXIS_NAMES)
 
 

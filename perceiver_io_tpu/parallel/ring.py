@@ -138,19 +138,9 @@ def ring_attention_sharded(
     body = functools.partial(
         _ring_body, axis_name=axis_name, axis_size=n_seq, causal=causal
     )
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:  # jax >= 0.6: top-level API, replication check is check_vma
-        fn = sm(
-            body, mesh=mesh, in_specs=in_specs, out_specs=seq_spec,
-            check_vma=False,
-        )
-    else:  # older jax: experimental module, same check spelled check_rep
-        from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-        fn = _exp_shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=seq_spec,
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=seq_spec, check_vma=False
+    )
     args = (q, k, v) + ((pad_mask,) if pad_mask is not None else ())
     return fn(*args)
 

@@ -710,6 +710,17 @@ class ModelFamily:
     frozen_prefixes: Optional[Callable] = None  # (model_cfg) -> tuple of paths
 
 
+def _announce_device(subcommand: str) -> None:
+    """Name the backend on stderr before any model work, so that a run's log
+    says what it ran on."""
+    device = jax.devices()[0]
+    print(
+        f"[{subcommand}] device: platform={device.platform} "
+        f"kind={device.device_kind} count={jax.device_count()}",
+        file=sys.stderr, flush=True,
+    )
+
+
 def _wants_help(argv: Sequence[str]) -> bool:
     """True when a standalone ``-h``/``--help`` appears. Tokens consumed as
     the *value* of a space-separated flag don't count: ``--data.text --help``
@@ -788,6 +799,9 @@ class CLI:
                 f"unknown subcommand {subcommand!r} "
                 "(fit|validate|test|preproc|serve|obs)"
             )
+        from perceiver_io_tpu.utils.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
         if subcommand == "obs":
             # offline analyzers — no checkpoint, no datamodule, no jax work:
             # `obs report` reads the artifacts a run left behind, `obs
@@ -955,6 +969,7 @@ class CLI:
         dm.prepare_data()
         if subcommand == "preproc":
             return None
+        _announce_device(subcommand)
         dm.setup()
 
         if self.family.link is not None:
@@ -1100,6 +1115,7 @@ class CLI:
         ckpt = values.get("ckpt") or values.get("params")
         if not ckpt:
             raise SystemExit("serve requires --ckpt <save_pretrained dir>")
+        _announce_device("serve")
         args = build_dataclass(ServeArgs, values, "serve")
         obs = build_dataclass(ObservabilityArgs, values, "obs")
         kit = _obs_kit(obs, os.getcwd(), passed=set(values))

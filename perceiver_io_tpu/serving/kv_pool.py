@@ -55,7 +55,7 @@ Design rules (all pinned by ``tests/test_paged_kv.py``):
   :meth:`KVPagePool.cow` swaps a fresh private block into the writing
   slot's table (copy-on-write; the owning engine performs the device-side
   page copy). ``frees_by_cause`` gains two causes on top of the
-  retirement taxonomy: ``"shared"`` (a cached prefix block dropped by the
+  retirement causes: ``"shared"`` (a cached prefix block dropped by the
   index — LRU eviction under pool pressure, or a flush) and ``"cow"`` (a
   shared mapping's final deref through a copy-on-write replacement).
 - **Host swap (docs/serving.md "Host-swap preemption").** A preemption

@@ -38,8 +38,8 @@ order is the only float difference; pinned on an 8-virtual-device CPU
 mesh by ``tests/test_sharding.py``).
 
 Mesh geometry is part of executor identity: the spec's fingerprint folds
-into every slot-engine cache key and the compile ledger's component
-taxonomy (``mesh``), so a mesh flip REBUILDS and attributes instead of
+into every slot-engine cache key and the compile ledger's components
+(``mesh``), so a mesh flip REBUILDS and attributes instead of
 silently reusing a single-device trace (docs/observability.md).
 """
 from __future__ import annotations

@@ -122,6 +122,7 @@ faults failing only resident requests while the queue survives.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
 from typing import Deque, Dict, List, Optional, Union
 
@@ -173,24 +174,27 @@ PREEMPTION_MODES = ("off", "recompute", "swap", "auto")
 _EXECUTOR_CACHE: dict = register_executor_cache({})
 
 
-def _donate(*argnums: int) -> tuple:
-    """Donate the persistent slot state into the executor (in-place cache
-    update on device) — skipped on CPU, where donation is unimplemented and
-    only produces a warning per compile."""
-    return argnums if jax.default_backend() != "cpu" else ()
-
-
 def _jit(fn, donate: tuple, out_shardings=None):
-    """jit an executor body, optionally pinning its output shardings to the
-    serving mesh (docs/serving.md "Sharded serving"). Pinning matters for
-    trace stability, not just placement: the persistent state round-trips
-    through every executor, so an output GSPMD re-sharded differently from
-    its input would change the next call's committed-input signature and
-    retrace. ``None`` (unsharded engine) is byte-for-byte today's
-    ``jax.jit`` call."""
+    """jit an executor body, donating the persistent slot state (``donate``
+    argnums: in-place cache update on device) and optionally pinning its
+    output shardings to the serving mesh (docs/serving.md "Sharded
+    serving"). Pinning matters for trace stability, not just placement: the
+    persistent state round-trips through every executor, so an output GSPMD
+    re-sharded differently from its input would change the next call's
+    committed-input signature and retrace. On a serving mesh the body is
+    traced with that mesh published, because the flash kernel shard_maps
+    itself over the ambient mesh (``ops/attention.py``). ``None`` (unsharded
+    engine) is a plain ``jax.jit`` call."""
     if out_shardings is None:
         return jax.jit(fn, donate_argnums=donate)
-    return jax.jit(fn, donate_argnums=donate, out_shardings=out_shardings)
+    mesh = jax.tree_util.tree_leaves(out_shardings)[0].mesh
+
+    @functools.wraps(fn)
+    def on_mesh(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+
+    return jax.jit(on_mesh, donate_argnums=donate, out_shardings=out_shardings)
 
 
 _STATE_SHAPES: dict = {}  # (model key, param dtypes) -> (logits, cache) shapes
@@ -358,7 +362,7 @@ def _build_prefill_executor(model, config: GenerationConfig, bucket_len: int,
                 cache=cache, length=length, m=jnp.asarray(m0, jnp.int32),
             )
 
-        return _jit(run, _donate(4), out_shardings)
+        return _jit(run, (4,), out_shardings)
 
     def run_paged(params, ids, pad_count, slot, table_row, state):
         window, pad, logits, cache, length = prefill(params, ids, pad_count)
@@ -368,7 +372,7 @@ def _build_prefill_executor(model, config: GenerationConfig, bucket_len: int,
             table_row=table_row, block_size=block_size,
         )
 
-    return _jit(run_paged, _donate(5), out_shardings)
+    return _jit(run_paged, (5,), out_shardings)
 
 
 def _build_chunked_prefill_executor(model, config: GenerationConfig, chunk: int,
@@ -426,7 +430,7 @@ def _build_chunked_prefill_executor(model, config: GenerationConfig, chunk: int,
 
         return jax.lax.cond(is_final, fin, stage, (stage_k, stage_v, state))
 
-    return _jit(run, _donate(9, 10, 11), out_shardings)
+    return _jit(run, (9, 10, 11), out_shardings)
 
 
 def _build_shared_prefill_executor(model, config: GenerationConfig, chunk: int,
@@ -510,7 +514,7 @@ def _build_shared_prefill_executor(model, config: GenerationConfig, chunk: int,
         with paged_ops.gather_constraint(gather_sharding):
             return jax.lax.cond(is_final, fin, stage, state)
 
-    return _jit(run, _donate(11), out_shardings)
+    return _jit(run, (11,), out_shardings)
 
 
 def _build_page_copy_executor(block_size: int, out_shardings=None):
@@ -538,7 +542,7 @@ def _build_page_copy_executor(block_size: int, out_shardings=None):
             )
         return out
 
-    return _jit(run, _donate(0), out_shardings)
+    return _jit(run, (0,), out_shardings)
 
 
 def _build_swap_extract_executor(block_size: int):
@@ -631,7 +635,7 @@ def _build_swap_restore_executor(block_size: int, out_shardings=None):
         )
         return out
 
-    return _jit(run, _donate(0), out_shardings)
+    return _jit(run, (0,), out_shardings)
 
 
 def _build_decode_executor(model, config: GenerationConfig, boundary: bool,
@@ -753,7 +757,7 @@ def _build_decode_executor(model, config: GenerationConfig, boundary: bool,
             with paged_ops.gather_constraint(gather_sharding):
                 return _paged_body(params, state, table, rng)
 
-        return _jit(run_paged, _donate(1), out_shardings)
+        return _jit(run_paged, (1,), out_shardings)
 
     def run(params, state, rng):
         logits = state["logits"].astype(jnp.float32)
@@ -822,7 +826,7 @@ def _build_decode_executor(model, config: GenerationConfig, boundary: bool,
         }
         return new_state, token
 
-    return _jit(run, _donate(1), out_shardings)
+    return _jit(run, (1,), out_shardings)
 
 
 def _build_spec_draft_executor(model, config: GenerationConfig, spec,
@@ -891,7 +895,7 @@ def _build_spec_verify_executor(model, config: GenerationConfig, spec,
         )
         return new_state, n_e
 
-    return _jit(run, _donate(1), out_shardings)
+    return _jit(run, (1,), out_shardings)
 
 
 @dataclasses.dataclass
@@ -1555,7 +1559,7 @@ class SlotServingEngine(ServingEngine):
     def _ledger_components(self, **extra) -> dict:
         """Named cache-key components for the compile ledger — the same
         knobs :meth:`_cache_key` folds into the tuple key, under the names
-        retrace attribution diffs (docs/observability.md taxonomy). Only
+        retrace attribution diffs (docs/observability.md reason names). Only
         called on a cache MISS (the executor getters pass it as a thunk):
         the model-id hash and config normalization stay off the per-token
         hit path."""
@@ -2825,7 +2829,7 @@ class SlotServingEngine(ServingEngine):
             entry.req.result = out
         self._finish(entry.req, status, error=error)
         self._slots[entry.slot] = None
-        # pool free-cause taxonomy (kv_pool.frees_by_cause): client-driven
+        # pool free-cause names (kv_pool.frees_by_cause): client-driven
         # reclaim, engine-fault reclaim, and fleet scale-down evacuation
         # (kv_cause override) stay separable from ordinary
         # EOS/max_new/deadline churn
@@ -3097,27 +3101,18 @@ class SlotServingEngine(ServingEngine):
                 )
                 disposed += 1
             else:
-                final = admit.next_chunk == len(admit.offsets)
-                shared = admit.plan is not None
                 ran_chunk_call = True
                 try:
                     self._advance_chunked_admit()
                 except Exception as e:
-                    # on CPU an UNSHARED chunk fault only poisons the
-                    # batch-1 staging caches; a SHARED stage call writes
-                    # pool pages through the live state on every backend,
-                    # and with donation live (non-CPU) the shared slot
-                    # state was donated into the failed call too — as does
-                    # a finalize fault either way
+                    # the slot state was donated into the failed call
                     self._admitting = None
                     self._kv_release(admit.slot)
                     self._finish(req, "failed", error=f"{type(e).__name__}: {e}")
-                    disposed += 1
-                    if final or shared or _donate(0):
-                        return disposed + self._fail_resident(
-                            "chunked-prefill fault poisoned the slot state: "
-                            f"{type(e).__name__}: {e}"
-                        )
+                    return disposed + 1 + self._fail_resident(
+                        "chunked-prefill fault poisoned the slot state: "
+                        f"{type(e).__name__}: {e}"
+                    )
         self._preempts_this_step = 0
         if self._queue and (
             self.preemption != "off" or any(r.priority for r in self._queue)
@@ -3246,8 +3241,8 @@ class SlotServingEngine(ServingEngine):
                 try:
                     self._restore_admit(req, slot, bundle)
                 except Exception as e:
-                    # the restore scatter donates the slot state (non-CPU)
-                    # and may have half-written the pool either way —
+                    # the restore scatter donates the slot state and may
+                    # have half-written the pool —
                     # _fail_resident releases every slot's pages, which
                     # covers whatever pool.restore had re-mapped
                     self._finish(req, "failed", error=f"{type(e).__name__}: {e}")
@@ -3260,18 +3255,14 @@ class SlotServingEngine(ServingEngine):
                 try:
                     self._start_chunked_admit(req, slot, plan)
                 except Exception as e:
-                    # first chunk: staging-only fault on CPU; with donation
-                    # live the slot state went into the failed call too —
-                    # and a shared first call writes pool pages directly
+                    # the slot state was donated into the failed call
                     self._admitting = None
                     self._kv_release(slot)
                     self._finish(req, "failed", error=f"{type(e).__name__}: {e}")
-                    disposed += 1
-                    if plan is not None or _donate(0):
-                        return disposed + self._fail_resident(
-                            "chunked-prefill fault poisoned the slot state: "
-                            f"{type(e).__name__}: {e}"
-                        )
+                    return disposed + 1 + self._fail_resident(
+                        "chunked-prefill fault poisoned the slot state: "
+                        f"{type(e).__name__}: {e}"
+                    )
                 continue
             try:
                 self._admit(req, slot, plan)

@@ -23,6 +23,7 @@ import os
 from typing import Any, Optional
 
 import jax
+import numpy as np
 import orbax.checkpoint as ocp
 
 from perceiver_io_tpu.models.core.config import config_from_dict, config_to_dict
@@ -43,6 +44,23 @@ def save_pretrained(path: str, params: Any, config: Any, *, extra: Optional[dict
     ckptr = ocp.StandardCheckpointer()
     ckptr.save(os.path.join(path, PARAMS_DIR), params, force=True)
     ckptr.wait_until_finished()
+
+
+def _restore_tree(directory: str, target: Any):
+    """Restore an orbax tree: onto ``target``'s shardings when given, else
+    through host memory onto the default device, uncommitted. Restoring
+    without a target must not inherit the mesh the tree was saved from: a
+    checkpoint written by a four-chip ``fit`` would come back committed to
+    all four devices (and fail outright on a host that lacks them)."""
+    if target is not None:
+        return ocp.StandardCheckpointer().restore(directory, target)
+    ckptr = ocp.PyTreeCheckpointer()
+    saved = ckptr.metadata(directory).item_metadata.tree
+    to_host = jax.tree_util.tree_map(
+        lambda _: ocp.RestoreArgs(restore_type=np.ndarray), saved
+    )
+    host = ckptr.restore(directory, args=ocp.args.PyTreeRestore(restore_args=to_host))
+    return jax.device_put(host)
 
 
 def load_config(path: str) -> Any:
@@ -66,8 +84,8 @@ def _trainer_checkpoint_root(path: str) -> Optional[str]:
 
 def load_pretrained(path: str, *, target: Any = None):
     """:return: (params, config). ``target`` — an abstract pytree (e.g. from
-    ``jax.eval_shape``) with shardings for direct-to-mesh restore; omit for
-    host restore.
+    ``jax.eval_shape``) with shardings for direct-to-mesh restore; omit it
+    and the params land on the default device, whatever mesh saved them.
 
     Accepts either a ``save_pretrained`` dir or a trainer checkpoint dir
     (``<root>/checkpoints`` or the ``<root>/checkpoints/best`` alias), which
@@ -81,9 +99,7 @@ def load_pretrained(path: str, *, target: Any = None):
         finally:
             manager.close()
     config = load_config(path)
-    ckptr = ocp.StandardCheckpointer()
-    params = ckptr.restore(os.path.join(path, PARAMS_DIR), target)
-    return params, config
+    return _restore_tree(os.path.join(path, PARAMS_DIR), target), config
 
 
 def load_subtree(path: str, subtree: str, *, target: Any = None):
@@ -203,7 +219,11 @@ class BestCheckpointManager:
         step = self.best_step
         if step is None:
             raise FileNotFoundError(f"no checkpoints in {self.directory}")
-        params = self._manager.restore(step, args=ocp.args.StandardRestore(target))
+        # the manager's one unnamed item lives under <step>/<default item name>
+        item = os.path.join(
+            self.directory, str(step), ocp.checkpoint_manager.DEFAULT_ITEM_NAME
+        )
+        params = _restore_tree(item, target)
         with open(os.path.join(self.directory, CONFIG_FILE)) as f:
             d = json.load(f).get("model_config")
         return params, (config_from_dict(None, d) if d is not None else None)

@@ -24,16 +24,9 @@ def trace(log_dir: str, *, host_tracer_level: int = 2):
             state, metrics = train_step(state, batch, rng)
             jax.block_until_ready(metrics)
     """
-    # ProfileOptions landed after jax 0.4.x; on older jax start_trace takes
-    # no options object and host_tracer_level stays at its default (same
-    # getattr version-shim discipline as pltpu.CompilerParams).
-    options_cls = getattr(jax.profiler, "ProfileOptions", None)
-    if options_cls is not None:
-        options = options_cls()
-        options.host_tracer_level = host_tracer_level
-        jax.profiler.start_trace(log_dir, profiler_options=options)
-    else:
-        jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:

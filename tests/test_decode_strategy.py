@@ -459,7 +459,7 @@ def test_serve_cli_decode_mode_env_deference(monkeypatch):
 def test_bench_prefill_chunk_ab_probe_tiny(tiny_model):
     """The bench.py chunked-prefill A/B runs at a pure-CPU tiny shape and
     reports both arms' p95 resident inter-token latency (tiny shapes are
-    dispatch-bound, so no winner is asserted here; the CPU-fallback bench
+    dispatch-bound, so no winner is asserted here; the reduced-shape bench
     record is the acceptance number)."""
     import importlib.util
     import os

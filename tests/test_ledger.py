@@ -104,7 +104,7 @@ def _build_sequence(ledger):
 def test_cold_compile_and_retrace_attribution():
     """First build of an identity is a cold compile; rebuilds count under
     every changed component; an unchanged rebuild is ``duplicate_key``; a
-    different model is a fresh identity (docs/observability.md taxonomy)."""
+    different model is a fresh identity (docs/observability.md reason names)."""
     reg = MetricsRegistry()
     ledger = CompileLedger(registry=reg, clock=FakeClock())
     _build_sequence(ledger)

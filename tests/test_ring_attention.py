@@ -102,7 +102,7 @@ def test_model_level_ring_dispatch(rng):
     ids = jnp.asarray(rng.integers(1, 32, (2, 32)), jnp.int32)
 
     mesh = make_mesh(MeshConfig(seq=4))
-    with mesh:
+    with jax.set_mesh(mesh):
         out_ring = ring_model.apply({"params": params}, ids, 16)
     out_xla = xla_model.apply({"params": params}, ids, 16)
     np.testing.assert_allclose(

@@ -1,0 +1,157 @@
+"""Lowering for the ``tpu`` platform on the CPU: what Mosaic and the SPMD
+partitioner refuse, they refuse while lowering, so these catch it without a
+chip. Lowering only (no libtpu compile), small shapes.
+
+The kernels choose compiled-or-interpreted from the *lowering* platform
+(``flash_attention.pallas_call_on_lowering_platform``), which is what makes
+this test anything: lowered for ``tpu`` the program must hold
+``tpu_custom_call``, lowered for the CPU it must not. The two refusals this pins were both live
+before the chip bring-up: a pad-mask BlockSpec that broke the (8, 128) tiling
+rule at batch > 1, and a Mosaic kernel under a multi-device mesh without
+``shard_map``. Numerical parity of the compiled kernels is ``chip_smoke.py``'s.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from perceiver_io_tpu.models.text.clm import CausalLanguageModel, CausalLanguageModelConfig
+from perceiver_io_tpu.ops import flash_attention
+from perceiver_io_tpu.ops import paged_attention as paged
+from perceiver_io_tpu.ops.attention import dot_product_attention
+from perceiver_io_tpu.ops.ragged_attention import ragged_paged_attention
+from perceiver_io_tpu.parallel import (
+    MeshConfig,
+    create_train_state,
+    make_mesh,
+    make_train_step,
+    shard_batch,
+)
+from perceiver_io_tpu.training.tasks import clm_loss_fn
+
+pytestmark = pytest.mark.timeout(120)
+
+
+def _mosaic_calls(fn, *args, platform="tpu"):
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=(platform,))
+    return lowered.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize(
+    "causal,pad",
+    [(True, False), (True, True), (False, True)],
+    ids=["causal", "causal_pad_b2", "noncausal_pad_b2"],
+)
+def test_flash_forward_and_backward_lower_for_tpu(causal, pad):
+    b, h, i, j, d = 2, 2, 128, 256, 64
+    q = jnp.zeros((b, h, i, d), jnp.bfloat16)
+    k = v = jnp.zeros((b, h, j, d), jnp.bfloat16)
+    pad_mask = jnp.zeros((b, j), bool) if pad else None
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda q, k, v: jnp.sum(
+                flash_attention.flash_attention(
+                    q, k, v, pad_mask=pad_mask, causal=causal
+                ).astype(jnp.float32)
+            ),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    assert _mosaic_calls(fwd_bwd, q, k, v) == 3  # forward, dq, dk/dv
+    assert _mosaic_calls(fwd_bwd, q, k, v, platform="cpu") == 0  # interpreted
+
+
+@pytest.mark.parametrize(
+    "q_len,int8", [(1, False), (8, False), (1, True)],
+    ids=["decode_row", "window_row", "int8"],
+)
+def test_ragged_kernel_lowers_for_tpu(q_len, int8):
+    rows, h, d, bs, pages = 2, 2, 64, 16, 4
+    tokens = (rows * pages + 1) * bs
+    pool = jnp.zeros((tokens, h, d), jnp.float32)
+    scale_k = scale_v = None
+    if int8:
+        pool, scale_k = paged.quantize_kv(pool)
+        scale_v = scale_k
+    q = jnp.zeros((rows, h, q_len, d), jnp.float32)
+    table = jnp.zeros((rows, pages), jnp.int32)
+    lengths = jnp.zeros((rows,), jnp.int32)
+
+    def kernel(q, pool_k, pool_v, table, lengths, scale_k, scale_v):
+        return ragged_paged_attention(
+            q, pool_k, pool_v, table, lengths, block_size=bs,
+            scale_k=scale_k, scale_v=scale_v,
+        )
+
+    args = (q, pool, pool, table, lengths, scale_k, scale_v)
+    assert _mosaic_calls(kernel, *args) == 1
+    assert _mosaic_calls(kernel, *args, platform="cpu") == 0
+
+
+@pytest.mark.parametrize(
+    "axes",
+    [dict(data=4), dict(data=1, fsdp=4), dict(data=2, model=2)],
+    ids=["data4", "fsdp4", "data2_model2"],
+)
+def test_train_step_with_pad_mask_lowers_for_tpu_on_four_devices(devices, axes):
+    """The CLI's batch (pad mask included) through the flash kernel, over a
+    4-device mesh: ``make_train_step`` publishes the mesh and the flash call
+    shard_maps itself over it."""
+    cfg = CausalLanguageModelConfig(
+        vocab_size=262, max_seq_len=640, max_latents=128, num_channels=128,
+        num_heads=4, num_self_attention_layers=1,
+    )
+    model = CausalLanguageModel(cfg, dtype=jnp.bfloat16, attention_impl="flash")
+    mesh = make_mesh(MeshConfig(**axes), devices=devices[:4])
+
+    def init():
+        return model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 640), jnp.int32), 512
+        )["params"]
+
+    state, shardings = create_train_state(init, optax.adamw(1e-3), mesh)
+    step = make_train_step(clm_loss_fn(model, cfg.max_latents), mesh, shardings)
+    ids = np.zeros((4, 640), np.int32)
+    batch = shard_batch(
+        {"input_ids": ids, "labels": ids, "pad_mask": np.zeros((4, 640), bool)}, mesh
+    )
+    lowered = step.trace(state, batch, jax.random.PRNGKey(1)).lower(
+        lowering_platforms=("tpu",)
+    )
+    # cross-attention + one self-attention layer, each forward, dq and dk/dv
+    assert lowered.as_text().count("tpu_custom_call") == 6
+
+
+def test_mosaic_kernel_without_shard_map_is_refused_at_lowering(devices):
+    """Why ``dot_product_attention`` wraps the kernel: called bare on sharded
+    operands it cannot be partitioned, and lowering says so."""
+    mesh = Mesh(np.array(devices[:4]), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+    q = jax.ShapeDtypeStruct((4, 2, 128, 64), jnp.bfloat16, sharding=sharded)
+    k = jax.ShapeDtypeStruct((4, 2, 256, 64), jnp.bfloat16, sharding=sharded)
+
+    def bare(q, k, v):
+        return flash_attention.flash_attention(q, k, v, causal=True)
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(bare).trace(q, k, k).lower(lowering_platforms=("tpu",))
+
+    def dispatched(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return dot_product_attention(q, k, v, causal=True, impl="flash")
+
+    lowered = jax.jit(dispatched).trace(q, k, k).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+
+
+def test_flash_under_a_seq_sharded_mesh_is_an_error(devices):
+    mesh = make_mesh(MeshConfig(data=2, seq=2), devices=devices[:4])
+    q = jnp.zeros((2, 2, 128, 64), jnp.bfloat16)
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        with pytest.raises(ValueError, match="ring"):
+            jax.eval_shape(
+                lambda q: dot_product_attention(q, q, q, causal=True, impl="flash"), q
+            )
