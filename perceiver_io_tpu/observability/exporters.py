@@ -120,6 +120,8 @@ HELP_TEXT = {
     "trainer_skipped_steps_total": "Steps discarded by the non-finite skip policy.",
     "trainer_rollbacks_total": "Divergence rollbacks to a saved training state.",
     "trainer_callback_errors_total": "Callbacks that raised and were isolated.",
+    "trainer_data_wait_seconds_total": "Seconds the loop waited for the stream's next batch (trainer.data_wait); rate over trainer_steps_total's is the wait a step.",
+    "trainer_log_flush_seconds_total": "Seconds the loop waited for the device at a log flush (trainer.log_flush: the host fetch of a cadence's metrics).",
     "trainer_step_dispatch_ms": "Host dispatch time per step (unfenced; device async).",
     "trainer_step_ms": "Fenced true step time (profiler-trigger runs only).",
     "trainer_steps_per_sec": "Recent steady-state training step rate.",

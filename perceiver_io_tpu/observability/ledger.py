@@ -43,6 +43,21 @@ Registry families fed (docs/observability.md):
 - ``kv_cache_resident_bytes`` gauge — the analytic slot-KV footprint the
   slot engine publishes at construction (everywhere, device stats or not).
 
+**The scope tables** (``op_scopes``, ``fused_scopes``): the profiler names a
+device operation by its HLO instruction (``fusion.12``, ``flash_fwd.3``) and
+drops the instruction's metadata, where JAX put the path of scopes the
+operation was traced under (``jit(step)/jvp(Model)/encoder/.../q_proj/dot_general``:
+Flax modules name themselves, ``loss`` / ``grad_clip`` / ``optimizer`` are
+named in ``training/tasks.py`` and ``parallel/train_step.py``). Only the
+program that compiled the step can give the table from one to the other. A
+jitted function that keeps its own dispatch (the trainer's step) is
+announced with :meth:`CompileLedger.note_jit`; :meth:`CompileLedger.op_scopes`
+lowers and compiles it from the kept argument shapes when first asked (the
+lowering is done again; the compile is a persistent-cache hit after the
+run's own), parses the optimized HLO with :func:`parse_op_scopes` and keeps
+the tables. A fusion has one ``op_name`` and may hold operations of several
+scopes: :meth:`CompileLedger.fused_scopes` lists them all.
+
 Failure containment: observation must never change execution semantics. If
 the wrapped callable cannot be lowered (it is not a jitted function) or the
 compiled dispatch rejects the call signature (``TypeError`` — AOT
@@ -61,9 +76,12 @@ of the build sequence, pinned by ``tests/test_ledger.py``.
 """
 from __future__ import annotations
 
+import hashlib
+import re
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+import warnings
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from perceiver_io_tpu.observability.registry import MetricsRegistry
 
@@ -181,6 +199,94 @@ def _memory_summary(compiled) -> Dict[str, Optional[int]]:
     return out
 
 
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s.*?\s([a-z][\w\-]*)\((.*)$")
+_OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
+_FUSED = re.compile(r"\bcalls=%?([\w.\-]+)")
+#: how an instruction names a computation whose instructions run as device
+#: operations of their own (a fusion's or a reduction's callee does not)
+_CALLED = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}"
+)
+_APPLIED = re.compile(r"\bto_apply=%?([\w.\-]+)")  # a ``call``'s; a ``reduce`` has one too
+
+
+def parse_op_scopes(hlo_text: str) -> Tuple[Dict[str, str], Dict[str, List[str]]]:
+    """``({instruction name: op_name}, {fusion name: [op_name, ...]})`` from a
+    compiled program's text, for every instruction of the entry computation
+    and of the computations that control flow calls from it (loop bodies,
+    branches). The instruction name is what the profiler calls the device
+    operation; ``op_name`` is the path of scopes JAX traced it under, as XLA
+    left it in the instruction's metadata: on a fusion that of one of the
+    operations fused, ``""`` where XLA left none (its own fusions, async
+    copies, slices, bitcasts). The second table says what else a fusion
+    holds: the distinct ``op_name``s of the instructions fused into it, in
+    the program's order, through nested fusions. Nothing is guessed."""
+    # computation -> instruction -> (op_name, fused computation)
+    computations: Dict[str, Dict[str, Tuple[str, Optional[str]]]] = {}
+    entry, called, current = None, set(), None
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header is not None:
+            current = computations.setdefault(header.group(2), {})
+            if header.group(1):
+                entry = header.group(2)
+            continue
+        instruction = _INSTRUCTION.match(line)
+        if current is None or instruction is None:
+            continue
+        name, opcode, rest = instruction.groups()
+        # a Mosaic kernel's backend_config is kilobytes of its encoded body
+        rest = rest.partition(", backend_config=")[0]
+        op_name, fused = _OP_NAME.search(rest), _FUSED.search(rest)
+        current[name] = (
+            "" if op_name is None else op_name.group(1),
+            None if fused is None else fused.group(1),
+        )
+        for one, many in _CALLED.findall(rest):
+            called.update(n.strip().lstrip("%") for n in (one, *many.split(",")) if n.strip())
+        if opcode == "call":
+            called.update(_APPLIED.findall(rest))
+
+    def held(computation: str) -> Dict[str, None]:
+        names: Dict[str, None] = {}  # a dict keeps the order and drops repeats
+        for op_name, fused in computations.get(computation, {}).values():
+            if op_name:
+                names[op_name] = None
+            if fused:  # a fusion nested in the fused computation
+                names.update(held(fused))
+        return names
+
+    scopes: Dict[str, str] = {}
+    fusions: Dict[str, List[str]] = {}
+    for name in (entry, *sorted(called)):
+        for instruction, (op_name, fused) in computations.get(name, {}).items():
+            scopes[instruction] = op_name
+            if fused:
+                fusions[instruction] = list(held(fused))
+    return scopes, fusions
+
+
+def _abstract(tree):
+    """``tree`` with every array leaf replaced by its ``ShapeDtypeStruct``
+    (with the sharding of a committed ``jax.Array``): what lowering the call
+    again needs, and no buffer."""
+    import jax
+
+    def leaf(x):
+        if isinstance(x, jax.Array):
+            sharding = x.sharding if x.committed else None
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=sharding, weak_type=x.weak_type
+            )
+        if hasattr(x, "shape") and hasattr(x, "dtype"):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    return jax.tree_util.tree_map(leaf, tree)
+
+
 class CompileLedger:
     """Per-executor compile/memory/retrace ledger over one metrics registry.
 
@@ -218,6 +324,10 @@ class CompileLedger:
         self._reason_totals: Dict[str, int] = {}
         self._seq = 0
         self._on_record: List[Callable[[dict], None]] = []
+        #: site -> the jitted function last announced there and its abstract
+        #: arguments or, once asked for, its scope tables (``note_jit``)
+        self._jits: Dict[str, dict] = {}
+        self._scopes_lock = threading.Lock()
         registry.declare_counters(
             "compile_total", "retrace_total", "compile_ledger_fallback_total"
         )
@@ -238,6 +348,69 @@ class CompileLedger:
         comps = {k: str(v) for k, v in components.items()}
         entry = {"site": site, "components": comps}
         return LedgeredExecutor(executor, self, entry)
+
+    def note_jit(self, site: str, fn: Callable, args: tuple = ()) -> None:
+        """Announce a jitted function that keeps ``jax.jit``'s own dispatch
+        (the trainer's step at site ``trainer.step``) with one call's
+        arguments. The ledger keeps the function and the arguments' shapes,
+        dtypes and shardings, no array, so :meth:`op_scopes` can lower it
+        again after its caller is gone. A kept function keeps what
+        ``jax.jit`` cached for it, its loaded executable too: a caller that
+        is done with the function calls its ``clear_cache()`` (the trainer
+        does when ``fit`` ends). A later announcement at ``site`` replaces
+        this one; :meth:`reset` drops it."""
+        noted = {"fn": fn, "args": _abstract(args), "tables": None}
+        with self._lock:
+            self._jits[site] = noted
+
+    def op_scopes(self, site: str) -> Dict[str, str]:
+        """``{instruction name: op_name}`` of the program announced at
+        ``site`` (:func:`parse_op_scopes`). Lowered and compiled when first
+        asked, which takes about what a warm start spends on the step (the
+        run's persistent compile cache answers the compile, not the
+        lowering), recorded like any executor's build, then kept. Empty when
+        nothing was announced; a function the ledger cannot lower or compile
+        gives an empty table too and counts ``compile_ledger_fallback_total``."""
+        return dict(self._scope_tables(site)[0])
+
+    def fused_scopes(self, site: str) -> Dict[str, List[str]]:
+        """``{fusion's instruction name: [op_name, ...]}`` of the same
+        program: what each fusion holds besides the one ``op_name``
+        :meth:`op_scopes` gives it (XLA fuses an optimizer update into the
+        matmul that makes its gradient, and the fusion is named by the
+        matmul). From the same compile as :meth:`op_scopes`."""
+        return dict(self._scope_tables(site)[1])
+
+    def _scope_tables(self, site: str) -> Tuple[dict, dict]:
+        with self._lock:
+            noted = self._jits.get(site)
+        if noted is None:
+            return {}, {}
+        with self._scopes_lock:
+            if noted["tables"] is None:
+                noted["tables"] = self._compile_and_parse(site, noted["fn"], noted["args"])
+                # the tables are all that is kept: the function can go with
+                # its caller
+                noted["fn"] = noted["args"] = None
+            return noted["tables"]
+
+    def _compile_and_parse(self, site: str, fn: Callable, args: tuple) -> Tuple[dict, dict]:
+        t0 = self._clock()
+        try:
+            compiled = fn.lower(*args).compile()
+            text = compiled.as_text()
+        except Exception as e:  # the run has its step; only the tables are lost
+            warnings.warn(f"op_scopes({site!r}): cannot compile the announced function: {e!r}")
+            self._count_fallback()
+            return {}, {}
+        entry = {"site": site, "components": {
+            "function": getattr(fn, "__name__", type(fn).__name__),
+            "arguments": hashlib.sha1(repr(args).encode()).hexdigest()[:12],
+        }}
+        self._record_compiled(
+            entry, (self._clock() - t0) * 1e3, _cost_summary(compiled), _memory_summary(compiled)
+        )
+        return parse_op_scopes(text)
 
     def attach(self, callback: Callable[[dict], None]) -> Callable[[], None]:
         """Register a per-record callback (the serve CLI forwards records as
@@ -402,6 +575,7 @@ class CompileLedger:
             self._total_compile_ms = 0.0
             self._reason_totals.clear()
             self._seq = 0
+            self._jits.clear()
         # the executors the gauge described are gone too
         self.registry.set_gauge("executor_resident_bytes", 0)
 

@@ -23,7 +23,10 @@ events file would collide on restarted IDs — pass a per-run ``prefix``
 default stays bare for deterministic tests.
 
 Components take ``tracer=None`` and skip every span site when unset — the
-same zero-cost-when-off contract as the chaos hooks.
+same zero-cost-when-off contract as the chaos hooks. The trainer's phases are
+the exception: they are profiler annotations with or without a tracer
+(``Trainer._span``). Every context-managed span is also a
+``jax.profiler.TraceAnnotation`` of its name.
 
 **Trace sampling** (docs/observability.md "Trace sampling"): at fleet
 scale the span stream is a firehose — every request writes ~6 lines — so
@@ -158,10 +161,16 @@ class Tracer:
     def span(self, name: str, *, trace_id: Optional[str] = None,
              parent: Optional[Span] = None, **attrs: Any):
         """Context-managed span; a raising body ends it ``status="error"``
-        (and re-raises)."""
+        (and re-raises). The body also runs under a ``jax.profiler``
+        annotation of the span's name, so in a profiler capture the span is
+        on the device trace's clock; a backdated span (``start_span`` with
+        ``start_s``) cannot be."""
+        from jax.profiler import TraceAnnotation
+
         sp = self.start_span(name, trace_id=trace_id, parent=parent, **attrs)
         try:
-            yield sp
+            with TraceAnnotation(name):
+                yield sp
         except BaseException:
             self.end_span(sp, status="error")
             raise

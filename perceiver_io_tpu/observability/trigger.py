@@ -117,7 +117,8 @@ class ProfilerTrigger:
     @contextlib.contextmanager
     def capture(self, *, step: Optional[int] = None):
         """Run the enclosed (regressed) step under a profiler capture and
-        disarm; enters the cooldown window afterwards."""
+        disarm; enters the cooldown window afterwards. Yields the capture's
+        directory (the trainer writes ``op_scopes.json`` beside the trace)."""
         self._armed = False
         self.captures += 1
         self._cooldown_left = self.cooldown
@@ -133,4 +134,4 @@ class ProfilerTrigger:
 
             cm = trace(target)
         with cm:
-            yield
+            yield target

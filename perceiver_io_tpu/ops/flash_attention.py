@@ -72,17 +72,22 @@ def _pick_block(n: int) -> Optional[int]:
     return None
 
 
-def pallas_call_on_lowering_platform(kernel, *args, **spec):
-    """``pl.pallas_call(kernel, **spec)(*args)``, compiled by Mosaic when the
-    program is lowered for a TPU and run by the Pallas interpreter on any
-    other platform. ``lax.platform_dependent`` makes the choice when the
+def pallas_call_on_lowering_platform(kernel, *args, name: str, **spec):
+    """``pl.pallas_call(kernel, name=name, **spec)(*args)``, compiled by Mosaic
+    when the program is lowered for a TPU and run by the Pallas interpreter on
+    any other platform. ``lax.platform_dependent`` makes the choice when the
     lowering platform is known, so lowering for ``tpu`` on a CPU host goes
     through Mosaic and a CPU run never depends on what
     ``jax.default_backend()`` said at trace time. Only the chosen branch is
-    lowered."""
+    lowered.
+
+    ``name`` becomes the Mosaic kernel's ``kernel_name`` and the last scope of
+    its location, which is what the compiled program's instruction, and with
+    it the profiler's event, is called (``flash_fwd.3``, not
+    ``branch_0_fun.3``)."""
 
     def call(*args, interpret):
-        return pl.pallas_call(kernel, interpret=interpret, **spec)(*args)
+        return pl.pallas_call(kernel, interpret=interpret, name=name, **spec)(*args)
 
     return jax.lax.platform_dependent(
         *args,
@@ -285,6 +290,7 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
     out = pallas_call_on_lowering_platform(
         kernel,
         *args,
+        name="flash_fwd",
         grid=(b, h, i // bi, nj),
         in_specs=in_specs,
         out_specs=[
@@ -373,6 +379,7 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
     return pallas_call_on_lowering_platform(
         kernel,
         *args,
+        name="flash_bwd_dq",
         grid=(b, h, i // bi, nj),
         in_specs=in_specs,
         out_specs=_qk_spec(bi, d, by_dim2=True),
@@ -458,6 +465,7 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
     return pallas_call_on_lowering_platform(
         kernel,
         *args,
+        name="flash_bwd_dkv",
         grid=(b, h, j // bj, ni),
         in_specs=in_specs,
         out_specs=[

@@ -194,6 +194,7 @@ def _launch(q, k_pages, v_pages, table, lengths, scales, *, block_size):
     return pallas_call_on_lowering_platform(
         _make_kernel(block_size, pages, quantized),
         table, lengths, *inputs,
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
     )

@@ -184,12 +184,17 @@ def make_train_step(
         # the ambient mesh (ops/attention.py)
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             (loss, metrics), grads = value_and_grads(state.params, batch, rng)
+        # scopes for what is no Flax module (those name themselves): they end
+        # up in the compiled instructions' op_name, which
+        # observability.ledger.op_scopes reads
         if grad_clip_norm is not None:
-            gnorm = optax.global_norm(grads)
-            scale = jnp.minimum(1.0, grad_clip_norm / (gnorm + 1e-6))
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+            with jax.named_scope("grad_clip"):
+                gnorm = optax.global_norm(grads)
+                scale = jnp.minimum(1.0, grad_clip_norm / (gnorm + 1e-6))
+                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
             metrics = {**metrics, "grad_norm": gnorm}
-        state = state.apply_gradients(grads)
+        with jax.named_scope("optimizer"):
+            state = state.apply_gradients(grads)
         return state, {"loss": loss, **metrics}
 
     if multi_steps == 1:

@@ -19,12 +19,13 @@ IGNORE_INDEX = -100  # torch cross_entropy ignore_index, used throughout the ref
 def masked_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     """Token-mean CE ignoring ``IGNORE_INDEX`` labels — semantics of torch
     ``F.cross_entropy(logits, labels)`` with default mean reduction."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    valid = labels != IGNORE_INDEX
-    safe = jnp.where(valid, labels, 0)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    nll = jnp.where(valid, nll, 0.0)
-    return nll.sum() / jnp.maximum(1, valid.sum())
+    with jax.named_scope("loss"):  # read by observability.ledger.op_scopes
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        valid = labels != IGNORE_INDEX
+        safe = jnp.where(valid, labels, 0)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        nll = jnp.where(valid, nll, 0.0)
+        return nll.sum() / jnp.maximum(1, valid.sum())
 
 
 def _rngs(rng) -> Optional[dict]:

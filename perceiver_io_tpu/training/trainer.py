@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from perceiver_io_tpu.observability import MetricsRegistry
+from perceiver_io_tpu.observability import MetricsRegistry, default_ledger, default_registry
 
 from perceiver_io_tpu.parallel import (
     TrainState,
@@ -118,6 +118,14 @@ class TrainerConfig:
 
 #: steps traced per jax.profiler capture: [profile_start, profile_start + _PROFILE_WINDOW)
 _PROFILE_WINDOW = 3
+
+#: the phases in which the loop waits for something else (the stream; the
+#: device, at a log flush): ``_span("trainer.<phase>")`` adds their seconds to
+#: ``trainer_<phase>_seconds_total``
+_WAITS = ("data_wait", "log_flush")
+#: counters kept on the process-wide registry as well as on the trainer's
+#: own, so that they can be read after the trainer is gone
+_PROCESS_WIDE = ("trainer_steps_total",) + tuple(f"trainer_{p}_seconds_total" for p in _WAITS)
 
 
 def _check_uniform_block(block, k_exec: int) -> None:
@@ -266,10 +274,14 @@ class Trainer:
     :param registry: metrics registry the trainer's counters/histograms live
         on (``trainer_steps_total``, ``trainer_step_ms``, fault counters...);
         defaults to a private one (docs/observability.md).
+        ``trainer_steps_total``, ``trainer_data_wait_seconds_total`` and
+        ``trainer_log_flush_seconds_total`` are counted on
+        ``default_registry()`` as well.
     :param tracer: optional :class:`~perceiver_io_tpu.observability.Tracer`
         — one trace per ``fit`` with per-step ``trainer.data_wait`` /
         ``trainer.step`` / ``trainer.log_flush`` / ``trainer.checkpoint``
-        spans. None skips every span site.
+        spans. With or without one, each is a ``jax.profiler`` annotation of
+        the span's name.
     :param profiler_trigger: optional
         :class:`~perceiver_io_tpu.observability.ProfilerTrigger` — fed each
         single step's host time; when the p95 regresses, the next step runs
@@ -312,11 +324,12 @@ class Trainer:
         self._policy = _effective_non_finite_policy(config)
         self.registry = registry if registry is not None else MetricsRegistry()
         self.registry.declare_counters(
-            "trainer_steps_total",
+            *_PROCESS_WIDE,
             "trainer_skipped_steps_total",
             "trainer_rollbacks_total",
             "trainer_callback_errors_total",
         )
+        default_registry().declare_counters(*_PROCESS_WIDE)
         self._tracer = tracer
         self._fit_trace: Optional[str] = None
         self._profiler_trigger = profiler_trigger
@@ -340,12 +353,51 @@ class Trainer:
         """``rank_zero_only`` parity (reference ``clm/lightning.py:113``)."""
         return jax.process_index() == 0
 
+    @contextlib.contextmanager
     def _span(self, name: str, **attrs):
-        """A span under this fit's trace, or a no-op when tracing is off —
-        the zero-cost-when-unset contract the chaos hooks follow."""
+        """One phase of the loop, ``trainer.<phase>``: an annotation of that
+        name on the profiler's clock and, with a tracer, a span under this
+        fit's trace (``Tracer.span`` enters the annotation itself). The
+        seconds of the ``_WAITS`` go to ``trainer_<phase>_seconds_total``."""
         if self._tracer is None:
-            return contextlib.nullcontext()
-        return self._tracer.span(name, trace_id=self._fit_trace, **attrs)
+            cm = jax.profiler.TraceAnnotation(name)
+        else:
+            cm = self._tracer.span(name, trace_id=self._fit_trace, **attrs)
+        phase = name.partition(".")[2]
+        t0 = time.perf_counter()
+        try:
+            with cm:
+                yield
+        finally:
+            if phase in _WAITS:
+                self._count(f"trainer_{phase}_seconds_total", time.perf_counter() - t0)
+
+    def _count(self, name: str, value: float = 1.0) -> None:
+        """Add to one of the ``_PROCESS_WIDE`` counters, here and on the
+        process-wide registry."""
+        self.registry.inc(name, value)
+        if self.registry is not default_registry():
+            default_registry().inc(name, value)
+
+    def _write_op_scopes(self, trace_dirs) -> None:
+        """Beside each profiler capture of this ``fit``, ``op_scopes.json``
+        (the model scope, ``op_name``, of each device operation the trace
+        names by its instruction) and ``fused_scopes.json`` (what each
+        fusion holds besides). Lowers and compiles the step once more, which
+        takes about what a warm start spends on it: done once, when the loop
+        has ended, and only where something was captured."""
+        if not trace_dirs or not self.is_main_process:
+            return
+        ledger = default_ledger()
+        tables = {"op_scopes.json": ledger.op_scopes("trainer.step"),
+                  "fused_scopes.json": ledger.fused_scopes("trainer.step")}
+        if not tables["op_scopes.json"]:
+            return
+        for trace_dir in trace_dirs:
+            os.makedirs(trace_dir, exist_ok=True)
+            for name, table in tables.items():
+                with open(os.path.join(trace_dir, name), "w") as f:
+                    json.dump(table, f)
 
     def _count_fault(self, key: str) -> None:
         """Increment one ``fault_stats`` counter and its registry mirror."""
@@ -568,6 +620,11 @@ class Trainer:
             # SIGTERM handler is restored by fit()'s own finally)
             if resume_mgr is not None:
                 resume_mgr.close()
+            # the ledger keeps the step for its scope tables (note_jit):
+            # give back the executable jax.jit cached for it
+            clear_cache = getattr(train_step, "clear_cache", None)
+            if clear_cache is not None:  # a jitted function has one
+                clear_cache()
         return self.state
 
     def _block_ok(self, cfg, start: int, k: int, val_data, resume_mgr) -> bool:
@@ -641,6 +698,9 @@ class Trainer:
     ) -> None:
         window: list = []
         profiling = False
+        announced = False  # the single step, to the ledger (op_scopes)
+        profile_dir = os.path.join(cfg.default_root_dir, "profile")
+        captured: set = set()  # where this fit's profiler captures went
         t0 = time.time()
         if self._tracer is not None:
             self._fit_trace = self._tracer.new_trace_id()
@@ -687,7 +747,7 @@ class Trainer:
                             # dispatch time — fence the block (its cost is
                             # amortized over k_exec steps)
                             jax.block_until_ready(stacked_metrics["loss"])
-                    self.registry.inc("trainer_steps_total", k_exec)
+                    self._count("trainer_steps_total", k_exec)
                     self._record_step_time(
                         (time.perf_counter() - block_t0) * 1e3 / k_exec, trigger
                     )
@@ -706,11 +766,16 @@ class Trainer:
                     batch = shard_or_assemble(
                         batch, self.mesh, shard_seq=cfg.shard_seq
                     )
-                    if cfg.profile_start is not None and step_idx == cfg.profile_start:
-                        jax.profiler.start_trace(
-                            os.path.join(cfg.default_root_dir, "profile")
+                    if not announced:
+                        # shapes only, and before the call donates the state
+                        default_ledger().note_jit(
+                            "trainer.step", train_step, (self.state, batch, step_rng)
                         )
+                        announced = True
+                    if cfg.profile_start is not None and step_idx == cfg.profile_start:
+                        jax.profiler.start_trace(profile_dir)
                         profiling = True
+                        captured.add(profile_dir)
                     prev_state = (
                         self.state if self._policy in ("skip", "rollback") else None
                     )
@@ -730,7 +795,7 @@ class Trainer:
                     # unfenced step span times async dispatch, and the device
                     # work it launched surfaces later under log_flush's value
                     # fetch — readers must not attribute it there
-                    with capture, self._span(
+                    with capture as capture_dir, self._span(
                         "trainer.step", step=step_idx,
                         measures="fenced" if trigger is not None else "dispatch",
                     ):
@@ -743,12 +808,14 @@ class Trainer:
                             # The sync cost is the same one skip/rollback
                             # already pay — the price of opting in.
                             jax.block_until_ready(metrics["loss"])
-                    self.registry.inc("trainer_steps_total")
+                    self._count("trainer_steps_total")
                     self._record_step_time(
                         (time.perf_counter() - step_t0) * 1e3, trigger
                     )
                     per_step = [metrics]
                     n_ran = 1
+                    if capture_dir is not None:
+                        captured.add(capture_dir)
                     if profiling and step_idx >= cfg.profile_start + _PROFILE_WINDOW - 1:
                         jax.block_until_ready(metrics["loss"])
                         jax.profiler.stop_trace()
@@ -919,6 +986,7 @@ class Trainer:
                 step_idx += 1
             if profiling:  # max_steps ended inside the capture window
                 jax.profiler.stop_trace()
+            self._write_op_scopes(captured)
 
     @staticmethod
     def _resume_dir(path: str) -> str:

@@ -10,6 +10,8 @@ before the chip bring-up: a pad-mask BlockSpec that broke the (8, 128) tiling
 rule at batch > 1, and a Mosaic kernel under a multi-device mesh without
 ``shard_map``. Numerical parity of the compiled kernels is ``chip_smoke.py``'s.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -89,6 +91,45 @@ def test_ragged_kernel_lowers_for_tpu(q_len, int8):
     args = (q, pool, pool, table, lengths, scale_k, scale_v)
     assert _mosaic_calls(kernel, *args) == 1
     assert _mosaic_calls(kernel, *args, platform="cpu") == 0
+
+
+def _kernel_names(fn, *args):
+    """Each Mosaic kernel's ``kernel_name`` and the last scope of its
+    location, which the chip's instruction (and the profiler's event) takes
+    its name from."""
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text(debug_info=True)
+    return re.findall(r'kernel_name = "([^"]*)"', lowered.as_text()), set(
+        re.findall(r"branch_0_fun/(\w+)/pallas_call", text)
+    )
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"])
+def test_each_flash_kernel_lowers_under_its_own_name(kernel):
+    q = jnp.zeros((2, 2, 128, 64), jnp.bfloat16)
+    k = v = jnp.zeros((2, 2, 256, 64), jnp.bfloat16)
+    lse = delta = jnp.zeros((2, 2, 128, flash_attention.LANES), jnp.float32)
+    call = {
+        "flash_fwd": lambda q, k, v: flash_attention._forward(q, k, v, None, True),
+        "flash_bwd_dq": lambda q, k, v: flash_attention._backward_dq(
+            q, k, v, None, lse, delta, q, True),
+        "flash_bwd_dkv": lambda q, k, v: flash_attention._backward_dkv(
+            q, k, v, None, lse, delta, q, True),
+    }[kernel]
+    assert _kernel_names(call, q, k, v) == ([kernel], {kernel})
+
+
+def test_ragged_kernel_lowers_under_its_own_name():
+    rows, h, d, bs, pages = 2, 2, 64, 16, 4
+    pool = jnp.zeros(((rows * pages + 1) * bs, h, d), jnp.float32)
+    q = jnp.zeros((rows, h, 1, d), jnp.float32)
+    table, lengths = jnp.zeros((rows, pages), jnp.int32), jnp.zeros((rows,), jnp.int32)
+
+    def kernel(q, pool_k, pool_v, table, lengths):
+        return ragged_paged_attention(q, pool_k, pool_v, table, lengths, block_size=bs)
+
+    names = _kernel_names(kernel, q, pool, pool, table, lengths)
+    assert names == (["ragged_paged_attention"], {"ragged_paged_attention"})
 
 
 @pytest.mark.parametrize(
