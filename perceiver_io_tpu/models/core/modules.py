@@ -178,21 +178,27 @@ class MultiHeadAttention(nn.Module):
     def _finish_q(
         self, q_flat: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding]
     ) -> jnp.ndarray:
-        """Shared post-projection q path (fused and unfused): split heads,
-        scale, then rotate — the reference's order of operations."""
+        """Shared post-projection q path (fused and unfused): scale, then
+        rotate — the reference's order of operations — then split heads."""
         qk, _, _ = self._channels()
-        q = self._split_heads(q_flat) * ((qk // self.num_heads) ** -0.5)
-        if rot_pos_emb is not None:
-            q = rot_pos_emb.rotate(q)
-        return q
+        q_flat = q_flat * ((qk // self.num_heads) ** -0.5)
+        return self._split_heads(self._rotary(q_flat, rot_pos_emb))
 
     def _finish_k(
         self, k_flat: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding]
     ) -> jnp.ndarray:
-        k = self._split_heads(k_flat)
-        if rot_pos_emb is not None:
-            k = rot_pos_emb.rotate(k)
-        return k
+        return self._split_heads(self._rotary(k_flat, rot_pos_emb))
+
+    def _rotary(
+        self, x_flat: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding]
+    ) -> jnp.ndarray:
+        """Rotate the projection's ``(b, n, h * c)`` output as it stands:
+        one elementwise pass over full rows, so the head split is the only
+        relayout between the projection and the kernel."""
+        if rot_pos_emb is None:
+            return x_flat
+        with jax.named_scope("rotary"):
+            return rot_pos_emb.rotate(x_flat, self.num_heads)
 
     def project_kv(
         self, x_kv: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding] = None

@@ -62,8 +62,11 @@ def dot_product_attention(
 
     :param q: ``(b, h, i, ck)`` queries — already multiplied by ``ck**-0.5``
         and rotary-rotated by the caller (mirroring the reference's order of
-        operations, ``modules.py:104-115``).
-    :param k: ``(b, h, j, ck)`` keys (rotary-rotated by caller).
+        operations, ``modules.py:104-115``): ``MultiHeadAttention._finish_q``
+        scales and rotates the projection's ``(b, i, h * ck)`` output before
+        it splits the heads.
+    :param k: ``(b, h, j, ck)`` keys (rotary-rotated by the caller,
+        ``MultiHeadAttention._finish_k``, likewise before the head split).
     :param v: ``(b, h, j, cv)`` values.
     :param pad_mask: optional boolean ``(b, j)``; **True marks padding** (the
         reference's convention, ``modules.py:97``).

@@ -29,7 +29,9 @@ not the sequence length. Matmuls feed the MXU in the input dtype (bf16 in
 training) with float32 accumulation; softmax math is float32 on the VPU.
 
 Queries arrive pre-scaled and pre-rotated (see
-:func:`perceiver_io_tpu.ops.attention.dot_product_attention`).
+:func:`perceiver_io_tpu.ops.attention.dot_product_attention`): the attention
+module finishes q and k on the projections' flat output, under the ``rotary``
+scope, and the head split is the last thing before this kernel.
 """
 from __future__ import annotations
 

@@ -10,9 +10,10 @@ from __future__ import annotations
 import math
 from typing import Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-from flax import struct
+from jax import lax
 
 
 def positions(b: int, n: int, shift: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -37,7 +38,7 @@ def frequency_position_encoding(abs_pos: jnp.ndarray, dim: int) -> jnp.ndarray:
 
     ``inv_freq_i = 10000 ** (-2i/dim)``; each frequency is repeated twice along
     the channel axis so that consecutive channel pairs share a frequency (the
-    pair layout consumed by :func:`rotate_half`). Mirrors reference
+    pair layout :class:`RotaryEmbedding` rotates). Mirrors reference
     ``position.py:53-71``.
 
     :param abs_pos: ``(..., n)`` integer positions.
@@ -50,46 +51,109 @@ def frequency_position_encoding(abs_pos: jnp.ndarray, dim: int) -> jnp.ndarray:
     return jnp.repeat(pos_enc, 2, axis=-1)
 
 
-def rotate_half(x: jnp.ndarray) -> jnp.ndarray:
-    """Channel-pair rotation ``[x1, x2, x3, x4, ...] -> [-x2, x1, -x4, x3, ...]``."""
-    x = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
-    x1, x2 = x[..., 0], x[..., 1]
-    x = jnp.stack((-x2, x1), axis=-1)
-    return x.reshape(*x.shape[:-2], -1)
+def swap_pairs(x: jnp.ndarray) -> jnp.ndarray:
+    """Exchange channels ``2i`` and ``2i+1`` of the last axis:
+    ``[x0, x1, x2, x3, ...] -> [x1, x0, x3, x2, ...]``. Two shifts by one
+    channel and a select on the channel's parity: no ``(..., c/2, 2)`` view,
+    which on the TPU is a relayout of the whole array."""
+    axis = x.ndim - 1
+    keep = [(0, 0, 0)] * axis
+    zero = jnp.zeros((), x.dtype)
+    nxt = lax.pad(x, zero, keep + [(-1, 1, 0)])  # nxt[l] = x[l + 1]
+    prv = lax.pad(x, zero, keep + [(1, -1, 0)])  # prv[l] = x[l - 1]
+    return jnp.where(lax.broadcasted_iota(jnp.int32, x.shape, axis) % 2 == 0, nxt, prv)
 
 
-@struct.dataclass
+@jax.custom_vjp
+def _rotate(t: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """``t * cos + swap_pairs(t) * sin`` with float32 tables of ``t``'s shape:
+    products and sum in float32, one rounding to ``t``'s dtype. The swap moves
+    ``t`` as it is stored and the conversion follows it, so that the pass
+    reads its input once, in its own dtype.
+
+    The backward pass is written out because it is the same pass, ``g``
+    rotated by ``cos`` and the swapped ``sin``: autodiff's transpose of the
+    shifts and the select comes out of XLA as two passes with a float32
+    array of ``t``'s size between them (1.8 of the 8k training step's ms on
+    the cross-attention's keys alone, PERF.md PR 27)."""
+    y = t.astype(jnp.float32) * cos + swap_pairs(t).astype(jnp.float32) * sin
+    return y.astype(t.dtype)
+
+
+def _rotate_fwd(t, cos, sin):
+    return _rotate(t, cos, sin), (t, cos, sin)
+
+
+def _rotate_bwd(residuals, g):
+    t, cos, sin = residuals
+    g32 = g.astype(jnp.float32)
+    return (
+        _rotate(g, cos, swap_pairs(sin)),
+        g32 * t.astype(jnp.float32),
+        g32 * swap_pairs(t).astype(jnp.float32),
+    )
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
+
+
+@jax.tree_util.register_pytree_node_class
 class RotaryEmbedding:
     """Rotary position embedding (RoFormer) applied to the leading
     ``rotate_dim`` channels of q/k heads; remaining channels pass through.
 
-    ``frq_pos_enc`` has shape ``(b, n, rotate_dim)``. When ``right_align`` is
-    set, a shorter input of length ``m < n`` is aligned to the *last* ``m``
-    positions — used by Perceiver AR where latents sit at the sequence tail.
-    Mirrors reference ``position.py:20-50``.
+    Built from ``frq_pos_enc`` of shape ``(b, n, rotate_dim)``, the angles of
+    :func:`frequency_position_encoding` (consecutive channel pairs share one).
+    Rotating the pair ``(x1, x2)`` by its angle ``a`` to
+    ``(x1 cos a - x2 sin a, x2 cos a + x1 sin a)`` is, over a whole head,
+    ``x * cos + swap_pairs(x) * sin`` with ``sin`` signed ``-`` on even and
+    ``+`` on odd channels: the embedding holds these two float32 tables,
+    computed once here for every module that rotates with it. When
+    ``right_align`` is set, a shorter input of length ``m < n`` is aligned to
+    the *last* ``m`` positions — used by Perceiver AR where latents sit at the
+    sequence tail. Mirrors reference ``position.py:20-50``.
     """
 
-    frq_pos_enc: jnp.ndarray
-    right_align: bool = struct.field(pytree_node=False, default=False)
+    def __init__(self, frq_pos_enc: jnp.ndarray, right_align: bool = False):
+        angles = jnp.asarray(frq_pos_enc, jnp.float32)
+        self.cos = jnp.cos(angles)
+        self.sin = jnp.sin(angles) * np.resize(np.float32([-1.0, 1.0]), angles.shape[-1])
+        self.right_align = right_align
+
+    def tree_flatten(self):
+        return (self.cos, self.sin), self.right_align
+
+    @classmethod
+    def tree_unflatten(cls, right_align, tables):
+        self = object.__new__(cls)
+        self.cos, self.sin = tables
+        self.right_align = right_align
+        return self
 
     @property
     def rotate_dim(self) -> int:
-        return self.frq_pos_enc.shape[-1]
+        return self.cos.shape[-1]
 
-    def rotate(self, t: jnp.ndarray) -> jnp.ndarray:
-        """Rotate ``t`` of shape ``(b, h, m, c)`` with ``c >= rotate_dim``."""
-        seq_len = t.shape[-2]
-        pos_enc = self.frq_pos_enc[:, None, :, :]  # (b, 1, n, rd)
-        if self.right_align:
-            pos_enc = pos_enc[..., pos_enc.shape[-2] - seq_len :, :]
+    def _tables(self, seq_len: int, num_channels: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """``(b, seq_len, num_channels)`` tables of one head: the positions
+        this input sits at, 1 and 0 on the channels that pass through."""
+        n = self.cos.shape[-2]
+        at = slice(n - seq_len, n) if self.right_align else slice(0, seq_len)
+        rest = ((0, 0), (0, 0), (0, num_channels - self.rotate_dim))
+        cos = jnp.pad(self.cos[:, at], rest, constant_values=1.0)
+        return cos, jnp.pad(self.sin[:, at], rest)
+
+    def rotate(self, t: jnp.ndarray, num_heads: Optional[int] = None) -> jnp.ndarray:
+        """Rotate heads of ``c >= rotate_dim`` channels: ``t`` is
+        ``(b, h, m, c)`` or, with ``num_heads``, a projection's
+        ``(b, m, h * c)`` output, rotated as it stands (every head by the
+        same tables)."""
+        if num_heads is None:
+            cos, sin = (jnp.broadcast_to(x[:, None], t.shape) for x in self._tables(*t.shape[-2:]))
         else:
-            pos_enc = pos_enc[..., :seq_len, :]
-        pos_enc = pos_enc.astype(jnp.float32)
-        t_rot, t_pass = t[..., : self.rotate_dim], t[..., self.rotate_dim :]
-        t_dtype = t_rot.dtype
-        t_rot = t_rot.astype(jnp.float32)
-        t_rot = t_rot * jnp.cos(pos_enc) + rotate_half(t_rot) * jnp.sin(pos_enc)
-        return jnp.concatenate((t_rot.astype(t_dtype), t_pass), axis=-1)
+            tables = self._tables(t.shape[-2], t.shape[-1] // num_heads)
+            cos, sin = (jnp.tile(x, (1, 1, num_heads)) for x in tables)
+        return _rotate(t, cos, sin)
 
 
 import functools

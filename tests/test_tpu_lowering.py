@@ -196,3 +196,73 @@ def test_flash_under_a_seq_sharded_mesh_is_an_error(devices):
             jax.eval_shape(
                 lambda q: dot_product_attention(q, q, q, causal=True, impl="flash"), q
             )
+
+
+_TENSOR = re.compile(r"tensor<((?:\d+x)+)\w+>")
+_LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"')
+_LOC_USE = re.compile(r"loc\((#loc\d+)\)\s*$")
+_FUNC = re.compile(r"func\.func (?:public |private )?@([\w.]+)\(")
+_CALL = re.compile(r"= (?:func\.)?call @([\w.]+)\(")
+
+
+def test_rotary_lowers_without_a_pair_dimension_or_a_concatenate():
+    """q and k are rotated as the projections' ``(b, n, h * c)`` outputs by
+    shifts and a parity select. The ``(..., c/2, 2)`` view of channel pairs
+    and the ``concatenate`` of rotated and pass-through channels cost the
+    8k training step relayout copies of every q and k (PERF.md, PR 27): no
+    value of the activations' size may end in a dimension of 2, forward or
+    backward, and no ``concatenate`` of that size may sit under ``rotary``."""
+    from perceiver_io_tpu.models.core.modules import MultiHeadAttention
+    from perceiver_io_tpu.ops.position import RotaryEmbedding, frequency_position_encoding
+
+    b, n, h, c = 2, 128, 2, 64
+    mha = MultiHeadAttention(
+        num_heads=h, num_q_input_channels=h * c, num_kv_input_channels=h * c,
+        causal_attention=True, dtype=jnp.bfloat16, attention_impl="flash",
+    )
+    x = jnp.zeros((b, n, h * c), jnp.bfloat16)
+    frq = frequency_position_encoding(jnp.broadcast_to(jnp.arange(n), (b, n)), c // 2)
+    params = mha.init(jax.random.PRNGKey(0), x, x)
+
+    def loss(params, x, frq):
+        rot = RotaryEmbedding(frq, right_align=True)
+        out = mha.apply(params, x, x, rot_pos_emb_q=rot, rot_pos_emb_k=rot)
+        return jnp.sum(out.astype(jnp.float32))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, x, frq).lower(
+        lowering_platforms=("tpu",)
+    )
+    lines = lowered.as_text(debug_info=True).splitlines()
+    scope_of = dict(m.groups() for m in map(_LOC_DEF.match, lines) if m)
+    assert any("/rotary/" in scope for scope in scope_of.values())  # the pass is there, by name
+    large = b * n * h * c // 2
+
+    def shapes(text):
+        return [tuple(int(d) for d in dims.split("x")[:-1]) for dims in _TENSOR.findall(text)]
+
+    paired = {s for line in lines for s in shapes(line) if s[-1] == 2 and np.prod(s) >= large}
+    assert not paired, f"activation-sized values with a trailing pair dimension: {paired}"
+
+    # an operation is under ``rotary`` by its own location, or by that of a
+    # call into the function that holds it (a jitted helper, ``jnp.roll``'s say,
+    # is lowered to a function of its own whose locations start afresh)
+    function, ops, calls = None, [], []
+    for line in lines:
+        opened = _FUNC.search(line)
+        function = opened.group(1) if opened else function
+        use = _LOC_USE.search(line)
+        scope = scope_of.get(use.group(1), "") if use else ""
+        called = _CALL.search(line)
+        if called:
+            calls.append((function, called.group(1), scope))
+        elif "stablehlo.concatenate" in line:
+            ops.append((function, scope, line))
+    under = set()
+    while True:
+        more = {to for frm, to, scope in calls if "/rotary/" in scope or frm in under} - under
+        if not more:
+            break
+        under |= more
+    for function, scope, line in ops:
+        size = np.prod(shapes(line.rsplit("->", 1)[-1])[0])
+        assert not (size >= large and ("/rotary/" in scope or function in under)), line
