@@ -20,6 +20,7 @@ def import_task_modules() -> None:
     import perceiver_io_tpu.models.audio.symbolic  # noqa: F401
     import perceiver_io_tpu.models.text.classifier  # noqa: F401
     import perceiver_io_tpu.models.text.clm  # noqa: F401
+    import perceiver_io_tpu.models.text.lm  # noqa: F401
     import perceiver_io_tpu.models.text.mlm  # noqa: F401
     import perceiver_io_tpu.models.vision.image_classifier  # noqa: F401
     import perceiver_io_tpu.models.vision.optical_flow  # noqa: F401
@@ -39,6 +40,7 @@ def model_for_config(config: Any, *, dtype=None, attention_impl: str = "auto"):
     from perceiver_io_tpu.models.text.clm import CausalLanguageModel, CausalLanguageModelConfig
     from perceiver_io_tpu.models.text.classifier import TextClassifier
     from perceiver_io_tpu.models.text.common import TextEncoderConfig
+    from perceiver_io_tpu.models.text.lm import DecoderLM, DecoderLMConfig
     from perceiver_io_tpu.models.text.mlm import MaskedLanguageModel, TextDecoderConfig
     from perceiver_io_tpu.models.vision.image_classifier import ImageClassifier, ImageEncoderConfig
     from perceiver_io_tpu.models.vision.optical_flow import OpticalFlow, OpticalFlowEncoderConfig
@@ -50,6 +52,8 @@ def model_for_config(config: Any, *, dtype=None, attention_impl: str = "auto"):
         return CausalLanguageModel(config, **kwargs)
     if isinstance(config, SymbolicAudioModelConfig):
         return SymbolicAudioModel(config, **kwargs)
+    if isinstance(config, DecoderLMConfig):
+        return DecoderLM(config, **kwargs)
     if isinstance(config, PerceiverIOConfig):
         enc, dec = config.encoder, config.decoder
         if isinstance(enc, ImageEncoderConfig):

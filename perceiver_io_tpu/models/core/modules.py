@@ -52,6 +52,22 @@ def _layer_norm(dtype, name: str) -> nn.LayerNorm:
     return nn.LayerNorm(epsilon=LAYER_NORM_EPS, dtype=dtype, name=name, use_fast_variance=False)
 
 
+class RMSNorm(nn.Module):
+    """``x * rsqrt(mean(x ** 2) + eps) * scale`` over the last axis, no mean
+    taken off and no bias. Statistics and the gain in float32, one rounding
+    to ``dtype``."""
+
+    epsilon: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + self.epsilon) * scale).astype(self.dtype)
+
+
 def fused_qkv_enabled() -> bool:
     """``PERCEIVER_FUSED_QKV=1`` merges same-input q/k/v (self-attention) and
     k/v (cross-attention) projections into single wider matmuls. Like the
@@ -128,6 +144,14 @@ class MultiHeadAttention(nn.Module):
     embeddings and causal attention over right-aligned q/kv.
 
     Reference: ``perceiver/model/core/modules.py:19-154``.
+
+    ``num_kv_heads`` (a divisor of ``num_heads``) is grouped-query attention:
+    k and v are projected to that many heads of the same size, and query head
+    ``n`` reads key-value head ``n // (num_heads // num_kv_heads)``; the
+    attention paths index the shared head and repeat nothing. ``qk_norm``
+    puts an :class:`RMSNorm` over each head's channels of q and of k (one
+    gain per channel, shared by the heads) before the scale and the rotation.
+    Neither changes the program of a module that leaves them unset.
     """
 
     num_heads: int
@@ -144,6 +168,9 @@ class MultiHeadAttention(nn.Module):
     init_scale: float = 0.02
     dtype: Any = jnp.float32
     attention_impl: str = "auto"
+    num_kv_heads: Optional[int] = None
+    qk_norm: bool = False
+    norm_eps: float = 1e-5
 
     def _channels(self) -> Tuple[int, int, int]:
         qk = self.num_qk_channels or self.num_q_input_channels
@@ -153,18 +180,34 @@ class MultiHeadAttention(nn.Module):
             raise ValueError("num_qk_channels must be divisible by num_heads")
         if v % self.num_heads != 0:
             raise ValueError("num_v_channels must be divisible by num_heads")
+        if self.num_heads % self._kv_heads != 0:
+            raise ValueError("num_heads must be divisible by num_kv_heads")
         return qk, v, out
+
+    @property
+    def _kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
 
     def setup(self):
         qk, v, out = self._channels()
+        shared = self.num_heads // self._kv_heads  # query heads a key-value head
         self.q_proj = _dense(qk, self.qkv_bias, self.init_scale, self.dtype, "q_proj")
-        self.k_proj = _dense(qk, self.qkv_bias, self.init_scale, self.dtype, "k_proj")
-        self.v_proj = _dense(v, self.qkv_bias, self.init_scale, self.dtype, "v_proj")
+        self.k_proj = _dense(qk // shared, self.qkv_bias, self.init_scale, self.dtype, "k_proj")
+        self.v_proj = _dense(v // shared, self.qkv_bias, self.init_scale, self.dtype, "v_proj")
         self.o_proj = _dense(out, self.out_bias, self.init_scale, self.dtype, "o_proj")
+        if self.qk_norm:
+            self.q_norm = RMSNorm(self.norm_eps, self.dtype, name="q_norm")
+            self.k_norm = RMSNorm(self.norm_eps, self.dtype, name="k_norm")
 
-    def _split_heads(self, x: jnp.ndarray) -> jnp.ndarray:
+    def _split_heads(self, x: jnp.ndarray, num_heads: Optional[int] = None) -> jnp.ndarray:
         b, n, _ = x.shape
-        return x.reshape(b, n, self.num_heads, -1).transpose(0, 2, 1, 3)
+        return x.reshape(b, n, num_heads or self.num_heads, -1).transpose(0, 2, 1, 3)
+
+    @staticmethod
+    def _norm_heads(norm: RMSNorm, x_flat: jnp.ndarray, num_heads: int) -> jnp.ndarray:
+        """``norm`` over each head's channels of a projection's flat output."""
+        b, n, _ = x_flat.shape
+        return norm(x_flat.reshape(b, n, num_heads, -1)).reshape(x_flat.shape)
 
     def _merge_heads(self, x: jnp.ndarray) -> jnp.ndarray:
         b, h, n, c = x.shape
@@ -181,16 +224,21 @@ class MultiHeadAttention(nn.Module):
         """Shared post-projection q path (fused and unfused): scale, then
         rotate — the reference's order of operations — then split heads."""
         qk, _, _ = self._channels()
+        if self.qk_norm:
+            q_flat = self._norm_heads(self.q_norm, q_flat, self.num_heads)
         q_flat = q_flat * ((qk // self.num_heads) ** -0.5)
         return self._split_heads(self._rotary(q_flat, rot_pos_emb))
 
     def _finish_k(
         self, k_flat: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding]
     ) -> jnp.ndarray:
-        return self._split_heads(self._rotary(k_flat, rot_pos_emb))
+        if self.qk_norm:
+            k_flat = self._norm_heads(self.k_norm, k_flat, self._kv_heads)
+        return self._split_heads(self._rotary(k_flat, rot_pos_emb, self._kv_heads), self._kv_heads)
 
     def _rotary(
-        self, x_flat: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding]
+        self, x_flat: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding],
+        num_heads: Optional[int] = None,
     ) -> jnp.ndarray:
         """Rotate the projection's ``(b, n, h * c)`` output as it stands:
         one elementwise pass over full rows, so the head split is the only
@@ -198,7 +246,7 @@ class MultiHeadAttention(nn.Module):
         if rot_pos_emb is None:
             return x_flat
         with jax.named_scope("rotary"):
-            return rot_pos_emb.rotate(x_flat, self.num_heads)
+            return rot_pos_emb.rotate(x_flat, num_heads or self.num_heads)
 
     def project_kv(
         self, x_kv: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding] = None
@@ -217,11 +265,11 @@ class MultiHeadAttention(nn.Module):
             # identical to the separate projections (same per-element dot
             # products).
             kv = self._fused_dense((self.k_proj, self.v_proj), x_kv)
-            qk, _, _ = self._channels()
-            k_flat, v_flat = kv[..., :qk], kv[..., qk:]
+            k_width = self._channels()[0] // (self.num_heads // self._kv_heads)
+            k_flat, v_flat = kv[..., :k_width], kv[..., k_width:]
         else:
             k_flat, v_flat = self.k_proj(x_kv), self.v_proj(x_kv)
-        return self._finish_k(k_flat, rot_pos_emb), self._split_heads(v_flat)
+        return self._finish_k(k_flat, rot_pos_emb), self._split_heads(v_flat, self._kv_heads)
 
     def _fused_dense(self, projs, x: jnp.ndarray) -> jnp.ndarray:
         """Apply several same-input Dense submodules as one matmul over their
@@ -284,10 +332,11 @@ class MultiHeadAttention(nn.Module):
             and not self.is_initializing()
         ):
             qk, _, _ = self._channels()
+            k_end = qk + qk // (self.num_heads // self._kv_heads)
             qkv = self._fused_dense((self.q_proj, self.k_proj, self.v_proj), x_q)
             q = self._finish_q(qkv[..., :qk], rot_pos_emb_q)
-            k = self._finish_k(qkv[..., qk:2 * qk], rot_pos_emb_k)
-            v = self._split_heads(qkv[..., 2 * qk:])
+            k = self._finish_k(qkv[..., qk:k_end], rot_pos_emb_k)
+            v = self._split_heads(qkv[..., k_end:], self._kv_heads)
             return self.attend(q, k, v, pad_mask=pad_mask, deterministic=deterministic)
         q = self.project_q(x_q, rot_pos_emb_q)
         k, v = self.project_kv(x_kv, rot_pos_emb_k)

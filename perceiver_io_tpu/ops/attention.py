@@ -65,9 +65,11 @@ def dot_product_attention(
         operations, ``modules.py:104-115``): ``MultiHeadAttention._finish_q``
         scales and rotates the projection's ``(b, i, h * ck)`` output before
         it splits the heads.
-    :param k: ``(b, h, j, ck)`` keys (rotary-rotated by the caller,
+    :param k: ``(b, hk, j, ck)`` keys (rotary-rotated by the caller,
         ``MultiHeadAttention._finish_k``, likewise before the head split).
-    :param v: ``(b, h, j, cv)`` values.
+        ``hk`` may divide ``h`` (grouped-query attention): query head ``n``
+        reads key-value head ``n // (h // hk)``; neither path repeats k or v.
+    :param v: ``(b, hk, j, cv)`` values.
     :param pad_mask: optional boolean ``(b, j)``; **True marks padding** (the
         reference's convention, ``modules.py:97``).
     :param causal: apply right-aligned causal masking.
@@ -117,6 +119,8 @@ def dot_product_attention(
             _count_einsum_fallback(q, k, v, causal)
 
     num_heads = q.shape[1]
+    if k.shape[1] != num_heads:
+        return _attention_xla_grouped(q, k, v, pad_mask, causal, dropout_rate, dropout_rng)
     if max_heads_parallel is None or max_heads_parallel >= num_heads:
         return _attention_xla(q, k, v, pad_mask, causal, dropout_rate, dropout_rng)
 
@@ -167,7 +171,8 @@ def _flash_over_mesh(q, k, v, pad_mask, causal):
         return axes if axes and size % shards == 0 else None
 
     batch_ax = dividing(BATCH_AXES, q.shape[0])
-    head_ax = dividing((AXIS_MODEL,), q.shape[1])
+    # grouped heads: a shard of query heads needs its own key-value heads
+    head_ax = dividing((AXIS_MODEL,), k.shape[1])
     qkv_spec = P(batch_ax, head_ax, None, None)
     args, in_specs = (q, k, v), (qkv_spec,) * 3
     if pad_mask is not None:
@@ -234,6 +239,7 @@ def _attention_xla(
     causal: bool,
     dropout_rate: float,
     dropout_rng: Optional[jax.Array],
+    causal_rows: Optional[int] = None,
 ) -> jnp.ndarray:
     i, j = q.shape[-2], k.shape[-2]
     logits = jnp.einsum("bhic,bhjc->bhij", q, k, preferred_element_type=jnp.float32)
@@ -244,6 +250,11 @@ def _attention_xla(
     if causal:
         allowed = jnp.arange(j)[None, :] <= jnp.arange(i)[:, None] + (j - i)
         logits = jnp.where(allowed[None, None], logits, _mask_value())
+    elif causal_rows is not None:
+        # grouped heads folded into the rows: row r is position r % causal_rows
+        pos = jnp.arange(i) % causal_rows
+        allowed = jnp.arange(j)[None, :] <= pos[:, None] + (j - causal_rows)
+        logits = jnp.where(allowed[None, None], logits, _mask_value())
 
     attn = jax.nn.softmax(logits, axis=-1)
     if dropout_rate > 0.0 and dropout_rng is not None:
@@ -251,3 +262,19 @@ def _attention_xla(
         attn = jnp.where(keep, attn / (1.0 - dropout_rate), 0.0)
     attn = attn.astype(v.dtype)
     return jnp.einsum("bhij,bhjc->bhic", attn, v)
+
+
+def _attention_xla_grouped(q, k, v, pad_mask, causal, dropout_rate, dropout_rng):
+    """The einsum path with fewer key-value heads than query heads: the
+    query heads are viewed ``(b, hk, h // hk, i, c)`` and each group
+    contracts with its one key-value head, so k and v keep ``hk`` heads."""
+    b, h, i, c = q.shape
+    hk = k.shape[1]
+    if h % hk:
+        raise ValueError(f"{h} query heads are not a multiple of {hk} key-value heads")
+    # fold the group into the query rows: (b, hk, g * i, c) against (b, hk, j, c)
+    o = _attention_xla(
+        q.reshape(b, hk, (h // hk) * i, c), k, v, pad_mask, False, dropout_rate, dropout_rng,
+        causal_rows=i if causal else None,
+    )
+    return o.reshape(b, h, i, v.shape[-1])

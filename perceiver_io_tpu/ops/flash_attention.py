@@ -105,6 +105,8 @@ def supported(q, k, v, *, causal: bool) -> bool:
         return False
     if q.dtype != k.dtype or q.dtype != v.dtype:
         return False
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        return False  # grouped heads: each key-value head serves h / hk query heads
     i, j = q.shape[2], k.shape[2]
     if causal and j < i:
         return False
@@ -128,8 +130,11 @@ def flash_attention(
     """Flash attention with Perceiver masking semantics.
 
     :param q: ``(b, h, i, d)`` pre-scaled queries.
-    :param k: ``(b, h, j, d)`` keys.
-    :param v: ``(b, h, j, dv)`` values.
+    :param k: ``(b, hk, j, d)`` keys; ``hk`` divides ``h`` and query head
+        ``n`` reads key-value head ``n // (h // hk)`` (grouped-query
+        attention). The kernels index the shared head from their grid: no
+        key or value array is ever repeated to ``h`` heads.
+    :param v: ``(b, hk, j, dv)`` values.
     :param pad_mask: optional boolean ``(b, j)``, True marks padding.
     :param causal: right-aligned causal masking (offset ``j - i``).
 
@@ -203,10 +208,26 @@ def _maybe_when(run, body):
         pl.when(run)(body)
 
 
-def _qk_spec(bi, d, by_dim2=True):
+def _qk_spec(bi, d, by_dim2=True, group: int = 1):
+    """Blocks of ``bi`` rows walking grid dim 2 (or 3). ``group`` > 1 is a
+    key or value array of grouped heads under a grid over the query heads:
+    grid head ``h_`` reads head ``h_ // group``. Ungrouped calls keep the
+    index maps they had (no ``// 1``), so their kernels compile as before."""
+    if group > 1:
+        if by_dim2:
+            return pl.BlockSpec((1, 1, bi, d), lambda b_, h_, x_, y_: (b_, h_ // group, x_, 0))
+        return pl.BlockSpec((1, 1, bi, d), lambda b_, h_, x_, y_: (b_, h_ // group, y_, 0))
     if by_dim2:
         return pl.BlockSpec((1, 1, bi, d), lambda b_, h_, x_, y_: (b_, h_, x_, 0))
     return pl.BlockSpec((1, 1, bi, d), lambda b_, h_, x_, y_: (b_, h_, y_, 0))
+
+
+def _group_q_spec(bi, d, group: int, ni: int):
+    """A query-side array under the dK/dV kernel's grid over the key-value
+    heads: grid dim 3 walks the ``group`` query heads of a key-value head,
+    ``ni`` row blocks each."""
+    return pl.BlockSpec(
+        (1, 1, bi, d), lambda b_, h_, x_, y_: (b_, h_ * group + y_ // ni, y_ % ni, 0))
 
 
 def _pad_spec(bj, by_dim2=False):
@@ -223,6 +244,7 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
 def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, h, i, d = q.shape
     j, dv = k.shape[2], v.shape[3]
+    group = h // k.shape[1]
     bi, bj = _pick_block(i), _pick_block(j)
     offset = j - i
     nj = j // bj
@@ -281,8 +303,8 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
     in_specs = [
         _qk_spec(bi, d, by_dim2=True),
-        _qk_spec(bj, d, by_dim2=False),
-        _qk_spec(bj, dv, by_dim2=False),
+        _qk_spec(bj, d, by_dim2=False, group=group),
+        _qk_spec(bj, dv, by_dim2=False, group=group),
     ]
     args = [q, k, v]
     if has_pad:
@@ -316,6 +338,7 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def _backward_dq(q, k, v, pad, lse, delta, do, causal):
     b, h, i, d = q.shape
     j, dv = k.shape[2], v.shape[3]
+    group = h // k.shape[1]
     bi, bj = _pick_block(i), _pick_block(j)
     offset = j - i
     nj = j // bj
@@ -364,8 +387,8 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
 
     in_specs = [
         _qk_spec(bi, d, by_dim2=True),
-        _qk_spec(bj, d, by_dim2=False),
-        _qk_spec(bj, dv, by_dim2=False),
+        _qk_spec(bj, d, by_dim2=False, group=group),
+        _qk_spec(bj, dv, by_dim2=False, group=group),
     ]
     args = [q, k, v]
     if has_pad:
@@ -393,23 +416,26 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
 
 def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
     b, h, i, d = q.shape
-    j, dv = k.shape[2], v.shape[3]
+    hk, j, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = h // hk
     bi, bj = _pick_block(i), _pick_block(j)
     offset = j - i
     ni = i // bi
     has_pad = pad is not None
 
-    # Grid dim 2 walks kv blocks, dim 3 walks q blocks (innermost, so the
-    # dk/dv accumulators carry across q blocks).
+    # Grid dim 1 walks the key-value heads, dim 2 kv blocks, dim 3 the q
+    # blocks of every query head that shares the key-value head (innermost,
+    # so the dk/dv accumulators carry across q blocks and across the group).
     def kernel(q_ref, k_ref, v_ref, *rest):
         if has_pad:
             pad_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
         else:
             lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
             pad_ref = None
-        j_idx, i_idx = pl.program_id(2), pl.program_id(3)
+        j_idx, t_idx = pl.program_id(2), pl.program_id(3)
+        i_idx = t_idx if group == 1 else t_idx % ni
 
-        @pl.when(i_idx == 0)
+        @pl.when(t_idx == 0)
         def _():
             dk_sc[:] = jnp.zeros_like(dk_sc)
             dv_sc[:] = jnp.zeros_like(dv_sc)
@@ -443,13 +469,17 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
 
         _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal), body)
 
-        @pl.when(i_idx == ni - 1)
+        @pl.when(t_idx == group * ni - 1)
         def _():
             dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
 
+    if group == 1:
+        q_side = lambda width: _qk_spec(bi, width, by_dim2=False)  # q blocks walk grid dim 3
+    else:
+        q_side = lambda width: _group_q_spec(bi, width, group, ni)
     in_specs = [
-        _qk_spec(bi, d, by_dim2=False),   # q blocks walk grid dim 3
+        q_side(d),
         _qk_spec(bj, d, by_dim2=True),    # k blocks walk grid dim 2
         _qk_spec(bj, dv, by_dim2=True),
     ]
@@ -457,26 +487,22 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
     if has_pad:
         in_specs.append(_pad_spec(bj, by_dim2=True))
         args.append(pad)
-    in_specs += [
-        _qk_spec(bi, LANES, by_dim2=False),
-        _qk_spec(bi, LANES, by_dim2=False),
-        _qk_spec(bi, dv, by_dim2=False),
-    ]
+    in_specs += [q_side(LANES), q_side(LANES), q_side(dv)]
     args += [lse, delta, do]
 
     return pallas_call_on_lowering_platform(
         kernel,
         *args,
         name="flash_bwd_dkv",
-        grid=(b, h, j // bj, ni),
+        grid=(b, hk, j // bj, group * ni),
         in_specs=in_specs,
         out_specs=[
             _qk_spec(bj, d, by_dim2=True),
             _qk_spec(bj, dv, by_dim2=True),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, j, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, j, dv), v.dtype),
+            jax.ShapeDtypeStruct((b, hk, j, d), k.dtype),
+            jax.ShapeDtypeStruct((b, hk, j, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bj, d), jnp.float32),

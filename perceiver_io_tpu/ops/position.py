@@ -33,10 +33,12 @@ def positions(b: int, n: int, shift: Optional[jnp.ndarray] = None) -> jnp.ndarra
     return jnp.maximum(pos, 0)
 
 
-def frequency_position_encoding(abs_pos: jnp.ndarray, dim: int) -> jnp.ndarray:
+def frequency_position_encoding(
+    abs_pos: jnp.ndarray, dim: int, theta: float = 10000.0
+) -> jnp.ndarray:
     """Inverse-frequency encoding of absolute positions (rotary frequencies).
 
-    ``inv_freq_i = 10000 ** (-2i/dim)``; each frequency is repeated twice along
+    ``inv_freq_i = theta ** (-2i/dim)``; each frequency is repeated twice along
     the channel axis so that consecutive channel pairs share a frequency (the
     pair layout :class:`RotaryEmbedding` rotates). Mirrors reference
     ``position.py:53-71``.
@@ -45,7 +47,7 @@ def frequency_position_encoding(abs_pos: jnp.ndarray, dim: int) -> jnp.ndarray:
     :param dim: number of rotated channels (even).
     :return: ``(..., n, dim)`` float32 angles ``pos * inv_freq``.
     """
-    inv_freq = 1.0 / (10000 ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     pos_enc = abs_pos.astype(jnp.float32)[..., None] * inv_freq
     # [f0, f0, f1, f1, ...] pairing, matching the reference's (pf r) repeat.
     return jnp.repeat(pos_enc, 2, axis=-1)

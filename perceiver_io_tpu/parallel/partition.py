@@ -44,7 +44,19 @@ _TP_KERNEL_RULES: Tuple[Tuple[str, int], ...] = (
     (r"o_proj/kernel$", 0),
     (r"mlp/hidden/kernel$", 1),
     (r"mlp/out/kernel$", 0),
+    # gated MLP (models/core/hybrid.py): gate and up by column, down by row
+    (r"mlp/(gate|up)/kernel$", 1),
+    (r"mlp/down/kernel$", 0),
+    # stacked expert weights (experts, in, out): the experts' hidden width,
+    # never the expert dimension
+    (r"moe/(gate|up)$", 2),
+    (r"moe/down$", 1),
 )
+
+# Stacked expert weights: dim 0 counts experts, and a shard of it would be
+# another placement of experts than the layer was told it holds
+# (SparseExperts.expert_offset): neither ``model`` nor ``fsdp`` splits it.
+_STACKED_EXPERTS = r"moe/(gate|up|down)$"
 
 # Biases of column-parallel layers follow their kernel's output sharding;
 # row-parallel biases stay replicated (added after the allreduce).
@@ -85,6 +97,8 @@ def infer_param_spec(
     fsdp_size = mesh.shape.get(AXIS_FSDP, 1)
     if fsdp_size > 1 and np.size(value) >= min_fsdp_size:
         dims = sorted(range(len(shape)), key=lambda d: shape[d], reverse=True)
+        if re.search(_STACKED_EXPERTS, path):
+            dims = [d for d in dims if d != 0]
         for d in dims:
             if spec[d] is None and shape[d] % fsdp_size == 0:
                 spec[d] = AXIS_FSDP

@@ -1165,6 +1165,16 @@ class CLI:
             model = model_for_config(model_cfg)
             from perceiver_io_tpu.models.text.clm import CausalLanguageModel
 
+            from perceiver_io_tpu.models.text.lm import DecoderLM
+
+            if isinstance(model, DecoderLM):
+                # the engines hold Perceiver AR's caches: no convolution
+                # state, no grouped heads, no expert layer (docs/lm.md)
+                raise SystemExit(
+                    "serve does not take the lm family yet: the serving engines "
+                    "cache Perceiver AR's latents and keys only (docs/lm.md); "
+                    f"got a {type(model).__name__} checkpoint"
+                )
             if not isinstance(model, CausalLanguageModel):
                 # The decode side is the byte tokenizer; a non-text AR family
                 # (e.g. symbolic audio) would sample ids the tokenizer cannot

@@ -61,6 +61,26 @@ def clm_loss_fn(model, max_latents: int) -> Callable:
     return loss_fn
 
 
+def lm_loss_fn(model) -> Callable:
+    """Decoder-only LM step (``models/text/lm.py``): next-token CE over every
+    position, pad labels ignored. With expert layers the step's metrics carry
+    ``moe_assignments_held`` and ``moe_expert_load_max_over_mean``, which the
+    trainer logs and sets as gauges ``trainer_moe_*`` (docs/observability.md)."""
+
+    def loss_fn(params, batch, rng):
+        labels = batch["labels"]
+        pad_mask = batch.get("pad_mask")
+        if pad_mask is not None:
+            labels = jnp.where(pad_mask, IGNORE_INDEX, labels)
+        logits, stats = model.apply(
+            {"params": params}, batch["input_ids"], pad_mask=pad_mask, return_stats=True
+        )
+        loss = masked_cross_entropy(logits, labels)
+        return loss, stats if model.config.has_experts else {}
+
+    return loss_fn
+
+
 def mlm_loss_fn(model) -> Callable:
     """Masked-LM step: CE over all positions, unmasked labels = -100
     (reference ``perceiver/model/text/mlm/lightning.py:57-62``)."""

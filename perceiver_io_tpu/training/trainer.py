@@ -379,6 +379,12 @@ class Trainer:
         if self.registry is not default_registry():
             default_registry().inc(name, value)
 
+    def _gauge(self, name: str, value: float) -> None:
+        """Set a gauge here and on the process-wide registry."""
+        self.registry.set_gauge(name, value)
+        if self.registry is not default_registry():
+            default_registry().set_gauge(name, value)
+
     def _write_op_scopes(self, trace_dirs) -> None:
         """Beside each profiler capture of this ``fit``, ``op_scopes.json``
         (the model scope, ``op_name``, of each device operation the trace
@@ -719,8 +725,50 @@ class Trainer:
                 grad_accum_steps=cfg.grad_accum_steps,
                 multi_steps=k_exec,
             )
-        with self.mesh:
-            step_idx = start_step
+
+        def flush_window(step_idx):
+            nonlocal window, t0
+            with self._span("trainer.log_flush", step=step_idx):
+                mean = {
+                    k: float(np.mean([float(m[k]) for m in window]))
+                    for k in window[0]
+                }
+                if self.lr_schedule is not None:
+                    mean["lr"] = float(self.lr_schedule(step_idx))
+                mean["steps_per_sec"] = len(window) / (time.time() - t0)
+                for k, v in mean.items():
+                    if np.isfinite(v):
+                        self._gauge(f"trainer_{k}", v)
+                self.log_metrics(step_idx, mean, prefix="train/")
+            if self._snapshot_writer is not None:
+                self._snapshot_writer.maybe_write()
+            window, t0 = [], time.time()
+            if self._policy == "halt" and not np.isfinite(
+                mean.get("loss", 0.0)
+            ):
+                raise FloatingPointError(
+                    f"train loss went non-finite at step {step_idx} "
+                    f"({mean['loss']}); halting — resume from the last "
+                    "snapshot with a lower lr / grad clip, or set "
+                    "non_finite_policy=skip|rollback to recover in place"
+                )
+
+        @contextlib.contextmanager
+        def flushing_the_rest():
+            """However the loop ends, the steps since its last flush are
+            logged too; an error that ended it is the one raised."""
+            try:
+                yield
+            except BaseException:
+                with contextlib.suppress(Exception):
+                    if window:
+                        flush_window(last_step)
+                raise
+            if window:
+                flush_window(last_step)
+
+        with self.mesh, flushing_the_rest():
+            step_idx = last_step = start_step
             while step_idx <= cfg.max_steps:
                 if multi_step is not None and self._block_ok(
                     cfg, step_idx, k_exec, val_data, resume_mgr
@@ -878,42 +926,14 @@ class Trainer:
                 for m in per_step:
                     window.append(m)
                 step_idx += n_ran - 1  # bookkeeping below runs at the block's last step
-
-                def flush_window(step_idx=step_idx):
-                    nonlocal window, t0
-                    with self._span("trainer.log_flush", step=step_idx):
-                        mean = {
-                            k: float(np.mean([float(m[k]) for m in window]))
-                            for k in window[0]
-                        }
-                        if self.lr_schedule is not None:
-                            mean["lr"] = float(self.lr_schedule(step_idx))
-                        mean["steps_per_sec"] = len(window) / (time.time() - t0)
-                        self.registry.set_gauge(
-                            "trainer_steps_per_sec", mean["steps_per_sec"]
-                        )
-                        if "loss" in mean and np.isfinite(mean["loss"]):
-                            self.registry.set_gauge("trainer_loss", mean["loss"])
-                        self.log_metrics(step_idx, mean, prefix="train/")
-                    if self._snapshot_writer is not None:
-                        self._snapshot_writer.maybe_write()
-                    window, t0 = [], time.time()
-                    if self._policy == "halt" and not np.isfinite(
-                        mean.get("loss", 0.0)
-                    ):
-                        raise FloatingPointError(
-                            f"train loss went non-finite at step {step_idx} "
-                            f"({mean['loss']}); halting — resume from the last "
-                            "snapshot with a lower lr / grad clip, or set "
-                            "non_finite_policy=skip|rollback to recover in place"
-                        )
+                last_step = step_idx
 
                 if (
                     window
                     and step_idx % cfg.log_every_n_steps < n_ran
                     and step_idx >= cfg.log_every_n_steps
                 ):
-                    flush_window()
+                    flush_window(step_idx)
 
                 if resume_mgr is not None and (
                     step_idx % cfg.save_state_every_n_steps == 0
@@ -948,7 +968,7 @@ class Trainer:
 
                 if val_data is not None and step_idx % cfg.val_check_interval == 0:
                     if window:  # flush partial window so steps_per_sec stays honest
-                        flush_window()
+                        flush_window(step_idx)
                     val_metrics = self.validate(val_data())
                     self.log_metrics(step_idx, val_metrics, prefix="val/")
                     if self._ckpt is not None and "loss" in val_metrics:

@@ -544,7 +544,8 @@ VOCAB, SEQ, LATENTS = 29, 16, 8
 
 
 def _tr_fit(root, max_steps, *, registry=None, tracer=None,
-            profiler_trigger=None, snapshot_writer=None, **cfg_kwargs):
+            profiler_trigger=None, snapshot_writer=None, stream=lambda batches: batches,
+            **cfg_kwargs):
     import optax
 
     from perceiver_io_tpu.parallel import MeshConfig, make_mesh
@@ -586,9 +587,41 @@ def _tr_fit(root, max_steps, *, registry=None, tracer=None,
             jnp.zeros((1, SEQ), jnp.int32), SEQ - LATENTS,
         )["params"]
 
-    state = trainer.fit(init_params, batches)
-    trainer.close()
+    try:
+        state = trainer.fit(init_params, stream(batches))
+    finally:
+        trainer.close()
     return state, trainer
+
+
+class _StreamEnded(Exception):
+    pass
+
+
+def _three_then_raise(batches):
+    yield from batches[:3]
+    raise _StreamEnded
+
+
+@pytest.mark.parametrize("ends", ["at_max_steps", "stream_raises"])
+def test_trainer_flushes_the_steps_it_holds_when_the_loop_ends(tmp_path, ends):
+    """Cadence 2, three steps: the third is logged too, whether ``max_steps``
+    or the data stream's own exception ends the loop, and every scalar of a
+    flush is a gauge ``trainer_<name>``, here and process-wide."""
+    from perceiver_io_tpu.observability import default_registry
+
+    registry = MetricsRegistry()
+    if ends == "at_max_steps":
+        _tr_fit(tmp_path, 3, registry=registry)
+    else:
+        with pytest.raises(_StreamEnded):
+            _tr_fit(tmp_path, 10, registry=registry, stream=_three_then_raise)
+    with open(tmp_path / "metrics.jsonl") as fh:
+        rows = [json.loads(line) for line in fh]
+    assert [r["step"] for r in rows if "train/loss" in r] == [2, 3]
+    for name in ("trainer_loss", "trainer_steps_per_sec"):
+        assert registry.gauge(name) == default_registry().snapshot()["gauges"][name]
+    assert registry.gauge("trainer_loss") == pytest.approx(rows[-1]["train/loss"])
 
 
 @pytest.mark.slow

@@ -200,3 +200,75 @@ def test_mlm_tp_shards_encoder_and_decoder_heads():
     ):
         assert block["q_proj"]["kernel"] == jax.sharding.PartitionSpec(None, AXIS_MODEL)
         assert block["o_proj"]["kernel"] == jax.sharding.PartitionSpec(AXIS_MODEL, None)
+
+
+def _lm_param_shapes():
+    from perceiver_io_tpu.models.text.lm import DecoderLM, DecoderLMConfig
+
+    cfg = DecoderLMConfig(
+        vocab_size=64, max_seq_len=64, num_channels=64, num_heads=8, num_kv_heads=2,
+        layer_types=("conv", "full_attention", "conv"), num_dense_layers=1, mlp_channels=96,
+        expert_channels=48, router_width=16, num_experts=8, experts_per_token=2,
+    )
+    model = DecoderLM(cfg, attention_impl="xla")
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32)))["params"]
+    return model, shapes
+
+
+@pytest.mark.parametrize(
+    "axes", [dict(data=8), dict(data=2, fsdp=4), dict(data=2, model=2, fsdp=2), dict(model=8, data=1)],
+    ids=["data8", "data2xfsdp4", "data2xmodel2xfsdp2", "model8"])
+def test_lm_family_leaves_get_specs_that_divide_and_never_split_the_experts(axes):
+    """Every new leaf (2-head ``k_proj``/``v_proj``, ``gate``/``up``/``down``,
+    stacked expert weights, the convolution filter, the router) gets a spec
+    whose axes divide its dimensions; dim 0 of a stacked expert leaf, the
+    expert dimension, is never sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = make_mesh(MeshConfig(**axes))
+    _, shapes = _lm_param_shapes()
+    specs = infer_param_specs(shapes, mesh, min_fsdp_size=0)
+    flat_specs = jax.tree_util.tree_leaves_with_path(specs, is_leaf=lambda s: isinstance(s, P))
+    flat_shapes = dict(jax.tree_util.tree_leaves_with_path(shapes))
+    seen = set()
+    for path, spec in flat_specs:
+        shape = flat_shapes[path].shape
+        name = "/".join(str(getattr(k, "key", k)) for k in path)
+        NamedSharding(mesh, spec).shard_shape(shape)  # raises if an axis does not divide
+        if "/moe/" in name and name.rsplit("/", 1)[1] in ("gate", "up", "down"):
+            assert len(spec) == 0 or spec[0] is None, (name, spec)
+            seen.add(name.rsplit("/", 1)[1])
+        if axes.get("model", 1) > 1:
+            if name.endswith(("k_proj/kernel", "v_proj/kernel", "mlp/gate/kernel", "mlp/up/kernel")):
+                assert spec[1] == "model", (name, spec)
+            if name.endswith("mlp/down/kernel"):
+                assert spec[0] == "model", (name, spec)
+    assert seen == {"gate", "up", "down"}
+
+
+def test_lm_family_train_step_on_a_data_by_fsdp_mesh_matches_one_device():
+    """Two steps of the family's loss on ``data=2 x fsdp=4`` (the expert
+    layer's tokens part runs in ``shard_map`` over the batch axes) against
+    one device."""
+    from perceiver_io_tpu.training.tasks import lm_loss_fn
+
+    model, _ = _lm_param_shapes()
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 64, size=(8, 17)).astype(np.int32)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:], "pad_mask": np.zeros((8, 16), bool)}
+    losses = {}
+    for name, axes in (("one", dict(data=1)), ("mesh", dict(data=2, fsdp=4))):
+        devices = jax.devices()[:1] if name == "one" else None
+        mesh = make_mesh(MeshConfig(**axes), devices=devices)
+        state, shardings = create_train_state(
+            lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))["params"],
+            optax.adamw(1e-2), mesh, min_fsdp_size=0)
+        step = make_train_step(lm_loss_fn(model), mesh, shardings)
+        out = []
+        for i in range(2):
+            state, metrics = step(state, shard_batch(batch, mesh), jax.random.PRNGKey(i))
+            out.append((float(metrics["loss"]), float(metrics["moe_assignments_held"])))
+        losses[name] = out
+    assert losses["one"][0][1] == 2 * 8 * 16 * 2 * 8 / 16 or losses["one"][0][1] > 0
+    np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=2e-4)

@@ -266,3 +266,68 @@ def test_rotary_lowers_without_a_pair_dimension_or_a_concatenate():
     for function, scope, line in ops:
         size = np.prod(shapes(line.rsplit("->", 1)[-1])[0])
         assert not (size >= large and ("/rotary/" in scope or function in under)), line
+
+
+_SHAPED = re.compile(r"tensor<([0-9x]+)x(?:bf16|f32|i32|i1)>")
+
+
+def _tensor_shapes(text):
+    return {tuple(int(d) for d in m.group(1).split("x")) for m in _SHAPED.finditer(text)}
+
+
+def test_grouped_head_attention_lowers_for_tpu_without_repeating_keys_or_values():
+    """32 query heads on 8 key-value heads, forward and backward, through the
+    module: three Mosaic kernels, and nowhere in the program a key or value
+    array at the query heads' count: ``(b, 32, n, 64)`` arrays are q, o and
+    their gradients only, as many as the same module has without grouping
+    has of q-shaped ones (the kernels index the shared head from their grid)."""
+    from perceiver_io_tpu.models.core.modules import MultiHeadAttention
+
+    b, n, h, hk, c = 2, 256, 32, 8, 64
+    x = jnp.zeros((b, n, h * c), jnp.bfloat16)
+
+    def lowered_text(kv_heads):
+        mha = MultiHeadAttention(
+            num_heads=h, num_q_input_channels=h * c, num_kv_input_channels=h * c,
+            causal_attention=True, qkv_bias=False, out_bias=False, dtype=jnp.bfloat16,
+            attention_impl="flash", num_kv_heads=kv_heads, qk_norm=True)
+        params = mha.init(jax.random.PRNGKey(0), x, x)
+        loss = lambda p, x: jnp.sum(mha.apply(p, x, x).astype(jnp.float32))
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    grouped, full = lowered_text(hk), lowered_text(None)
+    assert grouped.count("tpu_custom_call") == 3
+    count = lambda text, heads: len(re.findall(rf"tensor<{b}x{heads}x{n}x{c}xbf16>", text))
+    assert count(grouped, hk) > 0  # k, v, dk, dv at 8 heads
+    # every (b, 32, n, 64) array of the full module that was a key or a value is gone
+    assert count(grouped, h) < count(full, h)
+    assert f"tensor<{b}x{hk}x{h // hk}x{n}x{c}" not in grouped  # and no broadcast view of them
+    assert f"tensor<{b}x{h}x{n}x{n}" not in grouped  # nor a score matrix
+
+
+def test_expert_layer_lowers_for_tpu_without_a_dispatch_array_over_all_experts():
+    """The expert layer, forward and backward: tokens are sorted by held
+    expert and multiplied by ``ragged_dot``; no ``(tokens, 64, ...)`` one-hot
+    dispatch or combine array over the router's width, and nothing of the
+    size of ``tokens x experts x channels``."""
+    from perceiver_io_tpu.models.core.hybrid import SparseExperts
+
+    tokens, c, f, width, held, k = 512, 128, 64, 64, 8, 4
+    layer = SparseExperts(num_channels=c, hidden_channels=f, router_width=width,
+                          num_experts=held, top_k=k, dtype=jnp.bfloat16)
+    u = jnp.zeros((2, tokens // 2, c), jnp.bfloat16)
+    params = layer.init(jax.random.PRNGKey(0), u)
+    loss = lambda p, u: jnp.sum(layer.apply(p, u)[0].astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, u).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "ragged_dot" in text
+    for shape in _tensor_shapes(text):
+        if len(shape) >= 3 and shape[0] in (tokens, tokens * k):
+            assert width not in shape[1:] and held not in shape[1:], shape
+        size = 1
+        for d in shape:
+            size *= d
+        assert size <= max(tokens * k * c, held * c * f), shape
+    # the router's scores are the only array over the router's width
+    assert (tokens, width) in _tensor_shapes(text)
