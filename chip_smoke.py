@@ -191,7 +191,7 @@ def check_flash(name, shape, heads, *, causal, pad, on_tpu, tol=3e-2):
         return run
 
     compiled, kernels = _compile_with_kernel(
-        fwd_bwd("flash"), (q, k, v, do), on_tpu, at_least=3
+        fwd_bwd("flash"), (q, k, v, do), on_tpu, at_least=2  # forward, and one backward: dQ fits VMEM
     )
     got = compiled(q, k, v, do)
     want = jax.jit(fwd_bwd("xla"))(q, k, v, do)

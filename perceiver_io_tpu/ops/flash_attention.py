@@ -28,6 +28,20 @@ float32 — cheap because every Perceiver query length is the latent count,
 not the sequence length. Matmuls feed the MXU in the input dtype (bf16 in
 training) with float32 accumulation; softmax math is float32 on the VPU.
 
+The backward is one kernel, ``flash_bwd_dkv``, wherever the float32 dQ of one
+``(batch, key-value head)`` fits ``_DQ_VMEM_BUDGET_BYTES`` — for the same
+reason, every Perceiver shape. Its grid is ``(b, hk, j_blocks, group *
+i_blocks)``: dK and dV of a kv block accumulate over the q blocks (innermost),
+and each block pair's ``ds @ k`` is added to its rows of a dQ accumulator that
+stays in VMEM across both block dimensions and is rounded once, when the last
+kv block has added its part. Scores, mask, ``exp`` and ``dP`` are computed
+once per block pair: five products. Where dQ does not fit (long self-attention
+with grouped heads), dQ has a kernel of its own, ``flash_bwd_dq``, which
+recomputes them (seven products; ``flash_bwd_dkv`` then returns dK and dV
+alone), and the traced backward is counted in
+``flash_backward_two_call_total``. The sums, their order and the rounding are
+the same either way. The choice is made from the shapes at trace time.
+
 Queries arrive pre-scaled and pre-rotated (see
 :func:`perceiver_io_tpu.ops.attention.dot_product_attention`): the attention
 module finishes q and k on the projections' flat output, under the ``rotary``
@@ -47,6 +61,24 @@ LANES = 128
 _BLOCK_CANDIDATES = (512, 256, 128)
 # Large-but-finite mask value (f32 min would overflow when subtracted).
 _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
+# The float32 dQ of one (batch, key-value head) that the backward may keep in
+# VMEM to run as one kernel (``_dq_fits_vmem``). A kernel gets 16 MiB of scoped
+# VMEM unless it asks for more. At 512 x 512 blocks the dK/dV kernel takes
+# 1.75 MiB of it in bfloat16 at 64-wide heads, 7 MiB at 256-wide, and 6.5 to
+# 10 MiB in float32 at 128- to 256-wide (compiled for a v5e without the chip,
+# halving ``vmem_limit_bytes`` until Mosaic refused). Fused, it holds besides
+# the accumulator A (lanes padded to 128) and the dQ output block twice over
+# (A / 2 each in bfloat16, A in float32): 10 + 3 A <= 16 MiB gives 2 MiB,
+# 4096 rows of 128 lanes. The Perceiver latents are far inside it (1024 rows
+# 0.5 MiB, 2048 rows 1 MiB); four query heads on 8192 rows (16 MiB) are not.
+_DQ_VMEM_BUDGET_BYTES = 2 * 1024 * 1024
+# Heads wider than 256 leave the dK/dV kernel itself little of the 16 MiB
+# (float32 at 512-wide: 14.5 MiB), so the fused kernel asks for the default and
+# as much again: whatever compiled as two kernels compiles as one. Mosaic
+# allocates what the kernel needs, not the limit (v5e: 128 MiB of VMEM).
+_FUSED_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+# backwards traced as two kernels because dQ is over the budget (docs/observability.md)
+_TWO_CALL_COUNTER = "flash_backward_two_call_total"
 
 
 def _candidates() -> Tuple[int, ...]:
@@ -168,16 +200,31 @@ def _flash_fwd(q, k, v, pad, causal):
 
 
 def _flash_bwd(causal, res, do):
+    from perceiver_io_tpu.observability import default_registry
+
     q, k, v, pad, o, lse = res
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, LANES))
-    dq = _backward_dq(q, k, v, pad, lse, delta, do, causal)
-    dk, dv = _backward_dkv(q, k, v, pad, lse, delta, do, causal)
+    default_registry().declare_counters(_TWO_CALL_COUNTER)
+    if _dq_fits_vmem(q, k):
+        dk, dv, dq = _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq=True)
+    else:
+        # trace time, so once per traced backward; correct, only slower: no warning
+        default_registry().inc(_TWO_CALL_COUNTER)
+        dq = _backward_dq(q, k, v, pad, lse, delta, do, causal)
+        dk, dv = _backward_dkv(q, k, v, pad, lse, delta, do, causal)
     dpad = None if pad is None else jnp.zeros_like(pad)
     return dq, dk, dv, dpad
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _dq_fits_vmem(q, k) -> bool:
+    """Whether the backward runs as one kernel: the float32 dQ of one
+    ``(batch, key-value head)`` as Mosaic lays it out, against the budget."""
+    group, i, d = q.shape[1] // k.shape[1], q.shape[2], q.shape[3]
+    return group * i * max(d, LANES) * 4 <= _DQ_VMEM_BUDGET_BYTES
 
 
 def _block_mask(i_idx, j_idx, bi: int, bj: int, offset: int, causal: bool, pad_blk):
@@ -238,6 +285,11 @@ def _pad_spec(bj, by_dim2=False):
 
 _DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+)
+# the fused backward carries dQ across the kv blocks (grid dim 2) too
+_DIM_SEMANTICS_RESIDENT_DQ = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=_FUSED_VMEM_LIMIT_BYTES,
 )
 
 
@@ -414,36 +466,53 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
     )
 
 
-def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
+def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
+    """dK and dV, and with ``with_dq`` dQ as a third output of the same
+    kernel (the caller has checked :func:`_dq_fits_vmem`)."""
     b, h, i, d = q.shape
     hk, j, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hk
     bi, bj = _pick_block(i), _pick_block(j)
     offset = j - i
-    ni = i // bi
+    ni, nj = i // bi, j // bj
     has_pad = pad is not None
 
     # Grid dim 1 walks the key-value heads, dim 2 kv blocks, dim 3 the q
     # blocks of every query head that shares the key-value head (innermost,
     # so the dk/dv accumulators carry across q blocks and across the group).
+    # With dQ, the float32 dQ of the whole key-value head (``group * i`` rows,
+    # grid step ``t_idx`` owning rows ``t_idx * bi`` on) stays in VMEM across
+    # dims 2 and 3: each kv block adds its part in ascending order, as
+    # ``_backward_dq`` sums them, and the last rounds the rows once into the
+    # output block, which is resident as long and written back when
+    # ``(b, hk)`` moves on.
     def kernel(q_ref, k_ref, v_ref, *rest):
+        pad_ref = None
         if has_pad:
-            pad_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
+            pad_ref, *rest = rest
+        if with_dq:
+            lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dq_ref, dk_sc, dv_sc, dq_sc = rest
         else:
             lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
-            pad_ref = None
         j_idx, t_idx = pl.program_id(2), pl.program_id(3)
         i_idx = t_idx if group == 1 else t_idx % ni
+        if with_dq:
+            dq_rows = pl.ds(pl.multiple_of(t_idx * bi, bi), bi)
 
         @pl.when(t_idx == 0)
         def _():
             dk_sc[:] = jnp.zeros_like(dk_sc)
             dv_sc[:] = jnp.zeros_like(dv_sc)
 
+        if with_dq:
+            @pl.when(j_idx == 0)
+            def _():
+                dq_sc[dq_rows, :] = jnp.zeros((bi, d), jnp.float32)
+
         def body():
-            qb, dob = q_ref[0, 0], do_ref[0, 0]
+            qb, kb, dob = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
             s = jax.lax.dot_general(
-                qb, k_ref[0, 0], (((1,), (1,)), ((), ())),
+                qb, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             allowed = _block_mask(
@@ -466,6 +535,11 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
                 ds, qb, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+            if with_dq:
+                dq_sc[dq_rows, :] = dq_sc[dq_rows, :] + jax.lax.dot_general(
+                    ds, kb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
 
         _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal), body)
 
@@ -473,6 +547,11 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
         def _():
             dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
+
+        if with_dq:
+            @pl.when(j_idx == nj - 1)
+            def _():
+                dq_ref[0, 0, dq_rows, :] = dq_sc[dq_rows, :].astype(dq_ref.dtype)
 
     if group == 1:
         q_side = lambda width: _qk_spec(bi, width, by_dim2=False)  # q blocks walk grid dim 3
@@ -490,23 +569,36 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal):
     in_specs += [q_side(LANES), q_side(LANES), q_side(dv)]
     args += [lse, delta, do]
 
-    return pallas_call_on_lowering_platform(
+    out_specs = [
+        _qk_spec(bj, d, by_dim2=True),
+        _qk_spec(bj, dv, by_dim2=True),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((b, hk, j, d), k.dtype),
+        jax.ShapeDtypeStruct((b, hk, j, dv), v.dtype),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((bj, d), jnp.float32),
+        pltpu.VMEM((bj, dv), jnp.float32),
+    ]
+    if with_dq:
+        # the group's query heads are adjacent, so (b, hk, group * i, d) is
+        # (b, h, i, d) seen by key-value head: the reshape below moves nothing
+        out_specs.append(pl.BlockSpec((1, 1, group * i, d), lambda b_, h_, x_, y_: (b_, h_, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, hk, group * i, d), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((group * i, d), jnp.float32))
+
+    out = pallas_call_on_lowering_platform(
         kernel,
         *args,
         name="flash_bwd_dkv",
-        grid=(b, hk, j // bj, group * ni),
+        grid=(b, hk, nj, group * ni),
         in_specs=in_specs,
-        out_specs=[
-            _qk_spec(bj, d, by_dim2=True),
-            _qk_spec(bj, dv, by_dim2=True),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hk, j, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hk, j, dv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bj, d), jnp.float32),
-            pltpu.VMEM((bj, dv), jnp.float32),
-        ],
-        compiler_params=_DIM_SEMANTICS,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
+        compiler_params=_DIM_SEMANTICS_RESIDENT_DQ if with_dq else _DIM_SEMANTICS,
     )
+    if with_dq:
+        return out[0], out[1], out[2].reshape(q.shape)
+    return out[0], out[1]

@@ -122,3 +122,110 @@ def test_bf16_forward_close(rng):
     out = flash_attention.flash_attention(qb, kb, vb, causal=True).astype(jnp.float32)
     expected = _attention_xla(qb, kb, vb, None, True, 0.0, None).astype(jnp.float32)
     np.testing.assert_allclose(out, expected, atol=2e-2, rtol=2e-2)
+
+
+# h, hk, i, j, d, dv, causal, pad lengths by batch row (keys padded on the left)
+FUSED_CASES = {
+    # three query blocks on five key blocks of 128, offset 256: blocks above the diagonal are skipped
+    "causal_right_aligned": (2, 2, 384, 640, 64, 64, True, None),
+    # key block 0 wholly padded in row 0; its query rows 0..4 see nothing but padding (dead)
+    "pad_block_and_dead_row": (2, 2, 256, 384, 64, 64, True, (133, 7)),
+    "grouped_heads": (4, 2, 256, 384, 64, 64, True, (0, 40)),
+    "mlm_head_widths": (2, 2, 256, 512, 32, 160, False, (0, 130)),
+}
+
+
+def _grads(fn, q, k, v, cot):
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * cot), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_backward_matches_two_call_and_einsum(rng, monkeypatch, case, dtype):
+    h, hk, i, j, d, dv, causal, lengths = FUSED_CASES[case]
+    b = 2
+    q = (jnp.asarray(rng.standard_normal((b, h, i, d)), jnp.float32) * d**-0.5).astype(dtype)
+    k = jnp.asarray(rng.standard_normal((b, hk, j, d)), jnp.float32).astype(dtype)
+    v = jnp.asarray(rng.standard_normal((b, hk, j, dv)), jnp.float32).astype(dtype)
+    cot = jnp.asarray(rng.standard_normal((b, h, i, dv)), jnp.float32)
+    pad = None if lengths is None else jnp.asarray(np.arange(j)[None, :] < np.asarray(lengths)[:, None])
+    flash = lambda q, k, v: flash_attention.flash_attention(q, k, v, pad_mask=pad, causal=causal)
+
+    assert flash_attention._dq_fits_vmem(q, k)
+    fused = _grads(flash, q, k, v, cot)
+    monkeypatch.setattr(flash_attention, "_DQ_VMEM_BUDGET_BYTES", 0)
+    two_call = _grads(flash, q, k, v, cot)
+    for a, e, name in zip(fused, two_call, "qkv"):  # the same sums in the same order
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(e, np.float32), err_msg=f"d{name}")
+
+    # the einsum path leaks through dead rows (flash_attention's docstring); a loss masks them
+    if pad is not None:
+        visible = ~pad[:, None, None, :]
+        if causal:
+            visible = visible & (jnp.arange(j)[None, :] <= jnp.arange(i)[:, None] + (j - i))
+        alive = jnp.any(visible, axis=-1)  # (b, 1, i)
+        if case == "pad_block_and_dead_row":
+            assert not bool(alive[0, 0, 4]) and bool(alive[0, 0, 5]) and bool(alive[1].all())
+            assert not np.asarray(fused[0], np.float32)[0, :, :5].any()  # dead rows: zero dQ
+        cot = cot * alive[..., None]
+        fused = _grads(flash, q, k, v, cot)
+    einsum = _grads(
+        lambda q, k, v: dot_product_attention(q, k, v, pad_mask=pad, causal=causal, impl="xla"),
+        q, k, v, cot,
+    )
+    tol = 1e-4 if dtype == jnp.float32 else 5e-2
+    for a, e, name in zip(fused, einsum, "qkv"):
+        a, e = np.asarray(a, np.float32), np.asarray(e, np.float32)
+        np.testing.assert_allclose(a, e, atol=tol * max(1.0, np.abs(e).max()), rtol=tol, err_msg=f"d{name}")
+
+
+def _traced_backward(h, hk, i):
+    """The jaxpr of a flash forward and backward at ``i`` query rows, traced and not run."""
+    q = jax.ShapeDtypeStruct((1, h, i, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, hk, 128, 64), jnp.bfloat16)
+    loss = lambda q, k, v: jnp.sum(flash_attention.flash_attention(q, k, v).astype(jnp.float32))
+    return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv))
+
+
+def test_backward_is_one_kernel_while_dq_fits_the_budget():
+    from perceiver_io_tpu.observability import default_registry
+
+    counter = "flash_backward_two_call_total"
+    budget = flash_attention._DQ_VMEM_BUDGET_BYTES
+    rows = budget // (flash_attention.LANES * 4)  # 64-wide heads take whole lanes all the same
+
+    def kernels(h, hk, i):
+        before = default_registry().counter(counter)
+        text = _traced_backward(h, hk, i)
+        names = {n for n in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if f"name={n}" in text}
+        return names, default_registry().counter(counter) - before
+
+    assert kernels(1, 1, rows) == ({"flash_fwd", "flash_bwd_dkv"}, 0)
+    assert kernels(4, 1, rows // 4) == ({"flash_fwd", "flash_bwd_dkv"}, 0)  # the group's heads share it
+    assert kernels(1, 1, rows + 128) == ({"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, 1)
+    assert kernels(4, 1, rows // 4 + 128) == ({"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, 1)
+    assert kernels(4, 4, rows // 4 + 128) == ({"flash_fwd", "flash_bwd_dkv"}, 0)
+
+
+def test_two_call_counter_has_help_text_and_a_benchmark_reader(monkeypatch):
+    """``benchmarks/metrics/flash_bwd_two_call_shapes.py``: nothing from a
+    program without the counter, 0 once a fused backward was traced, then the
+    count of two-call ones."""
+    import os
+
+    import perceiver_io_tpu.observability as observability
+    from perceiver_io_tpu.observability.exporters import HELP_TEXT
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks import harness
+
+    registry = observability.MetricsRegistry()
+    monkeypatch.setattr(observability, "default_registry", lambda: registry)
+    read = harness.load_reader(os.path.join(root, "benchmarks"), "flash_bwd_two_call_shapes")
+    assert "flash_backward_two_call_total" in HELP_TEXT
+    assert read({}) is None  # the parent's program never declares it
+    _traced_backward(1, 1, 1024)
+    assert read({}) == 0.0
+    _traced_backward(1, 1, 8192)
+    assert read({}) == 1.0

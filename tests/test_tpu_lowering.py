@@ -62,7 +62,7 @@ def test_flash_forward_and_backward_lower_for_tpu(causal, pad):
             argnums=(0, 1, 2),
         )(q, k, v)
 
-    assert _mosaic_calls(fwd_bwd, q, k, v) == 3  # forward, dq, dk/dv
+    assert _mosaic_calls(fwd_bwd, q, k, v) == 2  # forward, and one backward: dQ fits VMEM here
     assert _mosaic_calls(fwd_bwd, q, k, v, platform="cpu") == 0  # interpreted
 
 
@@ -119,6 +119,29 @@ def test_each_flash_kernel_lowers_under_its_own_name(kernel):
     assert _kernel_names(call, q, k, v) == ([kernel], {kernel})
 
 
+@pytest.mark.parametrize(
+    "b,h,hk,i,j,pad",
+    [(32, 8, 8, 1024, 4608, True), (2, 8, 2, 512, 512, False)],
+    ids=["ar_cross_attention", "grouped_heads"],
+)
+def test_fused_backward_lowers_as_flash_bwd_dkv_with_three_outputs(b, h, hk, i, j, pad):
+    q = do = jax.ShapeDtypeStruct((b, h, i, 64), jnp.bfloat16)
+    k = v = jax.ShapeDtypeStruct((b, hk, j, 64), jnp.bfloat16)
+    lse = delta = jax.ShapeDtypeStruct((b, h, i, flash_attention.LANES), jnp.float32)
+    pad_mask = jax.ShapeDtypeStruct((b, 1, j), jnp.float32) if pad else None
+    assert flash_attention._dq_fits_vmem(q, k)
+
+    def call(q, k, v, pad_mask, lse, delta, do):
+        return flash_attention._backward_dkv(q, k, v, pad_mask, lse, delta, do, True, with_dq=True)
+
+    args = (q, k, v, pad_mask, lse, delta, do)
+    assert _kernel_names(call, *args) == (["flash_bwd_dkv"], {"flash_bwd_dkv"})
+    text = jax.jit(call).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    (results,) = re.findall(r"custom_call @tpu_custom_call.*-> \((.*)\)", text)
+    kv, dq = f"tensor<{b}x{hk}x{j}x64xbf16>", f"tensor<{b}x{hk}x{h // hk * i}x64xbf16>"
+    assert results.split(", ") == [kv, kv, dq]  # dK, dV, and dQ by key-value head
+
+
 def test_ragged_kernel_lowers_under_its_own_name():
     rows, h, d, bs, pages = 2, 2, 64, 16, 4
     pool = jnp.zeros(((rows * pages + 1) * bs, h, d), jnp.float32)
@@ -162,8 +185,8 @@ def test_train_step_with_pad_mask_lowers_for_tpu_on_four_devices(devices, axes):
     lowered = step.trace(state, batch, jax.random.PRNGKey(1)).lower(
         lowering_platforms=("tpu",)
     )
-    # cross-attention + one self-attention layer, each forward, dq and dk/dv
-    assert lowered.as_text().count("tpu_custom_call") == 6
+    # cross-attention + one self-attention layer, each a forward and one backward
+    assert lowered.as_text().count("tpu_custom_call") == 4
 
 
 def test_mosaic_kernel_without_shard_map_is_refused_at_lowering(devices):
@@ -277,7 +300,8 @@ def _tensor_shapes(text):
 
 def test_grouped_head_attention_lowers_for_tpu_without_repeating_keys_or_values():
     """32 query heads on 8 key-value heads, forward and backward, through the
-    module: three Mosaic kernels, and nowhere in the program a key or value
+    module: two Mosaic kernels (the group's dQ fits VMEM, so the backward is
+    one), and nowhere in the program a key or value
     array at the query heads' count: ``(b, 32, n, 64)`` arrays are q, o and
     their gradients only, as many as the same module has without grouping
     has of q-shaped ones (the kernels index the shared head from their grid)."""
@@ -297,7 +321,7 @@ def test_grouped_head_attention_lowers_for_tpu_without_repeating_keys_or_values(
             lowering_platforms=("tpu",)).as_text()
 
     grouped, full = lowered_text(hk), lowered_text(None)
-    assert grouped.count("tpu_custom_call") == 3
+    assert grouped.count("tpu_custom_call") == 2
     count = lambda text, heads: len(re.findall(rf"tensor<{b}x{heads}x{n}x{c}xbf16>", text))
     assert count(grouped, hk) > 0  # k, v, dk, dv at 8 heads
     # every (b, 32, n, 64) array of the full module that was a key or a value is gone
