@@ -21,6 +21,6 @@ RUN pip install --no-cache-dir \
 
 COPY tests ./tests
 COPY examples ./examples
-COPY bench.py Makefile ./
+COPY Makefile ./
 
 CMD ["python", "-c", "import jax, perceiver_io_tpu; print(jax.devices())"]

@@ -62,7 +62,8 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# ctx / latents / channels / heads / layers / batch: bench.py's FULL_SHAPE
+# ctx / latents / channels / heads / layers / batch: the `perceiver-ar-8k`
+# configuration's widths (benchmarks/configs/) at batch 8
 FULL = dict(
     ctx=8192, latents=1024, channels=512, heads=8, layers=8, batch=8,
     steps=30, timed_steps=10,

@@ -2,18 +2,19 @@
 generated token pinned into ONE cache phase (``--phase``):
 
 - ``latent`` — latent-growth: the cached step runs O(1) tokens of compute
-  per token vs the recompute path's full window (measured ~6× on CPU,
-  ``docs/benchmarks.md`` round-5 curves).
+  per token vs the recompute path's full window (faster on a CPU; on the
+  chip: not measured).
 - ``boundary`` — prefix-growth: the cache elides the full-window embedding +
   cross-k/v projections (the ``2·n·c²`` matmuls) but recomputes the latent
-  stack like the recompute path does (measured sub-1× on CPU at 256 ch).
+  stack like the recompute path does (slower than recompute on a CPU at
+  256 channels; on the chip: not measured).
 
 Under the static right-aligned window formulation both paths' per-token cost
 is a function of the *window* size ``n = max_seq_len`` (left pads are
 computed and masked), so the scaling axis is context length, not prompt
 length. Runs on the backend ``JAX_PLATFORMS`` selects (bf16 on a TPU, the
 default float32 elsewhere) and names it in every point. Prints one JSON line
-per point and a markdown table suitable for ``docs/benchmarks.md``.
+per point and a markdown table.
 
 Boundary-phase points also feed the decode-strategy registry
 (``inference/decode_strategy.py``): each point records the autotuner's

@@ -105,12 +105,12 @@ def _beam_executor(
         ledger_model_id,
         model_fingerprint,
     )
-    from perceiver_io_tpu.models.core.modules import trace_env_fingerprint
+    from perceiver_io_tpu.ops.ragged_attention import trace_env
 
     key = (
         type(model).__qualname__, model_fingerprint(model), config,
         b, prompt_len, num_latents, num_beams, length_penalty, ids_dtype,
-        trace_env_fingerprint(),
+        trace_env(),
     )
     return cached_executor(
         _EXECUTOR_CACHE, key,
@@ -133,7 +133,7 @@ def _beam_executor(
                 f"steps={config.max_new_tokens}"
             ),
             "ids_dtype": ids_dtype,
-            "trace_env": trace_env_fingerprint(),
+            "trace_env": trace_env(),
         },
     )
 

@@ -1,17 +1,17 @@
 """Per-phase decode strategies: cached vs recompute, chosen by measurement.
 
 The decode loop passes through three cache phases (``generate.py`` module
-docstring): latent growth (cached step runs O(1) tokens of compute — a
-measured ~6× win at every context length, docs/benchmarks.md), prefix
+docstring): latent growth (cached step runs O(1) tokens of compute; its
+speed-up on the chip is not measured), prefix
 growth ("boundary" — the cache elides only the ``2·n·c²`` full-window
 embedding + cross-k/v projections while the latent stack is recomputed
 either way), and the sliding window (recompute is semantically forced by
-the learned absolute position embedding). Round-5 measurements showed the
-cached boundary step *losing* to full recompute on CPU (0.83–0.97× at
-1k–8k ctx): whether the elision beats its own bookkeeping is a platform
-and shape question — exactly the portable-caching tradeoff of the
-compiler-first O(1)-caching paper (PAPERS.md) — so it should be a
-*measured choice*, not a hardcoded one.
+the learned absolute position embedding). On a CPU the cached boundary
+step has lost to full recompute (on the chip: not measured): whether the
+elision beats its own bookkeeping is a platform and shape question —
+exactly the portable-caching tradeoff of the compiler-first O(1)-caching
+paper (PAPERS.md) — so it should be a *measured choice*, not a hardcoded
+one.
 
 This module is that choice:
 
@@ -27,7 +27,7 @@ This module is that choice:
 - :func:`autotune_boundary` — the warmup-time autotuner: microbenchmark
   cached vs recompute boundary-phase decoding at the bound shape, pick
   the winner, memoize it in a process registry keyed by
-  ``(shape, platform, modules.trace_env_fingerprint())``. With optional
+  ``(shape, platform, ragged_attention.trace_env())``. With optional
   JSON persistence (``persist=`` / ``PERCEIVER_DECODE_STRATEGY_FILE``) a
   deployment measures once and every later process loads the verdict.
 - ``python -m perceiver_io_tpu.inference.decode_strategy`` — the
@@ -149,7 +149,7 @@ class DecodeStrategy:
         return self.latent == "cached" and self.boundary == "cached"
 
 
-#: (shape_key, platform, trace_env_fingerprint) -> measurement entry dict
+#: (shape_key, platform, trace_env) -> measurement entry dict
 _REGISTRY: dict = {}
 #: same key space -> {"kv_layout": "dense"|"paged", ...} measurement entry
 #: (separate dict so a boundary-only artifact and a kv-only artifact can
@@ -183,13 +183,13 @@ def shape_key(model) -> tuple:
 
 
 def registry_key(model, platform: Optional[str] = None) -> tuple:
-    from perceiver_io_tpu.models.core.modules import trace_env_fingerprint
+    from perceiver_io_tpu.ops.ragged_attention import trace_env
 
     if platform is None:
         import jax
 
         platform = jax.default_backend()
-    return (shape_key(model), str(platform), trace_env_fingerprint())
+    return (shape_key(model), str(platform), trace_env())
 
 
 def _maybe_load_env_file() -> None:
@@ -372,8 +372,7 @@ def lookup_swap_gbps(platform: Optional[str] = None) -> Optional[float]:
 
 def swap_entry(platform: Optional[str] = None) -> Optional[dict]:
     """The full calibrated-swap registry entry (rate + measurement
-    metadata), or None. Read-only view for observability and the bench
-    probes."""
+    metadata), or None. Read-only view for observability."""
     _maybe_load_env_file()
     if platform is None:
         import jax
@@ -783,9 +782,9 @@ def autotune_kv_layout(
     autotuner falls back to exact ``paged`` no matter the timing (the
     gate verdict is recorded either way, as ``quant_gate``).
     Note the tradeoff being measured is TIME at equal capacity; the paged
-    layouts' admission win (more residents per HBM byte — ~4x more again
-    for int8) is a capacity property the ``extras.paged_kv`` /
-    ``extras.quant_kv`` benches measure separately — an operator who
+    layouts' admission win (more residents per HBM byte, and more again
+    for int8's quarter-size entries) is a capacity property, a count this
+    measurement does not make — an operator who
     sizes ``kv_blocks`` below dense capacity has already chosen paged and
     should pass it explicitly.
 

@@ -766,7 +766,7 @@ def _decode_step_boundary(
       boundary-side normalization swap, reference ``modules.py:188-203``).
 
     Both cache updates land in ONE fused scatter per array (the step is
-    bookkeeping-bound on CPU — docs/benchmarks.md round-5 curves — so the
+    bookkeeping-bound on a CPU, so the
     fixed per-step overhead matters as much as the FLOPs). The migrated and
     appended indices are always distinct (``length - max_latents`` vs
     ``length``), so the fused scatter stays deterministic.
@@ -920,9 +920,10 @@ def generate(
     # position embedding (reference window schedule ``clm/huggingface.py:
     # 53-74``). The per-phase cached-vs-recompute choice is the decode
     # strategy (``inference/decode_strategy.py`` — measured, env- and
-    # flag-overridable; the boundary phase loses to recompute on some
-    # platforms, docs/benchmarks.md). The schedule is host-side static, so
-    # it is part of the executor cache key rather than traced control flow.
+    # flag-overridable; the cached boundary phase loses to recompute on a
+    # CPU and is not measured on the chip). The schedule is host-side
+    # static, so it is part of the executor cache key rather than traced
+    # control flow.
     from perceiver_io_tpu.inference import decode_strategy as _strategy
 
     strat = _strategy.resolve(decode_strategy, model)
@@ -1008,7 +1009,7 @@ def executor_cache_stats() -> dict:
     """Snapshot of the shared executor-cache counters, under both the
     canonical registry names (``executor_cache_hits_total``, ...) and the
     legacy short keys (``hits``, ...) — prefer the canonical ones; the
-    aliases exist for the serve CLI / bench probes written before the
+    aliases exist for the serve CLI and tests written before the
     unified telemetry layer."""
     from perceiver_io_tpu.observability import default_registry
 
@@ -1110,16 +1111,15 @@ def _generation_executor(
     ~2 ms/token of actual compute at test scale); this cache makes repeated
     pipeline calls with the same shape/config dispatch a compiled program.
     Keyed by the module's fingerprint, the frozen :class:`GenerationConfig`,
-    shapes, the phase plan, and every trace-time env knob
-    (``PERCEIVER_FUSED_QKV`` and the ``PERCEIVER_FLASH_*`` flags, via
-    :func:`~perceiver_io_tpu.models.core.modules.trace_env_fingerprint`) — a
+    shapes, the phase plan, and the one trace-time environment switch
+    (:func:`~perceiver_io_tpu.ops.ragged_attention.trace_env`) — a
     mid-process toggle must rebuild the executor, not silently reuse a trace
     captured under the other setting."""
-    from perceiver_io_tpu.models.core.modules import trace_env_fingerprint
+    from perceiver_io_tpu.ops.ragged_attention import trace_env
 
     key = (
         type(model).__qualname__, model_fingerprint(model), config,
-        b, prompt_len, num_latents, s1, s2, ids_dtype, trace_env_fingerprint(),
+        b, prompt_len, num_latents, s1, s2, ids_dtype, trace_env(),
     )
     return cached_executor(
         _EXECUTOR_CACHE, key,
@@ -1137,7 +1137,7 @@ def _generation_executor(
             "num_latents": num_latents,
             "phase_plan": f"s1={s1},s2={s2}",
             "ids_dtype": ids_dtype,
-            "trace_env": trace_env_fingerprint(),
+            "trace_env": trace_env(),
         },
     )
 

@@ -68,59 +68,6 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.epsilon) * scale).astype(self.dtype)
 
 
-def fused_qkv_enabled() -> bool:
-    """``PERCEIVER_FUSED_QKV=1`` merges same-input q/k/v (self-attention) and
-    k/v (cross-attention) projections into single wider matmuls. Like the
-    ``PERCEIVER_FLASH_*`` knobs this is read at trace time, so a toggle only
-    affects traces captured afterwards (the tuning sweep isolates each
-    setting in a subprocess). The generation/beam/slot executor caches fold
-    every trace-time knob into their cache keys
-    (:func:`trace_env_fingerprint`), so a mid-process toggle rebuilds those
-    executors instead of silently serving a program traced under the other
-    setting. Default off until measured on hardware; exactness vs the
-    unfused path is tested either way."""
-    import os
-
-    return os.environ.get("PERCEIVER_FUSED_QKV", "0") == "1"
-
-
-def trace_env_fingerprint() -> tuple:
-    """Every trace-time env knob that changes the compiled program, as one
-    hashable tuple for executor cache keys (``generate._generation_executor``,
-    ``beam._beam_executor``, ``serving.slots``). Folding ALL of them in —
-    not just ``PERCEIVER_FUSED_QKV`` — means a mid-process toggle of a
-    flash knob rebuilds the executor instead of silently no-op'ing
-    (ADVICE r5 on the process-start-only footgun). Values are normalized to
-    what the consumers parse (``attention._flash_eligible``,
-    ``flash_attention._candidates`` — without importing the pallas module,
-    which only loads on TPU), so semantically identical settings (unset vs
-    ``"0"``, an unparseable override vs the default) share one key instead
-    of retracing. Plain ``jax.jit`` call sites (train steps) still read
-    these at trace time only; the tuning sweep's subprocess isolation
-    remains the contract there."""
-    import os
-
-    try:
-        min_kv = int(os.environ.get("PERCEIVER_FLASH_MIN_KV", "0"))
-    except ValueError:
-        min_kv = 0
-    raw = os.environ.get("PERCEIVER_FLASH_BLOCKS", "")
-    try:
-        blocks = tuple(int(x) for x in raw.split(",")) if raw else ()
-    except ValueError:
-        blocks = ()
-    if not (blocks and all(b > 0 and b % 128 == 0 for b in blocks)):
-        # mirror flash_attention._candidates' validation (LANES == 128):
-        # overrides it would ignore must fingerprint like the unset default
-        blocks = ()
-    # PERCEIVER_RAGGED_KERNEL switches the slot engine's paged attends
-    # between the gather reference and the ragged Pallas kernel at trace
-    # time (ops/ragged_attention.py; interpreted off-TPU) — same
-    # mid-process-toggle contract as the flash knobs
-    ragged_kernel = os.environ.get("PERCEIVER_RAGGED_KERNEL", "0") == "1"
-    return (fused_qkv_enabled(), min_kv, blocks, ragged_kernel)
-
-
 def _remat_policy(offload: bool):
     """Remat saving policy for activation checkpointing. ``offload=False``
     saves nothing (pure rematerialization). ``offload=True`` is the TPU-native
@@ -221,8 +168,8 @@ class MultiHeadAttention(nn.Module):
     def _finish_q(
         self, q_flat: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding]
     ) -> jnp.ndarray:
-        """Shared post-projection q path (fused and unfused): scale, then
-        rotate — the reference's order of operations — then split heads."""
+        """Scale, then rotate (the reference's order of operations), then
+        split heads."""
         qk, _, _ = self._channels()
         if self.qk_norm:
             q_flat = self._norm_heads(self.q_norm, q_flat, self.num_heads)
@@ -254,36 +201,8 @@ class MultiHeadAttention(nn.Module):
         """(b, n, Dkv) -> rotated (b, h, n, ck), (b, h, n, cv). Exposed for
         the KV-cache decode loop (keys are cached post-rotation; rotary is
         relative so a global position offset cancels in attention scores)."""
-        if fused_qkv_enabled() and not self.is_initializing():
-            # One (n, Dkv) x (Dkv, ck+cv) matmul instead of two: k and v
-            # always project from the same (often window-length) input, and
-            # a single wider matmul keeps the MXU busier per dispatch. The
-            # param tree is untouched; the concat of the (loop-varying)
-            # kernels re-executes every step — ~2D² extra HBM traffic per
-            # layer against the n·D-dominated matmul reads, negligible for
-            # n >> D but part of what the sweep measures. Mathematically
-            # identical to the separate projections (same per-element dot
-            # products).
-            kv = self._fused_dense((self.k_proj, self.v_proj), x_kv)
-            k_width = self._channels()[0] // (self.num_heads // self._kv_heads)
-            k_flat, v_flat = kv[..., :k_width], kv[..., k_width:]
-        else:
-            k_flat, v_flat = self.k_proj(x_kv), self.v_proj(x_kv)
+        k_flat, v_flat = self.k_proj(x_kv), self.v_proj(x_kv)
         return self._finish_k(k_flat, rot_pos_emb), self._split_heads(v_flat, self._kv_heads)
-
-    def _fused_dense(self, projs, x: jnp.ndarray) -> jnp.ndarray:
-        """Apply several same-input Dense submodules as one matmul over their
-        output-axis-concatenated kernels (numerics preserved: computation
-        dtype and bias handling mirror ``nn.Dense``)."""
-        ws = [p.variables["params"]["kernel"] for p in projs]
-        w = jnp.concatenate([jnp.asarray(w, self.dtype) for w in ws], axis=1)
-        out = jnp.dot(x.astype(self.dtype), w)
-        if self.qkv_bias:
-            bs = [p.variables["params"]["bias"] for p in projs]
-            out = out + jnp.concatenate(
-                [jnp.asarray(b, self.dtype) for b in bs], axis=0
-            )
-        return out
 
     def project_out(self, o: jnp.ndarray) -> jnp.ndarray:
         """(b, h, n, cv) raw attention -> merged + output-projected
@@ -326,18 +245,6 @@ class MultiHeadAttention(nn.Module):
         rot_pos_emb_k: Optional[RotaryEmbedding] = None,
         deterministic: bool = True,
     ) -> jnp.ndarray:
-        if (
-            fused_qkv_enabled()
-            and x_q is x_kv  # self-attention: one source feeds q, k and v
-            and not self.is_initializing()
-        ):
-            qk, _, _ = self._channels()
-            k_end = qk + qk // (self.num_heads // self._kv_heads)
-            qkv = self._fused_dense((self.q_proj, self.k_proj, self.v_proj), x_q)
-            q = self._finish_q(qkv[..., :qk], rot_pos_emb_q)
-            k = self._finish_k(qkv[..., qk:k_end], rot_pos_emb_k)
-            v = self._split_heads(qkv[..., k_end:], self._kv_heads)
-            return self.attend(q, k, v, pad_mask=pad_mask, deterministic=deterministic)
         q = self.project_q(x_q, rot_pos_emb_q)
         k, v = self.project_kv(x_kv, rot_pos_emb_k)
         return self.attend(q, k, v, pad_mask=pad_mask, deterministic=deterministic)

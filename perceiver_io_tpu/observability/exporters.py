@@ -9,9 +9,8 @@ Two formats, one source:
   we keep raw reservoirs, not fixed buckets, so quantiles are the honest
   export.
 - :func:`snapshot_json` / :class:`SnapshotWriter` — the machine-readable
-  snapshot the serve CLI appends to ``serve_stats``, the trainer drops next
-  to ``metrics.jsonl``, and ``bench.py`` embeds in its record so every
-  BENCH_* file carries telemetry.
+  snapshot the serve CLI appends to ``serve_stats`` and the trainer drops
+  next to ``metrics.jsonl``.
 
 ``SnapshotWriter`` is cadence-gated on an injectable clock
 (``--obs.snapshot_every_s``): callers invoke :meth:`SnapshotWriter.maybe_write`
@@ -66,9 +65,6 @@ HELP_TEXT = {
     "slo_breach_total": "SLO burn-rate breaches entered (any dimension; see slo_breach_<dim>_total).",
     "slo_recoveries_total": "SLO breach recoveries (fast-window burn back under threshold).",
     "slo_burn_rate": "Worst sustained SLO burn rate across dimensions (min of fast/slow windows).",
-    "serving_throughput_tokens_per_sec": "Serving throughput gauge (bench probe).",
-    "serving_goodput_ratio": "Completed / offered requests (bench probe).",
-    "serving_mfu": "Serving model-FLOPs utilization gauge (bench probe).",
     "executor_cache_hits_total": "Executor-cache hits (no trace, no compile).",
     "executor_cache_misses_total": "Executor-cache misses (a fresh trace + compile).",
     "executor_cache_evictions_total": "Executors dropped by the FIFO cache bound.",

@@ -559,7 +559,7 @@ class CompileLedger:
     def snapshot(self) -> dict:
         """JSON-able ledger view: the lifetime rollup plus the per-key
         compile/memory table every durable consumer (``serve_stats``,
-        snapshots, bench records, ``obs report``) embeds. The table is
+        snapshots, ``obs report``) embeds. The table is
         bounded by ``keep`` (oldest rows FIFO out); the rollup keeps
         counting past it."""
         return {**self.rollup(), "records": self.records()}

@@ -22,8 +22,8 @@ Gemma-on-TPU comparison sweeps offered load open-loop):
   ``uniform`` (fixed spacing — the deterministic baseline), and ``spike``
   (Poisson at ``rate_rps`` with a ``spike_factor``× rate step over the
   window ``[spike_start_s, spike_start_s + spike_duration_s)`` — the
-  flash-crowd workload the fleet-elasticity drill and the
-  ``extras.elasticity`` bench offer; docs/serving.md "Elasticity").
+  flash-crowd workload the fleet-elasticity drill offers; docs/serving.md
+  "Elasticity").
 - **Closed loop** — ``users`` synthetic users each keep one request in
   flight: submit, await completion, think
   (``workload.think_time_s``), resubmit. Offered load self-limits to
@@ -43,16 +43,16 @@ The report (:meth:`LoadGenerator.run`) carries the shared
 goodput-under-SLO accounting — computed through
 :func:`~perceiver_io_tpu.observability.slo.offered_load` /
 :func:`~perceiver_io_tpu.observability.slo.goodput_ratio`, the SAME
-helpers the bench probes and ``obs report`` use: offered = accepted +
+helpers ``obs report`` uses: offered = accepted +
 shed + rejected, so saturation shows up as goodput < 1, never as a
 shrunk denominator.
 
 **HTTP client mode** (docs/serving.md "Streaming"): point the generator
 at a :class:`GatewayHttpClient` instead of an engine and the whole drill
 runs over real sockets — POST ``/v1/generate`` per request, streamed
-tokens read off the wire, shed/reject mapped back from 503/400 — so the
-``extras.slo_goodput`` sweep measures goodput-under-SLO through the full
-network path (socket-anchored TTFT included) with ONE flag flipped. The
+tokens read off the wire, shed/reject mapped back from 503/400 — so a run
+measures goodput-under-SLO through the full network path (socket-anchored
+TTFT included). The
 client reports ``bytes_on_wire`` (response bytes received), which
 :meth:`LoadGenerator.run` surfaces beside offered/completed. HTTP mode
 requires a real clock: sockets cannot be driven by a

@@ -6,14 +6,14 @@ The repo grew four disjoint telemetry islands (trainer ``metrics.jsonl``/TB,
 ``fault_stats``) — none sharing names or an export path. This registry is
 the one source of truth they migrate onto: a component increments a counter
 under its canonical name exactly once, and every exporter
-(:mod:`~perceiver_io_tpu.observability.exporters`), the serve CLI, and the
-bench probe read the same numbers.
+(:mod:`~perceiver_io_tpu.observability.exporters`) and the serve CLI read
+the same numbers.
 
 Design constraints, in order:
 
 - **Cheap on the hot path.** ``inc``/``observe`` are a lock acquire plus a
   dict update — microseconds against millisecond device steps (the slow-tier
-  overhead test pins the total at < 2% of a CPU bench step).
+  overhead test pins the total at < 2% of a CPU jitted step).
 - **Thread-safe.** One lock guards every map, so multiple threads can emit
   metrics concurrently (e.g. a front-end thread counting its own events
   while the engine's owner thread drains). NOTE: this makes the *registry*

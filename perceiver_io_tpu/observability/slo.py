@@ -29,11 +29,10 @@ this module turns those samples into an *operational* signal:
   once fresh samples burn below threshold the dimension recovers
   (``slo.recover`` event, ``slo_recoveries_total``).
 - :func:`offered_load` / :func:`goodput_ratio` — the ONE definition of
-  the goodput denominator, shared by ``bench.py``'s ``extras.fleet_chaos``
-  and ``extras.slo_goodput`` probes and the ``obs report`` SLO section:
-  offered load is *everything the callers asked for* (accepted + shed +
-  rejected), so an engine that sheds half its traffic cannot report
-  goodput 1.0.
+  the goodput denominator, shared by the load generator's report and the
+  ``obs report`` SLO section: offered load is *everything the callers
+  asked for* (accepted + shed + rejected), so an engine that sheds half
+  its traffic cannot report goodput 1.0.
 
 Everything runs on an injectable clock and is stdlib-only, so drills
 compose with :class:`~perceiver_io_tpu.reliability.FakeClock` like the
@@ -70,7 +69,7 @@ def offered_load(counts: Mapping[str, float], prefix: str = "serving") -> int:
 
 def goodput_ratio(counts: Mapping[str, float], prefix: str = "serving") -> float:
     """Completed / offered (:func:`offered_load`) — the one shared
-    definition, so the bench probes cannot drift on the denominator."""
+    definition, so no two reports can drift on the denominator."""
     return (
         counts.get(f"{prefix}_requests_completed_total", 0)
         / max(1, offered_load(counts, prefix))
@@ -412,7 +411,7 @@ class SLOMonitor:
         return out
 
     def stats(self) -> dict:
-        """JSON-able snapshot for ``serve_stats`` / bench records."""
+        """JSON-able snapshot for ``serve_stats``."""
         return {
             "policy": dataclasses.asdict(self.policy),
             "fast_window_s": self.fast_window_s,

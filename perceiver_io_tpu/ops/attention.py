@@ -104,7 +104,7 @@ def dot_product_attention(
                 q, k, v, mesh, axis_name="seq", pad_mask=pad_mask, causal=causal
             )
 
-    if impl == "flash" or (impl == "auto" and _flash_eligible(q, k, v, dropout_rate)):
+    if impl == "flash" or (impl == "auto" and _flash_eligible(dropout_rate)):
         from perceiver_io_tpu.ops import flash_attention
 
         if impl == "flash" and dropout_rate > 0.0:
@@ -203,32 +203,11 @@ def _count_einsum_fallback(q, k, v, causal) -> None:
     )
 
 
-def _flash_eligible(q, k, v, dropout_rate) -> bool:
-    # Flash path only on TPU, without attention dropout (the reference default
-    # is dropout 0.0 everywhere; training configs that enable it fall back).
-    if dropout_rate > 0.0:
-        return False
-    # Optional kv-length floor for 'auto' (PERCEIVER_FLASH_MIN_KV): below it,
-    # the materialized XLA softmax is cheap and the blockwise schedule's
-    # per-block overhead can dominate — lets short self-attention use XLA
-    # while long-kv cross-attention stays flash. Default 0 = flash everywhere.
-    #
-    # TRACE-TIME: this (and PERCEIVER_FLASH_BLOCKS in flash_attention.py) is
-    # read at trace time. The inference executor caches (generation, beam,
-    # slot serving) fold it into their cache keys via
-    # ``modules.trace_env_fingerprint``, so a mid-process toggle rebuilds
-    # those executors; plain ``jax.jit`` call sites (train steps) are NOT
-    # keyed on it — set it before the first forward pass there, or isolate
-    # per-setting in a subprocess as examples/perf/tune_step.py does.
-    import os
-
-    try:
-        min_kv = int(os.environ.get("PERCEIVER_FLASH_MIN_KV", "0"))
-    except ValueError:
-        min_kv = 0
-    if k.shape[2] < min_kv:
-        return False
-    return jax.default_backend() == "tpu"
+def _flash_eligible(dropout_rate: float) -> bool:
+    """What ``impl='auto'`` asks before the kernel's own ``supported()``:
+    flash on a TPU and without attention dropout (the reference's default is
+    0.0 everywhere; a training config that enables it takes the einsum path)."""
+    return dropout_rate == 0.0 and jax.default_backend() == "tpu"
 
 
 def _attention_xla(

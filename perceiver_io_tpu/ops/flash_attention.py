@@ -81,26 +81,8 @@ _FUSED_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 _TWO_CALL_COUNTER = "flash_backward_two_call_total"
 
 
-def _candidates() -> Tuple[int, ...]:
-    """Block-size preference order, largest first. Overridable via
-    ``PERCEIVER_FLASH_BLOCKS`` (comma-separated, e.g. ``1024,512,256``) so the
-    schedule can be tuned on hardware without a code edit; invalid values are
-    ignored in favor of the default."""
-    import os
-
-    raw = os.environ.get("PERCEIVER_FLASH_BLOCKS")
-    if raw:
-        try:
-            blocks = tuple(int(x) for x in raw.split(","))
-            if blocks and all(b > 0 and b % LANES == 0 for b in blocks):
-                return blocks
-        except ValueError:
-            pass
-    return _BLOCK_CANDIDATES
-
-
 def _pick_block(n: int) -> Optional[int]:
-    for b in _candidates():
+    for b in _BLOCK_CANDIDATES:
         if n % b == 0:
             return b
     return None

@@ -31,7 +31,7 @@ over that layout:
   elsewhere so the tier-1 CPU suite exercises the same kernel body. The
   kernel's blockwise online softmax is exact but not bit-identical to
   the XLA einsum, so the gather path stays the bitwise oracle; the flag
-  is folded into ``modules.trace_env_fingerprint`` so a mid-process
+  is folded into ``ragged_attention.trace_env`` so a mid-process
   toggle rebuilds the decode executors instead of silently reusing the
   other trace.
 
@@ -214,7 +214,7 @@ def _ragged_kernel_attention(
     kernel is not enabled (caller degrades to the gather reference)."""
     from perceiver_io_tpu.ops import ragged_attention as ragged
 
-    if not ragged.kernel_enabled():
+    if not ragged.kernel_requested():
         return None
     o = ragged.ragged_paged_attention(
         q, pool_k, pool_v, table, lengths,

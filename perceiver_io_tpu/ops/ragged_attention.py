@@ -28,10 +28,9 @@ run by the Pallas interpreter on any other platform
 the tier-1 CPU suite executes the same kernel body. The kernel's
 online softmax is exact but not bitwise-equal to the XLA einsum, so the
 gather reference remains the bitwise oracle and the kernel is opt-in via
-``PERCEIVER_RAGGED_KERNEL=1`` (folded into
-``modules.trace_env_fingerprint`` + the CompileLedger ``kv_layout``
-component, so flips rebuild and attribute instead of silently reusing a
-stale trace).
+``PERCEIVER_RAGGED_KERNEL=1`` (folded into :func:`trace_env`, which keys
+every executor cache, + the CompileLedger ``kv_layout`` component, so flips
+rebuild and attribute instead of silently reusing a stale trace).
 
 Quantized pools: optional per-(position, head) f32 scales ride along as
 two more page-blocked inputs and the dequantize multiply happens inside
@@ -59,7 +58,7 @@ from jax.experimental.pallas import tpu as pltpu
 from perceiver_io_tpu.ops.flash_attention import pallas_call_on_lowering_platform
 
 #: trace-time env flag enabling the ragged kernel on every paged read
-#: path (see module docstring; folded into ``trace_env_fingerprint``)
+#: path (see module docstring; folded into :func:`trace_env`)
 ENV_KERNEL = "PERCEIVER_RAGGED_KERNEL"
 
 #: number of times a kernel launch was TRACED this process — a retrace
@@ -69,17 +68,20 @@ TRACE_COUNT = 0
 
 
 def kernel_requested() -> bool:
-    """Normalized read of :data:`ENV_KERNEL` (trace-time, like the flash
-    knobs — ``attention._flash_eligible`` discipline)."""
+    """Normalized read of :data:`ENV_KERNEL` (read while a program is
+    traced; unset, ``"0"`` and anything but ``"1"`` are one setting). Not
+    TPU-gated: other backends run the same kernel body under the Pallas
+    interpreter, so the flag in the CPU test suite exercises the real path."""
     return os.environ.get(ENV_KERNEL, "0") == "1"
 
 
-def kernel_enabled() -> bool:
-    """True when the ragged kernel should be traced. Unlike the retired
-    dense-Pallas opt-in this is NOT TPU-gated: non-TPU backends run the
-    same kernel body under the Pallas interpreter, so enabling the flag
-    in the CPU test suite exercises the real code path."""
-    return kernel_requested()
+def trace_env() -> tuple:
+    """What of the environment a traced program depends on, as one hashable
+    tuple: the generation, beam and slot executor caches and the decode
+    strategy registry key on it, and the compile ledger attributes a rebuild
+    it caused as ``trace_env``. A mid-process flip of :data:`ENV_KERNEL`
+    rebuilds those executors; flipping back hits the first ones."""
+    return (kernel_requested(),)
 
 
 def _make_kernel(block_size: int, pages: int, quantized: bool):

@@ -179,7 +179,7 @@ class FleetAutoscaler:
         #: post-mortem records of the last few scale-down victims (replica
         #: id, replayed in-flight count, final KV pool stats incl.
         #: ``frees_by_cause``) — the zero-leak evidence the acceptance
-        #: drill and ``extras.elasticity`` read after the engine is gone
+        #: drill reads after the engine is gone
         self.retired: list = []
         self._publish_gauges()
         fleet.autoscaler = self
@@ -393,7 +393,7 @@ class FleetAutoscaler:
 
     # -- observability -------------------------------------------------------
     def stats(self) -> dict:
-        """JSON-able snapshot for ``serve_stats`` / bench records."""
+        """JSON-able snapshot for ``serve_stats``."""
         now = self._clock()
         return {
             "min_replicas": self.min_replicas,

@@ -866,7 +866,7 @@ class ServingEngine:
         self.registry.inc("serving_prompt_tokens_padded_total", b * length)
         # decode-row accounting, comparable with the slot engine's: every
         # row of every decode step, split real vs batch-padding filler —
-        # the padding-waste ratio the serve bench A/B reports
+        # the padding-waste ratio `stats()` reports
         self.registry.inc("serving_decode_rows_total", b * cfg.max_new_tokens)
         self.registry.inc(
             "serving_decode_rows_padded_total",
@@ -920,7 +920,7 @@ class ServingEngine:
 
         ``compiles`` is the executor-cache miss delta — the engine assumes
         it owns the process's generation traffic over its lifetime (true for
-        the CLI, bench probe, and tests)."""
+        the CLI and tests)."""
         cache_now = executor_cache_stats()
         # clamp at 0: reset_executor_caches() mid-lifetime rewinds the global
         # counters below this engine's construction-time snapshot

@@ -325,11 +325,9 @@ def _probe_main(argv: Optional[list] = None) -> int:
     perceiver_io_tpu.serving.sharding``): build a tiny CLM, serve ragged
     greedy prompts through a slot engine on the requested mesh, print ONE
     JSON line — tokens/s, per-shard resident bytes, the emitted tokens
-    (the parent's token-identity pin), compile count. ``bench.py
-    extras.sharded_serving`` runs it twice (1-device vs 8-virtual-device
-    CPU mesh, the device count injected via ``XLA_FLAGS`` in the child
-    env) and A/Bs the records; ``make shard-bench`` is the one-command
-    form."""
+    (what two runs are compared by), compile count. Run it twice (one
+    device, then a virtual CPU mesh with the device count injected via
+    ``XLA_FLAGS``) to compare the records."""
     import argparse
     import json
     import time

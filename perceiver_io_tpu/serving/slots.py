@@ -66,10 +66,10 @@ unshared path (pinned by ``tests/test_prefix_cache.py``).
 ``PERCEIVER_DECODE_STRATEGY``): the boundary decode variant's
 implementation — cached migration step vs full windowed recompute — is a
 measured platform/shape choice (``inference/decode_strategy.py``; the
-cached step loses to recompute on CPU, docs/benchmarks.md). Both are
-exact, so greedy output stays token-identical either way; ``"auto"`` uses
-the autotuner's memoized verdict (``warmup()`` measures it once when asked
-explicitly).
+cached step loses to recompute on a CPU; on the chip: not measured). Both
+are exact, so greedy output stays token-identical either way; ``"auto"``
+uses the autotuner's memoized verdict (``warmup()`` measures it once when
+asked explicitly).
 
 **Speculative decoding** (``speculation="k<K>d<D>"`` /
 ``PERCEIVER_SPECULATION``; docs/serving.md "Speculative decoding"): a
@@ -651,8 +651,8 @@ def _build_decode_executor(model, config: GenerationConfig, boundary: bool,
     picks that step's implementation per the decode strategy
     (``inference/decode_strategy.py``): ``"cached"`` runs the cross-cache
     boundary-migration step, ``"recompute"`` the full windowed forward
-    (exact either way; the winner is a measured platform/shape property —
-    docs/benchmarks.md). Under recompute the boundary rows' cross caches go
+    (exact either way; the winner is a measured platform/shape property).
+    Under recompute the boundary rows' cross caches go
     stale, which is safe: a row never leaves the boundary phase (the
     sliding-window phase is out of the slot engine's scope)."""
     n = model.max_seq_len
@@ -1310,7 +1310,7 @@ class SlotServingEngine(ServingEngine):
         capacity/resident gauges. Also the warmup-time layout-switch path
         (an explicit ``kv_layout="auto"`` re-resolving after the
         autotuner) — callers must guarantee no residents."""
-        from perceiver_io_tpu.models.core.modules import trace_env_fingerprint
+        from perceiver_io_tpu.ops.ragged_attention import trace_env
 
         # swapped-out bundles reference the OUTGOING pool's shared blocks
         # and its device content — a rebuild invalidates both, so drop them
@@ -1362,11 +1362,10 @@ class SlotServingEngine(ServingEngine):
                 model, params, self.slots, self.config.pad_token_id
             ))
             self._table_dev = None
-        #: trace-env fingerprint the cached prefix blocks were computed
-        #: under — a mid-process flag flip (fused QKV, flash knobs) changes
-        #: the projection trace, so the index flushes rather than serve
-        #: values from the other regime
-        self._prefix_env = trace_env_fingerprint()
+        #: trace environment the cached prefix blocks were written under:
+        #: a mid-process flip rebuilds every executor (``_cache_key``), and
+        #: the index flushes with them rather than carry blocks across
+        self._prefix_env = trace_env()
         # analytic worst-case slot-KV footprint: per-position byte cost
         # computed from the RESOLVED layout's pool dtype (int8 pools store
         # 1-byte entries plus f32 per-(position, head) dequant scales —
@@ -1417,7 +1416,7 @@ class SlotServingEngine(ServingEngine):
         from perceiver_io_tpu.ops import ragged_attention as ragged_mod
         self.registry.set_gauge(
             "kv_ragged_kernel_enabled",
-            1 if (self._pool is not None and ragged_mod.kernel_enabled()) else 0,
+            1 if (self._pool is not None and ragged_mod.kernel_requested()) else 0,
         )
         if self.sharding is not None:
             # mesh geometry gauges (docs/observability.md): presence of
@@ -1534,7 +1533,7 @@ class SlotServingEngine(ServingEngine):
 
     # -- executors -----------------------------------------------------------
     def _cache_key(self, kind: str, *extra):
-        from perceiver_io_tpu.models.core.modules import trace_env_fingerprint
+        from perceiver_io_tpu.ops.ragged_attention import trace_env
 
         # max_new_tokens is scheduled host-side (per-request retirement), so
         # it must NOT key the executors — requests overriding it share one
@@ -1553,7 +1552,7 @@ class SlotServingEngine(ServingEngine):
         mesh_fp = () if self.sharding is None else self.sharding.fingerprint()
         return (
             kind, type(self.model).__qualname__, model_fingerprint(self.model),
-            cfg, self.slots, trace_env_fingerprint(), *kv, *mesh_fp, *extra,
+            cfg, self.slots, trace_env(), *kv, *mesh_fp, *extra,
         )
 
     def _ledger_components(self, **extra) -> dict:
@@ -1563,14 +1562,14 @@ class SlotServingEngine(ServingEngine):
         called on a cache MISS (the executor getters pass it as a thunk):
         the model-id hash and config normalization stay off the per-token
         hit path."""
-        from perceiver_io_tpu.models.core.modules import trace_env_fingerprint
+        from perceiver_io_tpu.ops.ragged_attention import trace_env
 
         cfg = dataclasses.replace(self.config, max_new_tokens=0)
         components = {
             "model": ledger_model_id(self.model),
             "config": cfg,
             "slots": self.slots,
-            "trace_env": trace_env_fingerprint(),
+            "trace_env": trace_env(),
             **extra,
         }
         if self.kv_layout in decode_strategy_mod.PAGED_KV_LAYOUTS:
@@ -1857,12 +1856,12 @@ class SlotServingEngine(ServingEngine):
         index = self._prefix_index
         if index is None:
             return None
-        from perceiver_io_tpu.models.core.modules import trace_env_fingerprint
+        from perceiver_io_tpu.ops.ragged_attention import trace_env
 
-        env = trace_env_fingerprint()
+        env = trace_env()
         if env != self._prefix_env:
-            # a trace-env flip changes the projection programs; cached
-            # values from the other regime must not cross it
+            # the executors that wrote these blocks are no longer the
+            # ones that would read them
             index.flush(self._pool)
             self._prefix_env = env
             self._update_kv_gauges()
@@ -3400,7 +3399,7 @@ class SlotServingEngine(ServingEngine):
         self.registry.inc("serving_decode_steps_total")
         if self._pool is not None:
             from perceiver_io_tpu.ops import ragged_attention as ragged_mod
-            if ragged_mod.kernel_enabled():
+            if ragged_mod.kernel_requested():
                 # decode steps served by the ragged paged-attention kernel
                 # (vs the gather-to-dense reference) — docs/observability.md
                 self.registry.inc("kv_ragged_kernel_steps_total")

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point that compiles on the chip (the family CLIs'
-``main``, ``chip_smoke.py``, ``bench.py``, ``examples/perf/tune_step.py``):
+``main``, ``chip_smoke.py``, ``benchmarks/run.py``):
 where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache there
 and the code sets nothing; where it is not, the cache goes to one fixed,
 git-ignored directory at the root of the checkout. The directory's path is

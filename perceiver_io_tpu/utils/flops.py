@@ -8,8 +8,8 @@ cross-attention extra, dataset-size helpers, and ``C ≈ 6N``.
 
 Differences from the reference: parameter counts come from
 ``jax.eval_shape`` over the real flax model — no materialized weights, so
-sweeping a config grid is free — and :func:`training_flops_total` gives the
-absolute per-step FLOPs the benchmark uses for MFU accounting.
+sweeping a config grid is free. (The benchmark's MFU counts a step's required
+FLOPs itself, ``benchmarks/rooflines/``.)
 """
 from __future__ import annotations
 
@@ -162,16 +162,3 @@ def training_flops(
     tokens = num_training_tokens(num_steps, estimator.num_latents, batch_size)
     per_token = estimator.total(num_channels, num_layers, prefix_dropout)
     return per_token * tokens, tokens
-
-
-def training_flops_per_step(
-    estimator: ComputeEstimator,
-    num_channels: int,
-    num_layers: int,
-    batch_size: int,
-    prefix_dropout: float = 0.0,
-) -> int:
-    """Absolute fwd+bwd FLOPs of ONE training step — MFU accounting for the
-    benchmark (eval-mode prefix_dropout = 0 counts the full prefix)."""
-    per_token = estimator.total(num_channels, num_layers, prefix_dropout)
-    return per_token * batch_size * estimator.num_latents

@@ -4,8 +4,7 @@ The reference has no tracing/profiling subsystem at all (SURVEY.md §5.1);
 this is the TPU-native upgrade: :func:`trace` wraps a region in a
 ``jax.profiler`` capture viewable in TensorBoard/Perfetto (device timelines,
 HLO cost attribution, HBM usage), and :class:`StepTimer` measures steady-state
-step time with correct ``block_until_ready`` fencing — the number
-``bench.py`` reports.
+step time with correct ``block_until_ready`` fencing.
 """
 from __future__ import annotations
 
@@ -55,8 +54,8 @@ class StepTimer:
         :param peak_flops: if also given, report MFU against it.
         :param registry: optional
             :class:`~perceiver_io_tpu.observability.MetricsRegistry` — the
-            measured numbers are published as ``<name>_*`` gauges so bench
-            timing exports through the same path as live telemetry.
+            measured numbers are published as ``<name>_*`` gauges, the
+            same export path as live telemetry.
         """
         for _ in range(self.warmup):
             jax.block_until_ready(step_fn())

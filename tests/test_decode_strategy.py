@@ -1,6 +1,5 @@
 """Decode-strategy + chunked-prefill tests (``inference/decode_strategy.py``,
-``serving/slots.py``; docs/serving.md, docs/benchmarks.md round-5 boundary
-resolution).
+``serving/slots.py``; docs/serving.md).
 
 The load-bearing assertions:
 
@@ -453,33 +452,3 @@ def test_serve_cli_decode_mode_env_deference(monkeypatch):
     monkeypatch.setenv(strategy_mod.ENV_VAR, "sometimes")
     with pytest.raises(SystemExit, match=strategy_mod.ENV_VAR):
         _serve_decode_mode("auto")
-
-
-@pytest.mark.slow  # suite-budget control, like the serve A/B probe test
-def test_bench_prefill_chunk_ab_probe_tiny(tiny_model):
-    """The bench.py chunked-prefill A/B runs at a pure-CPU tiny shape and
-    reports both arms' p95 resident inter-token latency (tiny shapes are
-    dispatch-bound, so no winner is asserted here; the reduced-shape bench
-    record is the acceptance number)."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, _ = tiny_model
-    out = bench._bench_prefill_chunk_ab(
-        model.config, slots=2, resident_new=6, n_long=2, chunk=4, episodes=2
-    )
-    for arm in ("with_chunking", "without_chunking"):
-        assert out[arm]["p95_inter_token_ms"] > 0
-        assert out[arm]["gaps"] >= 1
-        # the resident completes; how many stream admissions finish inside
-        # its lifetime differs by arm (chunked admissions span more steps)
-        assert out[arm]["completed"] >= 2
-    assert out["with_chunking"]["prefill_chunks"] > 0
-    assert out["without_chunking"]["prefill_chunks"] == 0
-    assert out["workload"]["probe_max_latents"] == model.config.max_latents
-    assert isinstance(out["chunking_lowers_p95"], bool)

@@ -647,30 +647,3 @@ def test_cli_autoscale_flag_group(tmp_path, tiny_model):
         clm_script.main(base + [
             "--serve.autoscale.max=2", "--serve.autoscale.scale_up_slots=4",
         ])
-
-
-# -- bench probe -------------------------------------------------------------
-@pytest.mark.slow  # 2026-08 audit: ~6s; real lane is `make elasticity` —
-# test_bench_probe.py keeps bench.py bitrot in tier-1
-def test_bench_elasticity_probe_tiny(tiny_model):
-    """The bench.py elasticity probe at a reduced shape: the A/B runs end
-    to end with the acceptance pins (zero dropped, token-identical,
-    zero-leak) intact; the goodput comparison itself is asserted at the
-    full probe shape, not this smoke size."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_ela_probe", "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    model, params = tiny_model
-    out = bench._bench_elasticity(
-        model, params, CausalLanguageModelConfig(**TINY),
-        n_requests=10, new_tokens=6, slots=1, max_replicas=2,
-    )
-    assert out["requests"] == 10
-    assert out["zero_dropped"] is True
-    assert out["token_identical"] is True
-    assert out["pool_zero_leak"] is True
-    assert out["autoscaled"]["replicas_final"] >= 1
-    assert 0.0 <= out["static"]["goodput_under_slo"] <= 1.0
-    assert 0.0 <= out["autoscaled"]["goodput_under_slo"] <= 1.0

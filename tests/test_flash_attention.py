@@ -81,29 +81,38 @@ def test_supported_gating():
     assert not flash_attention.supported(q3, k3, v3, causal=False)
 
 
-def test_block_candidates_env_override(monkeypatch):
-    monkeypatch.setenv("PERCEIVER_FLASH_BLOCKS", "1024,256")
-    assert flash_attention._candidates() == (1024, 256)
-    assert flash_attention._pick_block(512) == 256
-    assert flash_attention._pick_block(2048) == 1024
-    # invalid values are ignored in favor of the default
-    monkeypatch.setenv("PERCEIVER_FLASH_BLOCKS", "100,abc")
-    assert flash_attention._candidates() == flash_attention._BLOCK_CANDIDATES
-    monkeypatch.delenv("PERCEIVER_FLASH_BLOCKS")
+def test_pick_block_takes_the_largest_candidate_that_divides():
+    assert flash_attention._BLOCK_CANDIDATES == (512, 256, 128)
     assert flash_attention._pick_block(512) == 512
+    assert flash_attention._pick_block(1024) == 512
+    assert flash_attention._pick_block(768) == 256
+    assert flash_attention._pick_block(384) == 128
+    assert flash_attention._pick_block(100) is None
 
 
-def test_min_kv_env_gates_auto_dispatch(rng, monkeypatch):
+def test_auto_dispatch_depends_on_dropout_and_backend_alone(rng, monkeypatch):
     from perceiver_io_tpu.ops import attention
 
+    assert attention._flash_eligible(0.0) == (jax.default_backend() == "tpu")
+    assert not attention._flash_eligible(0.1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention._flash_eligible(0.0) and not attention._flash_eligible(0.1)
+
+    # 'auto' on a TPU takes the kernel at any supported length (256 keys here)
+    # and the einsum path under attention dropout; 'flash' equals 'xla'
+    calls = []
+    monkeypatch.setattr(
+        attention, "_flash_over_mesh",
+        lambda *a: calls.append(a[0].shape) or attention._attention_xla(*a, 0.0, None),
+    )
     q, k, v = _qkv(rng, 1, 2, 128, 256, 64)
-    monkeypatch.setenv("PERCEIVER_FLASH_MIN_KV", "512")
-    assert not attention._flash_eligible(q, k, v, 0.0)  # kv 256 < floor 512
-    monkeypatch.setenv("PERCEIVER_FLASH_MIN_KV", "256")
-    # kv >= floor: eligibility now depends only on the platform gate
-    assert attention._flash_eligible(q, k, v, 0.0) == (jax.default_backend() == "tpu")
-    # explicit impl='flash' ignores the auto floor
-    monkeypatch.setenv("PERCEIVER_FLASH_MIN_KV", "4096")
+    dot_product_attention(q, k, v, causal=True, impl="auto")
+    assert calls == [q.shape]
+    dot_product_attention(
+        q, k, v, causal=True, impl="auto", dropout_rate=0.1, dropout_rng=jax.random.PRNGKey(0)
+    )
+    assert calls == [q.shape]
+    monkeypatch.undo()
     out = dot_product_attention(q, k, v, causal=True, impl="flash")
     expected = dot_product_attention(q, k, v, causal=True, impl="xla")
     np.testing.assert_allclose(out, expected, atol=2e-5, rtol=2e-5)

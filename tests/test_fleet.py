@@ -674,26 +674,3 @@ def test_serve_cli_fleet(tmp_path):
             "--serve.prompt_buckets=8", "--serve.batch_buckets=2",
             "--serve.warmup=false", "--serve.step_timeout_s=5",
         ])
-
-
-# -- bench probe ------------------------------------------------------------
-def test_bench_fleet_chaos_probe_tiny(tiny_model):
-    """The bench.py fleet-chaos probe: scripted mid-decode replica kill,
-    completion ratio 1.0, token-identical recovery — the extras block the
-    trajectory records."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_fleet_probe", "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    model, params = tiny_model
-    out = bench._bench_fleet_chaos(
-        model, params, CausalLanguageModelConfig(**TINY),
-        n_requests=4, new_tokens=3, replicas=2,
-    )
-    assert out["submitted"] == 4
-    assert out["completed"] == 4 and out["completion_ratio"] == 1.0
-    assert out["failovers"] >= 1
-    assert out["token_identical"] is True
-    assert out["survived"] is True
-    assert out["goodput_tokens_per_sec"] > 0

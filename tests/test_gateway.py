@@ -5,8 +5,8 @@ terminal ``cancelled`` span), the asyncio gateway over real sockets
 (greedy outputs token-identical to in-process ``generate()``, including
 fleet-routed and paged-KV configurations), client-disconnect propagation
 with the zero-leak invariant, the scripted mass-abandonment chaos drill,
-socket-anchored TTFT, the loadgen HTTP client mode, the ``obs report``
-gateway section, and the bench streaming probe.
+socket-anchored TTFT, the loadgen HTTP client mode, and the ``obs report``
+gateway section.
 
 All CPU, tiny shapes, tier-1 under tight per-test budgets; socket tests
 bind ephemeral localhost ports and run the gateway's event loop in a
@@ -986,65 +986,6 @@ def test_every_gateway_family_has_direct_help(tiny_model):
     text = to_prometheus_text(engine.registry)
     for name in published:
         assert f"# HELP {name} " in text, name
-
-
-# -- bench probes -----------------------------------------------------------
-@pytest.mark.timeout(300)
-@pytest.mark.slow  # 2026-08 audit: ~4s; bench probes' real lane is their
-# make target (`make stream-bench`) and test_bench_probe.py keeps bench.py
-# import/CLI bitrot in tier-1
-def test_bench_streaming_probe_tiny(tiny_model):
-    """Tiny end-to-end run of the extras.streaming probe: deterministic
-    FakeClock abandonment with zero leak, closed accounting, survivor
-    identity, and a reclaim latency bounded by one scheduler pass."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_gw_tiny", "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    model, params = tiny_model
-    cfg = CausalLanguageModelConfig(**TINY)
-    out = bench._bench_streaming(
-        model, params, cfg, slots=2, n_requests=4, new_tokens=4,
-        cancel_after_tokens=1,
-    )
-    assert out["requests"] == 4 and out["abandoned"] == 2
-    assert out["token_identical"] is True
-    assert out["accounting_closed"] is True
-    assert out["completed"] == 2 and out["cancelled"] == 2
-    assert out["pool"]["leaked"] == 0
-    assert out["pool"]["in_use_after_drain"] == 0
-    assert out["pool"]["frees_by_cause"].get("cancelled", 0) > 0
-    assert out["reclaim"]["max_ms"] <= out["reclaim"]["bound_ms"]
-
-
-@pytest.mark.timeout(300)
-@pytest.mark.slow  # 2026-08 audit: ~6s; goodput accounting is pinned by
-# test_slo.py's unit drills — the sockets-transport probe re-proof rides
-# the `make slo` lane
-def test_bench_slo_goodput_http_transport_tiny(tiny_model):
-    """The one-flag transport switch: the same slo_goodput probe runs its
-    sweep over real sockets (GatewayHttpClient), reporting bytes-on-wire
-    per point with the shared goodput accounting."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_gw_http_tiny", "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    model, params = tiny_model
-    cfg = CausalLanguageModelConfig(**TINY)
-    out = bench._bench_slo_goodput(
-        model, params, cfg, requests_per_rate=4, new_tokens=3, slots=2,
-        rate_factors=(1.0,), transport="http",
-    )
-    assert out["transport"] == "http"
-    assert len(out["sweep"]) == 1
-    point = out["sweep"][0]
-    assert point["offered"] == 4
-    assert point["bytes_on_wire"] > 0
-    assert point["p95_ttft_ms"] is not None
-    with pytest.raises(ValueError, match="transport"):
-        bench._bench_slo_goodput(model, params, cfg, transport="carrier-pigeon")
 
 
 # -- CLI flag surface --------------------------------------------------------

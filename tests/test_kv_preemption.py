@@ -581,42 +581,3 @@ def test_preemption_stats_gauges_and_report(tiny_model):
     section = _kv_pool_section(snap)
     assert section["preemption"]["preemptions"] == pre["preemptions"]
     assert section["preemption"]["readmissions"] == pre["readmissions"]
-
-
-# -- the bench probe ---------------------------------------------------------
-@pytest.mark.slow  # 2026-08 audit: ~6s; real lane is `make preemption` —
-# test_bench_probe.py keeps bench.py bitrot in tier-1
-def test_bench_preemption_probe_tiny(tiny_model):
-    """The extras.preemption A/B at a pure-CPU tiny shape: optimistic
-    admission packs more residents per HBM byte than strict worst-case
-    reservation at the same budget, beats it on goodput-under-SLO,
-    actually exercises preempt/readmit cycles, and stays token-identical
-    (the acceptance invariants; the bench-shape record carries the real
-    numbers)."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(root, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    out = bench._bench_preemption(
-        model, params, model.config, budget_slots=2, engine_slots=8,
-        n_requests=12,
-    )
-    assert out["token_identical"] is True
-    assert out["optimistic"]["max_residents"] > out["strict"]["max_residents"]
-    assert out["max_residents_ratio"] > 1.0
-    assert out["optimistic"]["residents_per_hbm_byte"] > \
-        out["strict"]["residents_per_hbm_byte"]
-    assert out["optimistic"]["goodput_under_slo"] >= \
-        out["strict"]["goodput_under_slo"]
-    assert out["optimistic"]["preemptions"] > 0
-    assert out["optimistic"]["readmissions"] > 0
-    assert out["strict"]["preemptions"] == 0
-    assert out["strict"]["tokens_per_sec"] > 0
-    assert out["optimistic"]["tokens_per_sec"] > 0

@@ -246,7 +246,7 @@ def test_warmed_slot_engine_builds_all_in_ledger_and_report(
     trace-env knob is attributed as ``trace_env``, stats() carries the
     rollup, and `obs report` over the recorded events + snapshot reproduces
     the request-latency breakdown stats() reports."""
-    monkeypatch.delenv("PERCEIVER_FUSED_QKV", raising=False)
+    monkeypatch.delenv("PERCEIVER_RAGGED_KERNEL", raising=False)
     reset_executor_caches()
     default_ledger().reset()
     model, params = tiny_model
@@ -291,7 +291,7 @@ def test_warmed_slot_engine_builds_all_in_ledger_and_report(
                 if r["components"].get("model") == mid]) == 4
 
     # a post-warmup trace-env flip rebuilds, attributed as trace_env
-    monkeypatch.setenv("PERCEIVER_FUSED_QKV", "1")
+    monkeypatch.setenv("PERCEIVER_RAGGED_KERNEL", "1")
     engine.submit(_prompts((4,))[0])
     engine.run_until_idle()
     rebuilt = [r for r in ledger.records()

@@ -47,7 +47,6 @@ def _batches(n, batch_size=8, seed=0):
     return out
 
 
-@pytest.mark.slow  # 16-19s: heaviest tier-1 entries (2026-08 runtime audit)
 def test_multi_step_matches_sequential():
     model, cfg = tiny_clm()
     mesh = make_mesh(MeshConfig(data=2))
@@ -90,13 +89,17 @@ def test_multi_step_matches_sequential():
             blk_losses.extend(float(x) for x in m["loss"])
     blk_params = jax.device_get(state.params)
 
-    np.testing.assert_allclose(blk_losses, seq_losses, rtol=1e-6)
+    # a scan over k steps and k calls are the same float32 sums in another
+    # association (XLA fuses across the scan's body), so equal to rounding:
+    # Adam at 1e-2 moves a weight by about 1e-2 a step, and 1e-6 is a
+    # ten-thousandth of one step
+    np.testing.assert_allclose(blk_losses, seq_losses, rtol=1e-5, atol=1e-6)
     jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6), blk_params, seq_params
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+        blk_params, seq_params,
     )
 
 
-@pytest.mark.slow  # 16-19s: heaviest tier-1 entries (2026-08 runtime audit)
 def test_trainer_steps_per_execution_matches_single(tmp_path):
     model, cfg = tiny_clm()
     prefix_len = SEQ - LATENTS
@@ -142,13 +145,13 @@ def test_trainer_steps_per_execution_matches_single(tmp_path):
     # flush on every multiple of 2, the blocked run flushes at block ends
     assert log_steps[1] == [2, 4, 5, 6, 8, 10], log_steps[1]
     assert log_steps[4] == [4, 5, 9, 10], log_steps[4]
+    # equal to rounding, as in test_multi_step_matches_sequential
     jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6),
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
         finals[1], finals[4],
     )
 
 
-@pytest.mark.slow  # 16-19s: heaviest tier-1 entries (2026-08 runtime audit)
 def test_multi_step_composes_with_grad_accum():
     """grad_accum_steps × multi_steps in one jitted program equals the
     sequential accumulated steps (the flagship clm.sh config uses both)."""
@@ -185,7 +188,6 @@ def test_multi_step_composes_with_grad_accum():
     )
 
 
-@pytest.mark.slow
 def test_ragged_block_raises_clear_error(tmp_path):
     """A user iterable yielding a short last batch under
     ``steps_per_execution>1`` must fail with the actual ``k_exec`` integer and

@@ -1,13 +1,12 @@
 """Unified-telemetry-layer suite (docs/observability.md): registry units,
 span tracing, exporters, the serving-engine + trainer integrations, the
-metrics.jsonl schema migration, StepTimer coverage, the profiler trigger,
-and the bench observability probe.
+metrics.jsonl schema migration, StepTimer coverage and the profiler trigger.
 
 The load-bearing acceptance tests: under FakeClock + a chaos script, span
 accounting CLOSES — every submitted request ends in exactly one terminal
 ``serving.request`` span and the registry counters reconcile with
 ``ServingEngine.stats()`` — and (slow tier) instrumentation overhead on a
-StepTimer-measured CPU bench step stays under 2%.
+StepTimer-measured CPU jitted step stays under 2%.
 """
 import json
 import os
@@ -411,6 +410,12 @@ def test_span_accounting_closes_under_chaos(tiny_model):
     assert submitted == stats["completed"] + stats["timed_out"] + stats["failed"]
     assert shed == stats["shed"] == 2
     assert stats["queued"] == 0
+    # goodput over the engine's own counters: completed / offered load
+    # (accepted + shed + rejected), the one shared definition
+    from perceiver_io_tpu.observability import goodput_ratio
+    assert goodput_ratio(registry.counters()) == pytest.approx(
+        stats["completed"] / (submitted + shed + stats["rejected"])
+    )
     # each enqueued request's trace is unique and ends exactly once
     enqueued_traces = [s.trace_id for s in terminals if s.status != "shed"
                        and s.status != "rejected"]
@@ -881,39 +886,12 @@ def test_serve_cli_lines_carry_trace_id_and_join_events(tmp_path):
         assert terminal[line["trace_id"]] == line["status"]
 
 
-# -- bench probe ------------------------------------------------------------
-def test_bench_observability_probe_tiny(tiny_model):
-    """``bench.py extras.observability`` runs on pure CPU and reports the
-    per-phase histograms, goodput, and an MFU key (None off-TPU)."""
-    import importlib.util
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    out = bench._bench_observability(model, params, model.config,
-                                     n_requests=6, new_tokens=2)
-    assert out["tokens_per_sec"] > 0
-    assert out["span_accounting_closed"] is True
-    assert out["goodput"] == pytest.approx(5 / 6, abs=1e-3)  # one injected failure
-    assert "mfu" in out  # None on CPU (no peak claim), a float on TPU
-    for hist in ("queue_wait_ms", "batch_assembly_ms", "device_execute_ms"):
-        assert out[hist]["count"] > 0
-        assert out[hist]["p95"] is not None
-    assert out["terminal_spans"].get("failed") == 1
-    assert out["snapshot"]["gauges"]["serving_goodput_ratio"] == pytest.approx(
-        out["goodput"], abs=1e-3
-    )
-
-
 # -- overhead: instrumentation < 2% -----------------------------------------
 @pytest.mark.slow
 def test_instrumentation_overhead_under_2_percent():
     """StepTimer delta with full per-step instrumentation (registry counter +
     two histogram observes + a traced span + LEDGER-WRAPPED executor
-    dispatch) vs bare, on a CPU bench-shaped jitted step. The workload is
+    dispatch) vs bare, on a CPU jitted step. The workload is
     sized so a step is ~10ms of real device work; the instrumented path adds
     a handful of dict ops under one lock plus the ledger wrapper's
     compiled-dispatch indirection and must stay within 2%."""

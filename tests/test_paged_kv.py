@@ -377,34 +377,3 @@ def test_engine_kv_layout_env_resolution(tiny_model, monkeypatch):
         )
     with pytest.raises(ValueError, match="choosing the paged layout"):
         SlotServingEngine(model, params, cfg, table, slots=2, kv_blocks=4)
-
-
-# -- bench probe ------------------------------------------------------------
-@pytest.mark.slow  # 2026-08 audit: ~6s; real lane is `make paged-bench` —
-# test_bench_probe.py keeps bench.py bitrot in tier-1
-def test_bench_paged_kv_probe_tiny(tiny_model):
-    """The extras.paged_kv A/B at a pure-CPU tiny shape: the paged pool
-    admits strictly more concurrent residents than dense at the same
-    simulated HBM budget on the long-tail workload, outputs token-identical
-    (the acceptance invariants; the bench-shape record carries the real
-    numbers)."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    out = bench._bench_paged_kv(
-        model, params, model.config, dense_slots=2, paged_slots=4, n_requests=8,
-    )
-    assert out["token_identical"] is True
-    assert out["paged"]["max_residents"] > out["dense"]["max_residents"]
-    assert out["max_residents_ratio"] > 1.0
-    assert out["dense"]["tokens_per_sec"] > 0
-    assert out["paged"]["tokens_per_sec"] > 0
-    assert 0.0 < out["paged"]["page_utilization_high_water"] <= 1.0
-    assert out["paged"]["block_allocs"] == out["paged"]["block_frees"] > 0
-    assert out["workload"]["hbm_budget_bytes"] == out["dense"]["kv_resident_bytes"]

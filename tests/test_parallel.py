@@ -101,21 +101,14 @@ def baseline():
     return run_steps(MeshConfig(data=1))[0]
 
 
-# 2026-08 runtime audit: the composed multi-axis meshes drift past
-# rtol=2e-4 against the 1-device baseline on the current jax build
-# (reduction-order change under GSPMD; the single-axis meshes still
-# match) and cost ~9s each — kept as `slow` depth until the trajectory
-# goldens/tolerances are re-recorded on the pinned build.
 @pytest.mark.parametrize(
     "mesh_config",
     [
         MeshConfig(data=8),
-        pytest.param(  # 2026-08 audit: ~10s; dp8 keeps the tier-1 signal,
-            MeshConfig(data=1, fsdp=8), marks=pytest.mark.slow
-        ),  # fsdp sharding itself is pinned by the cheap shard-layout test
-        pytest.param(MeshConfig(data=2, fsdp=4), marks=pytest.mark.slow),
-        pytest.param(MeshConfig(data=2, fsdp=2, model=2), marks=pytest.mark.slow),
-        pytest.param(MeshConfig(data=1, fsdp=2, model=4), marks=pytest.mark.slow),
+        MeshConfig(data=1, fsdp=8),
+        MeshConfig(data=2, fsdp=4),
+        MeshConfig(data=2, fsdp=2, model=2),
+        MeshConfig(data=1, fsdp=2, model=4),
     ],
     ids=["dp8", "fsdp8", "dp2xfsdp4", "dp2xfsdp2xtp2", "fsdp2xtp4"],
 )
@@ -127,17 +120,9 @@ def test_sharded_matches_single_device(baseline, mesh_config):
 @pytest.mark.parametrize(
     "mesh_config",
     [
-        # 2026-08 audit: ~10s each; seq-parallel re-proofs keep `slow`
-        # depth (the ring-attention op tests are the tier-1 seq signal)
-        pytest.param(
-            MeshConfig(data=1, fsdp=1, model=1, seq=8), marks=pytest.mark.slow
-        ),
-        pytest.param(
-            MeshConfig(data=2, fsdp=1, model=1, seq=4), marks=pytest.mark.slow
-        ),
-        pytest.param(
-            MeshConfig(data=2, fsdp=2, model=1, seq=2), marks=pytest.mark.slow
-        ),
+        MeshConfig(data=1, fsdp=1, model=1, seq=8),
+        MeshConfig(data=2, fsdp=1, model=1, seq=4),
+        MeshConfig(data=2, fsdp=2, model=1, seq=2),
     ],
     ids=["sp8", "dp2xsp4", "dp2xfsdp2xsp2"],
 )
@@ -151,8 +136,7 @@ def test_sequence_parallel_matches_single_device(baseline, mesh_config):
 
 @pytest.mark.parametrize("accum,mesh_config", [
     (2, MeshConfig(data=1)),
-    # 2026-08 audit: ~9s; accum2 keeps the tier-1 averaging-parity signal
-    pytest.param(4, MeshConfig(data=2), marks=pytest.mark.slow),
+    (4, MeshConfig(data=2)),
 ], ids=["accum2", "accum4xdp2"])
 def test_grad_accumulation_matches_full_batch(baseline, accum, mesh_config):
     """A step over N microbatches must equal the full-batch step: equal-sized
@@ -193,7 +177,6 @@ def test_tp_shards_attention_heads():
     assert sa["o_proj"]["kernel"] == jax.sharding.PartitionSpec(AXIS_MODEL, None)
 
 
-@pytest.mark.slow  # 2026-08 audit: ~11s composed-mesh smoke; dp8 parity stays tier-1
 def test_grad_norm_logged():
     losses, state, mesh = run_steps(MeshConfig(data=4, fsdp=2), n_steps=2)
     assert len(losses) == 2 and all(np.isfinite(losses))

@@ -813,34 +813,3 @@ def test_workload_shared_prefix_zipf_deterministic_end_to_end(tiny_model):
     assert report["completed"] == 6
     assert engine.registry.counter("kv_prefix_hits_total") > 0
     assert engine._pool.leaked() == 0
-
-
-# -- bench probe ------------------------------------------------------------
-@pytest.mark.slow  # 2026-08 audit: ~6s; real lane is `make prefix-bench` —
-# test_bench_probe.py keeps bench.py bitrot in tier-1
-def test_bench_prefix_cache_probe_tiny(tiny_model):
-    """The extras.prefix_cache A/B at a pure-CPU tiny shape: outputs
-    token-identical between arms, hits recorded, the shared arm packs at
-    least as many concurrent residents per HBM byte, and the record
-    carries the acceptance fields (the bench-shape run carries the real
-    TTFT ratios)."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    out = bench._bench_prefix_cache(
-        model, params, model.config, slots=3, n_requests=8, n_prefixes=2,
-        block_size=4, prefix_tokens=12, new_tokens=3,
-    )
-    assert out["token_identical"] is True
-    assert out["hit_ratio"] > 0
-    assert out["residents_per_hbm_byte_ratio"] >= 1.0
-    assert out["shared"]["max_residents"] >= out["unshared"]["max_residents"]
-    assert out["ttft_p95_ratio"] > 0
-    assert out["workload"]["hbm_budget_bytes"] > 0
-    assert out["shared"]["prefix"]["hits"] > 0

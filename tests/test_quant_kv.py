@@ -20,9 +20,7 @@ The load-bearing assertions:
   exact paged, ``autotune_kv_layout`` demotes a failed gate to exact
   layouts, serving warmup surfaces the demotion on
   ``kv_quant_fallback_total``, and the verdict round-trips the registry
-  artifact (corrupt files degrade to re-measurement);
-- the ``extras.quant_kv`` bench A/B admits >= 3x the residents per
-  simulated HBM byte.
+  artifact (corrupt files degrade to re-measurement).
 
 All pure-CPU, tiny shapes — tier-1 (marker ``quant_kv``).
 """
@@ -369,35 +367,3 @@ def test_engine_warmup_quant_fallback_counter(tiny_model, monkeypatch):
             assert engine.stats()["kv_pool"]["quant_fallbacks"] == 1
     finally:
         strategy_mod.reset_registry()
-
-
-# -- bench probe ------------------------------------------------------------
-@pytest.mark.slow  # bench A/B probe — `make quant-bench` runs it; the tier-1
-# budget keeps only the direct unit/parity pins (the PR 14 audit discipline)
-def test_bench_quant_kv_probe_tiny(tiny_model):
-    """The extras.quant_kv A/B at a pure-CPU tiny shape: at ONE simulated
-    HBM budget the int8 pool admits >= 3x the concurrent residents of the
-    exact pool (the ISSUE 16 acceptance ratio; 4d/(d+4) = 3.2x cheaper
-    blocks at d=16), with the quality-gate verdict riding in the
-    record."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    out = bench._bench_quant_kv(
-        model, params, model.config, exact_slots=2, n_requests=8,
-    )
-    assert out["exact"]["dtype"] == "float32" and out["int8"]["dtype"] == "int8"
-    assert out["block_bytes_ratio"] == 3.2  # 4d/(d+4) at d=16
-    assert out["int8"]["max_residents"] >= 3 * out["exact"]["max_residents"]
-    assert out["residents_per_hbm_byte_ratio"] >= 3.0
-    assert out["int8"]["kv_blocks"] * 4 * out["int8"]["pos_bytes"] <= \
-        out["workload"]["hbm_budget_bytes"]
-    assert 0.0 < out["token_match_rate"] <= 1.0
-    assert out["quality_gate"]["passed"] is True
-    assert out["exact"]["tokens_per_sec"] > 0 and out["int8"]["tokens_per_sec"] > 0

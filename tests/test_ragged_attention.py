@@ -18,7 +18,7 @@ The load-bearing assertions:
 - the compile bound is UNCHANGED (``len(prompt_buckets) + 2``) — no
   per-phase kernel variants — and steady-state traffic neither retraces
   executors nor re-traces the kernel (``TRACE_COUNT``);
-- the flag folds into ``trace_env_fingerprint`` (a mid-process toggle
+- the flag folds into ``ragged_attention.trace_env`` (a mid-process toggle
   rebuilds, never silently reuses) and dispatch is observable
   (``kv_ragged_kernel_steps_total`` / ``kv_ragged_kernel_enabled``).
 
@@ -38,7 +38,6 @@ from perceiver_io_tpu.inference.generate import (
     reset_executor_caches,
 )
 from perceiver_io_tpu.inference.samplers import SamplingConfig
-from perceiver_io_tpu.models.core import modules
 from perceiver_io_tpu.models.text.clm import CausalLanguageModel, CausalLanguageModelConfig
 from perceiver_io_tpu.ops import paged_attention as paged_ops
 from perceiver_io_tpu.ops import ragged_attention as ragged_mod
@@ -166,18 +165,42 @@ def test_kernel_ragged_rows_one_launch(q_len):
 
 def test_flag_normalization_and_fingerprint(monkeypatch):
     """The opt-in flag is trace-time state: it folds into
-    ``trace_env_fingerprint`` so executor caches rebuild on a mid-process
-    toggle instead of silently serving the other program."""
+    ``ragged_attention.trace_env`` so executor caches rebuild on a
+    mid-process toggle instead of silently serving the other program."""
     monkeypatch.delenv(ragged_mod.ENV_KERNEL, raising=False)
-    assert not ragged_mod.kernel_enabled()
-    off = modules.trace_env_fingerprint()
+    assert not ragged_mod.kernel_requested()
+    off = ragged_mod.trace_env()
     monkeypatch.setenv(ragged_mod.ENV_KERNEL, "1")
-    assert ragged_mod.kernel_requested() and ragged_mod.kernel_enabled()
-    on = modules.trace_env_fingerprint()
-    assert on != off and on[-1] is True and off[-1] is False
+    assert ragged_mod.kernel_requested()
+    on = ragged_mod.trace_env()
+    assert on == (True,) and off == (False,)
     monkeypatch.setenv(ragged_mod.ENV_KERNEL, "0")  # explicit off == unset
-    assert not ragged_mod.kernel_enabled()
-    assert modules.trace_env_fingerprint() == off
+    assert not ragged_mod.kernel_requested()
+    assert ragged_mod.trace_env() == off
+
+
+def test_generation_executor_cache_keys_on_the_flag(tiny_model, monkeypatch):
+    """A mid-process flip of the flag rebuilds the generation executor (the
+    flag is part of its cache key) and flipping back HITS the first one:
+    never a program traced under the other setting, never a third build."""
+    model, params = tiny_model
+    ids = jnp.asarray(np.random.default_rng(2).integers(1, 71, (1, 6)), jnp.int32)
+    cfg = GenerationConfig(max_new_tokens=3, num_latents=2, sampling=GREEDY)
+
+    monkeypatch.delenv(ragged_mod.ENV_KERNEL, raising=False)
+    out0 = np.asarray(generate(model, params, ids, cfg))
+    before = executor_cache_stats()
+    monkeypatch.setenv(ragged_mod.ENV_KERNEL, "1")
+    out1 = np.asarray(generate(model, params, ids, cfg))
+    mid = executor_cache_stats()
+    assert mid["misses"] - before["misses"] == 1  # fresh executor, not reuse
+    monkeypatch.setenv(ragged_mod.ENV_KERNEL, "0")
+    out2 = np.asarray(generate(model, params, ids, cfg))
+    after = executor_cache_stats()
+    assert after["misses"] == mid["misses"] and after["hits"] - mid["hits"] == 1
+    # generate() holds a dense cache, which the kernel never reads
+    np.testing.assert_array_equal(out0, out1)
+    np.testing.assert_array_equal(out0, out2)
 
 
 # -- engine parity under the flag -------------------------------------------

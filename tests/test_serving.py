@@ -156,6 +156,25 @@ def test_distinct_lengths_single_bucket_single_build(tiny_model):
     assert engine.stats()["batches"] == len(prompts)
 
 
+def test_second_engine_over_the_same_traffic_compiles_nothing(tiny_model):
+    """The executor cache is the process's, not the engine's: a first engine
+    pays every bucket's compile, and a fresh engine on the same model, config
+    and table serves the same traffic with zero builds and the same tokens."""
+    model, params = tiny_model
+    cfg = GenerationConfig(max_new_tokens=2, num_latents=2, sampling=GREEDY)
+    table = BucketTable(prompt_lens=(4, 8, 16), batch_sizes=(2, 4))
+    prompts = _ragged_prompts(np.random.default_rng(4), [2, 3, 5, 7, 9, 16])
+    first = ServingEngine(model, params, cfg, table)
+    cold = first.serve(prompts)
+    assert 1 <= first.stats()["compiles"] <= len(table)
+    second = ServingEngine(model, params, cfg, table)
+    warm = second.serve(prompts)
+    stats = second.stats()
+    assert stats["compiles"] == 0 and stats["requests"] == len(prompts)
+    for a, b in zip(cold, warm):
+        np.testing.assert_array_equal(a, b)
+
+
 @pytest.mark.slow
 def test_warmup_precompiles_all_buckets(tiny_model):
     """After warmup, a mixed workload (including the pad-overflow phase
@@ -352,51 +371,3 @@ def test_serve_cli_maps_infeasible_prompt_to_error_record(tmp_path):
     assert "completion" in results[0] and "completion" in results[2]
     assert results[1]["status"] == "rejected"
     assert "exceeds the largest bucket" in results[1]["error"]
-
-
-# -- bench probe -----------------------------------------------------------
-def test_bench_serve_probe_tiny(tiny_model):
-    """The bench.py serving probe must emit tokens/s + compile_count on a
-    pure-CPU tiny shape — the extras block the trajectory records."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    # with_ab=False: the slots-vs-bucket A/B has its own tiny probe test
-    # (tests/test_slots.py) — running it twice would bloat the tier-1 budget
-    out = bench._bench_serve(
-        model, params, model.config, n_requests=6, new_tokens=2, with_ab=False
-    )
-    assert out["tokens_per_sec"] > 0
-    assert out["compile_count"] >= 1
-    assert out["steady_state_compiles"] == 0  # second pass fully warm
-    assert out["requests"] == 6 and out["new_tokens"] == 2
-    assert out["p95_queue_wait_ms"] >= out["p50_queue_wait_ms"] >= 0.0
-    assert out["distinct_prompt_lens"] >= 1
-
-
-@pytest.mark.chaos
-def test_bench_chaos_probe_tiny(tiny_model):
-    """The bench.py chaos probe (``extras.chaos``) is deterministic on CPU:
-    fixed shed/timeout/failure counts, engine accounting closed."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    out = bench._bench_chaos(model, params, model.config)
-    assert out["survived"] is True
-    assert out["submitted"] == 8
-    assert out["shed"] == 2  # max_queue = 6
-    assert out["timed_out"] == 1 and out["failed"] == 1
-    assert out["completed"] == 4
-    assert out["ready_after_drain"] is False  # drained engines stop accepting

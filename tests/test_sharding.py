@@ -594,13 +594,13 @@ def test_serve_cli_mesh_flag_group(tmp_path):
         ])
 
 
-# -- bench probe ------------------------------------------------------------
-@pytest.mark.slow  # compiles its own probe model; `make shard-bench` is its lane
+# -- the module's own probe -------------------------------------------------
+@pytest.mark.slow  # compiles its own probe model
 def test_shard_probe_main_records(capsys):
     """The self-contained sharded-serving probe (``python -m
     perceiver_io_tpu.serving.sharding``) emits one JSON record with the
     A/B-able fields: mesh geometry, tokens/s, per-shard resident bytes,
-    and the token streams bench.py pins for identity."""
+    and the token streams (two runs of one workload can be compared)."""
     import json
 
     from perceiver_io_tpu.serving.sharding import _probe_main

@@ -103,8 +103,7 @@ def test_policy_and_monitor_validation():
 @pytest.mark.timeout(60)
 def test_offered_goodput_shared_definition():
     """The ONE goodput denominator (observability/slo.py): offered =
-    accepted + shed + rejected, for both counter prefixes — the helper
-    bench's fleet_chaos / observability / slo_goodput probes share."""
+    accepted + shed + rejected, for both counter prefixes."""
     counts = {
         "serving_requests_submitted_total": 8.0,
         "serving_requests_shed_total": 2.0,
@@ -794,40 +793,6 @@ def test_every_paged_slot_engine_family_has_direct_help(tiny_model):
     text = to_prometheus_text(engine.registry)
     for name in published:
         assert f"# HELP {name} " in text, name
-
-
-# -- bench probe ------------------------------------------------------------
-@pytest.mark.timeout(300)
-@pytest.mark.slow  # 2026-08 audit: ~6s; real lane is `make slo` —
-# test_bench_probe.py keeps bench.py bitrot in tier-1
-def test_bench_slo_goodput_probe_tiny(tiny_model):
-    """Tiny end-to-end sweep through the real bench probe: the record
-    carries the goodput-under-SLO curve (p95 TTFT / p95 ITL per offered
-    rate), a knee, calibration-derived targets, and the obs-report
-    percentile cross-check."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_slo_tiny", "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    model, params = tiny_model
-    cfg = CausalLanguageModelConfig(**TINY)
-    out = bench._bench_slo_goodput(
-        model, params, cfg, requests_per_rate=5, new_tokens=3, slots=2,
-        rate_factors=(0.5, 2.0),
-    )
-    assert len(out["sweep"]) == 2
-    for point in out["sweep"]:
-        assert point["p95_ttft_ms"] is not None
-        assert point["p95_inter_token_ms"] is not None
-        assert point["offered"] == 5
-        assert 0.0 <= point["goodput_ratio"] <= 1.0
-    assert out["slo"]["ttft_p95_ms"] > 0
-    assert out["knee"]["index"] in (0, 1)
-    assert out["knee"]["goodput_rps"] == max(
-        p["goodput_rps"] for p in out["sweep"]
-    )
-    assert out["report_percentiles_match_registry"] is True
 
 
 # -- CLI flag group ---------------------------------------------------------

@@ -321,30 +321,3 @@ def test_serve_cli_slots_engine(tmp_path):
     assert [r["completion"] for r in slots] == [r["completion"] for r in bucket]
     with pytest.raises(SystemExit, match="bucket.*or.*slots"):
         clm_script.main(common + ["--serve.engine=nope"])
-
-
-@pytest.mark.slow  # 12s bench probe; `make serve-bench` is its real lane (runtime audit)
-def test_bench_serve_ab_probe_tiny(tiny_model):
-    """The bench.py slots-vs-bucket A/B runs at a pure-CPU tiny shape and
-    records both engines' tokens/s, the speedup ratio, slot occupancy, and
-    the padding-waste ratios (tiny shapes are dispatch-bound, so no winner
-    is asserted here; the bench-shape record is the acceptance number)."""
-    import importlib.util
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    model, params = tiny_model
-    out = bench._bench_serve_ab(model, params, model.config, n_requests=4, slots=2)
-    assert out["bucket"]["tokens_per_sec"] > 0
-    assert out["bucket_exact"]["tokens_per_sec"] > 0
-    assert out["slots"]["tokens_per_sec"] > 0
-    assert out["slots_vs_bucket_speedup"] > 0
-    assert out["slots_vs_bucket_exact_speedup"] > 0
-    assert 0.0 < out["slots"]["slot_occupancy"] <= 1.0
-    assert 0.0 <= out["slots"]["decode_rows_padding_waste"] < 1.0
-    assert 0.0 <= out["bucket"]["decode_rows_padding_waste"] < 1.0
-    assert out["workload"]["requests"] == 4

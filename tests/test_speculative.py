@@ -275,8 +275,8 @@ class _ScriptClock:
     """Sampled twice per arm ("off" first): charges off 10s, the draft 1s —
     the decode-strategy suite's scripted-clock discipline, so the decision
     logic pins replayably while the engines (and the acceptance gate they
-    feed) run for real. The real-clock "drafting pays" direction is the
-    bench extras' pin (`make spec-bench`, extras.speculative speedup)."""
+    feed) run for real. The real-clock "drafting pays" direction is not
+    measured (no serving cell yet: PERF.md section 7)."""
     script = [0.0, 10.0, 10.0, 11.0]
 
     def __init__(self):
