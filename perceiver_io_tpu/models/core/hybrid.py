@@ -7,10 +7,12 @@ MLP, a gated short convolution and a layer of sparse experts. With
 The phases inside the modules carry ``jax.named_scope``s (``short_conv``;
 ``router``, ``dispatch``, ``experts``, ``combine``): Flax names the modules,
 these name what a module does, so that ``observability.ledger.op_scopes``
-places every device operation (docs/observability.md).
+places every device operation (docs/observability.md). The expert layer's
+``lax.cond`` stands outside them, so that no ``conditional`` carries a phase.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -21,6 +23,23 @@ import jax.numpy as jnp
 from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+#: The expert layer's row bound over what uniform routing sends to the held
+#: experts, ``m = tokens * top_k * num_experts / router_width`` pairs. A
+#: layer's held pairs are a sum of ``tokens * top_k`` choices: under uniform
+#: routing their spread is under ``sqrt(m)`` (91 at the 8,192 of 16,384
+#: tokens, 4 of 64 experts a token, 8 held), so chance alone needs a per cent.
+#: What needs room is routing that is not uniform: a selection bias seeded at
+#: 0.01 puts the fullest held expert at 1.5 times the mean one and a layer's
+#: held pairs within a few per cent of ``m`` (33.4 k a step over four layers
+#: for 32.8 k), a bias of 0.1 puts an expert at 3.7 times (PERF.md, PR 28).
+#: Twice ``m`` holds a layer all of whose held experts run half again over
+#: their share and then some, and the rows' cost is linear in the bound: at 8
+#: of 64 it is a quarter of the worst case, where ``1.25 m`` would be a
+#: sixth. Past it nothing is wrong: the layer pays the worst case that step.
+_ROWS_OVER_UNIFORM = 2
+#: rows of a tile of the grouped product's kernel on the TPU (``ragged-dot-none``)
+_ROW_TILE = 512
 
 
 def _dense(features: int, init_scale: float, dtype, name: str) -> nn.Dense:
@@ -88,46 +107,67 @@ class ShortConv(nn.Module):
 
 
 @jax.custom_vjp
-def _take_tokens(tokens, order, inverse, valid):
-    """``tokens[order // k]`` for ``order`` a permutation of the ``t * k``
-    token-expert pairs (pair ``p`` is token ``p // k``). The backward pass is
-    the inverse permutation's gather and a sum over each token's ``k`` pairs:
-    no scatter either way. ``valid`` marks the sorted rows that a held expert
+def _take_tokens(tokens, ids, valid, inverse):
+    """``tokens[ids]`` for ``ids`` ``(rows,)``, the tokens of the first
+    ``rows`` sorted pairs; ``inverse`` ``(t * k,)`` is every pair's place in
+    the sorted order (pair ``p`` is token ``p // k``). The backward pass is a
+    gather too: a token takes the gradient rows of its ``k`` pairs, choice by
+    choice (``(k, t, c)``, summed over the leading dimension in float32: no
+    ``(t, k, c)`` copy padded to the tiling), and a pair whose place is past
+    ``rows`` takes zeros. ``valid`` marks the sorted rows that a held expert
     computes; the gradient of the others is dropped, whatever it holds: a
-    grouped product's kernel leaves the rows past its last group unwritten."""
-    return tokens[order // (order.shape[0] // tokens.shape[0])]
+    grouped product's kernel leaves the rows past its last group unwritten.
+    (The other form, a float32 scatter-add of the rows by token id, put a
+    layer's ``dispatch`` at 2.97 ms on the chip against 1.50: PERF.md, PR 31.)"""
+    return tokens[ids]
 
 
-def _take_tokens_fwd(tokens, order, inverse, valid):
-    return _take_tokens(tokens, order, inverse, valid), (inverse, valid, tokens.shape[0])
+def _take_tokens_fwd(tokens, ids, valid, inverse):
+    return tokens[ids], (valid, inverse, tokens.shape[0])
 
 
 def _take_tokens_bwd(res, g):
-    inverse, valid, t = res
+    valid, inverse, t = res
     g = jnp.where(valid[:, None], g, jnp.zeros((), g.dtype))
-    by_token = g[inverse].reshape(t, -1, g.shape[-1])
-    return by_token.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None, None
+    by_choice = jnp.take(g, inverse.reshape(t, -1).T, axis=0, mode="fill", fill_value=0)
+    return by_choice.astype(jnp.float32).sum(axis=0).astype(g.dtype), None, None, None
 
 
 _take_tokens.defvjp(_take_tokens_fwd, _take_tokens_bwd)
 
 
 @jax.custom_vjp
-def _permute_rows(x: jnp.ndarray, perm: jnp.ndarray, inverse: jnp.ndarray) -> jnp.ndarray:
-    """``x[perm]`` for a permutation and its inverse; the backward pass is
-    ``g[inverse]``, a gather like the forward."""
-    return x[perm]
+def _combine(out_rows, weights, head):
+    """``(t, c)``: every token's sum of its sorted rows under their weights.
+    ``head`` ``(rows,)`` are the pairs of ``out_rows`` ``(rows, c)`` (pair
+    ``p`` is token ``p // k``, choice ``p % k`` of ``weights`` ``(t, k)``).
+    From the sorted side both ways: the rows, weighted in float32, are added
+    into the tokens' float32 sums by token id, and rounded once; the backward
+    pass gathers the tokens' gradient rows back, ``rows`` of them. A row that
+    no held expert computes must arrive zeroed and under a zero weight. (The
+    other form, a gather of all ``t * k`` places from the token side, read
+    1.65 ms a step more in ``lfm2moe-train-8k``: PERF.md, PR 31.)"""
+    return _combine_fwd(out_rows, weights, head)[0]
 
 
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], (inverse,)
+def _combine_fwd(out_rows, weights, head):
+    t, top_k = weights.shape
+    w = weights.reshape(-1)[head]
+    weighted = out_rows.astype(jnp.float32) * w[:, None]
+    out = jnp.zeros((t, out_rows.shape[-1]), jnp.float32).at[head // top_k].add(weighted)
+    return out.astype(out_rows.dtype), (out_rows, w, head, weights)
 
 
-def _permute_rows_bwd(res, g):
-    return g[res[0]], None, None
+def _combine_bwd(res, g):
+    out_rows, w, head, weights = res
+    g_rows = g[head // weights.shape[1]].astype(jnp.float32)
+    d_rows = (g_rows * w[:, None]).astype(out_rows.dtype)
+    d_w = (g_rows * out_rows.astype(jnp.float32)).sum(axis=-1)
+    d_weights = jnp.zeros((weights.size,), jnp.float32).at[head].set(d_w)
+    return d_rows, d_weights.reshape(weights.shape).astype(weights.dtype), None
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def route(tokens, router, bias, top_k: int, normalise: bool, scaling: float):
@@ -148,20 +188,20 @@ def route(tokens, router, bias, top_k: int, normalise: bool, scaling: float):
     return indices, weights * scaling
 
 
-def held_experts_output(
-    tokens, indices, weights, gate, up, down, expert_offset: int
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The held experts' part of the layer's output for ``tokens`` ``(t, c)``
-    and the pairs each held expert was given ``(held,)``.
+def expected_rows(tokens: int, top_k: int, num_experts: int, router_width: int) -> int:
+    """The sorted rows an expert layer runs on while its held pairs fit them:
+    ``_ROWS_OVER_UNIFORM`` times the held experts' uniform share of ``tokens
+    * top_k`` pairs, in whole tiles of the grouped product."""
+    uniform = tokens * top_k * num_experts / router_width
+    return _ROW_TILE * math.ceil(_ROWS_OVER_UNIFORM * uniform / _ROW_TILE)
 
-    The ``t * top_k`` token-expert pairs are sorted by held expert, the pairs
-    of experts not held last; each projection is one grouped product over the
-    held experts' rows. The buffers hold every pair, so none is dropped
-    whatever the routing. What a grouped product leaves in the rows past the
-    held pairs is never read: the output's are selected away before the
-    combine, and their gradient is dropped where the rows were gathered."""
-    t, top_k = indices.shape
-    held = gate.shape[0]
+
+def sort_pairs(indices, weights, expert_offset: int, held: int):
+    """``(order, inverse, group_sizes, weights)`` of the ``t * top_k``
+    token-expert pairs: ``order`` sorts them by held expert, the pairs of
+    experts not held last (stable: within an expert by token); ``inverse`` is
+    each pair's place in that order; ``group_sizes`` ``(held,)`` the pairs of
+    each held expert; ``weights`` with those of the other pairs zeroed."""
     with jax.named_scope("dispatch"):
         local = indices - expert_offset
         here = (local >= 0) & (local < held)
@@ -169,17 +209,103 @@ def held_experts_output(
         order = jnp.argsort(key, stable=True)
         inverse = jnp.argsort(order)
         group_sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(axis=0, dtype=jnp.int32)
-        valid = jnp.arange(t * top_k) < group_sizes.sum()
-        rows = _take_tokens(tokens, order, inverse, valid)
         weights = jnp.where(here, weights, 0.0)
+    return order, inverse, group_sizes, weights
+
+
+def sorted_rows_output(tokens, weights, gate, up, down, order, inverse, group_sizes, rows: int):
+    """The held experts' part of the output for ``tokens`` ``(t, c)``, from
+    the first ``rows`` pairs of :func:`sort_pairs`' order. ``rows`` is static
+    and must hold every held pair (``group_sizes.sum() <= rows``), which sort
+    first; ``order.shape[0]`` always does.
+
+    The ``rows`` token rows are gathered, each projection is one grouped
+    product over the held experts' rows, and the rows go back to their tokens
+    under their weights. What a grouped product leaves in the rows past the
+    held pairs is never read: the output's are selected away before the
+    combine, and their gradient is dropped where the rows were gathered."""
+    with jax.named_scope("dispatch"):
+        head = order[:rows]
+        valid = jnp.arange(rows) < group_sizes.sum()
+        x = _take_tokens(tokens, head // weights.shape[1], valid, inverse)
     with jax.named_scope("experts"):
-        hidden = nn.silu(grouped_matmul(rows, gate, group_sizes)) * grouped_matmul(rows, up, group_sizes)
+        hidden = nn.silu(grouped_matmul(x, gate, group_sizes)) * grouped_matmul(x, up, group_sizes)
         out_rows = grouped_matmul(hidden, down, group_sizes)
     with jax.named_scope("combine"):
         out_rows = jnp.where(valid[:, None], out_rows, jnp.zeros((), out_rows.dtype))
-        by_token = _permute_rows(out_rows, inverse, order).reshape(t, top_k, -1)
-        out = (by_token.astype(jnp.float32) * weights[..., None]).sum(axis=1)
-    return out.astype(tokens.dtype), group_sizes
+        return _combine(out_rows, weights, head)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bounded_or_full(rows, fits, tokens, weights, gate, up, down, order, inverse, group_sizes):
+    """:func:`sorted_rows_output` on ``rows`` rows if ``fits`` (they hold the
+    held pairs), else on every pair's: the same code at two sizes, chosen by
+    ``lax.cond`` on a count of this call's own routing. Forward and backward
+    are each a ``cond`` on ``fits``, and the backward's branches recompute
+    from the operands: reverse mode through one ``cond`` would have each
+    branch write the other's residuals as zeros, worst-case-sized ones on the
+    bounded branch. (Inside a rematerialised layer the forward's ``cond``
+    leaves the recomputation as dead code, its residuals being its operands:
+    three passes over the rows a step, as without the ``cond``.) The ``cond``s
+    stand outside the phase scopes, the scopes inside the branches."""
+    return jax.lax.cond(
+        fits,
+        functools.partial(sorted_rows_output, rows=rows),
+        functools.partial(sorted_rows_output, rows=order.shape[0]),
+        tokens, weights, gate, up, down, order, inverse, group_sizes,
+    )
+
+
+def _bounded_or_full_fwd(rows, fits, *operands):
+    return _bounded_or_full(rows, fits, *operands), (fits, operands)
+
+
+def _bounded_or_full_bwd(rows, res, g):
+    fits, (*inputs, order, inverse, group_sizes) = res
+
+    def pull_back(on_rows):
+        def branch(g, *inputs):
+            run = functools.partial(
+                sorted_rows_output, order=order, inverse=inverse, group_sizes=group_sizes, rows=on_rows)
+            return jax.vjp(run, *inputs)[1](g)
+        return branch
+
+    grads = jax.lax.cond(fits, pull_back(rows), pull_back(order.shape[0]), g, *inputs)
+    return (None, *grads, None, None, None)
+
+
+_bounded_or_full.defvjp(_bounded_or_full_fwd, _bounded_or_full_bwd)
+
+
+def held_experts_output(
+    tokens, indices, weights, gate, up, down, expert_offset: int, rows: Optional[int] = None
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the layer's output for ``tokens`` ``(t, c)``
+    and the pairs each held expert was given ``(held,)``, computed on the
+    first ``rows`` sorted pairs (:func:`sorted_rows_output`): every pair,
+    ``t * top_k``, unless told a smaller number that holds the held pairs.
+    On every pair none is dropped whatever the routing."""
+    order, inverse, group_sizes, weights = sort_pairs(indices, weights, expert_offset, gate.shape[0])
+    out = sorted_rows_output(
+        tokens, weights, gate, up, down, order, inverse, group_sizes, rows=rows or order.shape[0])
+    return out, group_sizes
+
+
+def bounded_experts_output(
+    tokens, indices, weights, gate, up, down, expert_offset: int, rows_expected: int
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """:func:`held_experts_output` on ``rows_expected`` rows when this call's
+    held pairs fit them and on every pair when they do not
+    (:func:`_bounded_or_full`): no pair is dropped either way. Also returns
+    1.0 if they fitted, else 0.0. With ``rows_expected`` no smaller than
+    ``t * top_k`` there is nothing to choose: one path, and 1.0."""
+    order, inverse, group_sizes, weights = sort_pairs(indices, weights, expert_offset, gate.shape[0])
+    operands = (tokens, weights, gate, up, down, order, inverse, group_sizes)
+    if rows_expected >= order.shape[0]:
+        out = sorted_rows_output(*operands, rows=order.shape[0])
+        return out, group_sizes, jnp.ones((), jnp.float32)
+    fits = group_sizes.sum() <= rows_expected
+    return _bounded_or_full(rows_expected, fits, *operands), group_sizes, fits.astype(jnp.float32)
 
 
 class SparseExperts(nn.Module):
@@ -193,10 +319,15 @@ class SparseExperts(nn.Module):
     sum of *their* outputs for the tokens routed to them, nothing for the
     others: one chip's part of an expert-parallel layer, without its
     exchange. With ``num_experts == router_width`` it is the whole layer. No
-    pair is ever dropped (:func:`held_experts_output`).
+    pair is ever dropped: the held pairs sort first, and dispatch, experts
+    and combine run on :func:`expected_rows` sorted rows while the held pairs
+    fit them and on every pair's when they do not
+    (:func:`bounded_experts_output`: the same code at two sizes, chosen each
+    call from the routing it finds; no option).
 
     Returns ``(output, stats)``; ``stats`` is ``[pairs computed here, fullest
-    held expert over the mean held expert]`` of this call, float32.
+    held expert over the mean held expert, 1.0 if the held pairs fitted the
+    row bound]`` of this call, float32.
 
     Under a mesh with more than one device the tokens' part runs inside
     ``jax.shard_map`` over the batch axes, every shard sorting its own
@@ -241,12 +372,13 @@ class SparseExperts(nn.Module):
                     tokens, router, bias, self.top_k, self.norm_topk_prob,
                     self.routed_scaling_factor,
                 )
-            out, sizes = held_experts_output(
-                tokens, indices, weights, gate, up, down, self.expert_offset)
-            if axes:
-                sizes = jax.lax.psum(sizes, axes)
+            out, sizes, fitted = bounded_experts_output(
+                tokens, indices, weights, gate, up, down, self.expert_offset,
+                expected_rows(tokens.shape[0], self.top_k, e, self.router_width))
+            if axes:  # every shard chose for its own tokens: bounded if all were
+                sizes, fitted = jax.lax.psum(sizes, axes), jax.lax.pmin(fitted, axes)
             sizes = sizes.astype(jnp.float32)
-            stats = jnp.stack([sizes.sum(), sizes.max() / jnp.maximum(sizes.mean(), 1.0)])
+            stats = jnp.stack([sizes.sum(), sizes.max() / jnp.maximum(sizes.mean(), 1.0), fitted])
             return out.reshape(x.shape), stats
 
         args = (u.astype(self.dtype), router, bias, gate, up, down)

@@ -76,8 +76,9 @@ class DecoderLMConfig:
 
 
 class DecoderLayer(nn.Module):
-    """One layer; returns ``(h, stats)`` with the expert layer's
-    ``[pairs computed, load max over mean]`` (zeros in a dense layer)."""
+    """One layer; returns ``(h, stats)`` with the expert layer's ``[pairs
+    computed, load max over mean, ran on the row bound]`` (zeros in a dense
+    layer)."""
 
     config: DecoderLMConfig
     layer_type: str
@@ -107,7 +108,7 @@ class DecoderLayer(nn.Module):
         if self.dense:
             out = GatedMLP(
                 cfg.num_channels, cfg.mlp_channels, cfg.init_scale, self.dtype, name="mlp")(u)
-            stats = jnp.zeros((2,), jnp.float32)
+            stats = jnp.zeros((3,), jnp.float32)
         else:
             out, stats = SparseExperts(
                 num_channels=cfg.num_channels, hidden_channels=cfg.expert_channels,
@@ -122,9 +123,10 @@ class DecoderLayer(nn.Module):
 
 class DecoderLM(nn.Module):
     """``(b, n)`` token ids -> ``(b, n, vocab_size)`` logits, and with
-    ``return_stats`` also ``{"moe_assignments_held", "moe_expert_load_max_over_mean"}``:
-    token-expert pairs computed by the held experts, summed over the expert
-    layers, and the worst layer's fullest held expert over its mean."""
+    ``return_stats`` also ``{"moe_assignments_held", "moe_expert_load_max_over_mean",
+    "moe_layers_bounded"}``: token-expert pairs computed by the held experts,
+    summed over the expert layers; the worst layer's fullest held expert over
+    its mean; and the expert layers whose held pairs fitted the row bound."""
 
     config: DecoderLMConfig
     dtype: Any = jnp.float32
@@ -169,4 +171,5 @@ class DecoderLM(nn.Module):
         return logits, {
             "moe_assignments_held": stats[:, 0].sum(),
             "moe_expert_load_max_over_mean": stats[:, 1].max(),
+            "moe_layers_bounded": stats[:, 2].sum(),
         }

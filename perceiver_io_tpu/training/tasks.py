@@ -64,8 +64,9 @@ def clm_loss_fn(model, max_latents: int) -> Callable:
 def lm_loss_fn(model) -> Callable:
     """Decoder-only LM step (``models/text/lm.py``): next-token CE over every
     position, pad labels ignored. With expert layers the step's metrics carry
-    ``moe_assignments_held`` and ``moe_expert_load_max_over_mean``, which the
-    trainer logs and sets as gauges ``trainer_moe_*`` (docs/observability.md)."""
+    ``moe_assignments_held``, ``moe_expert_load_max_over_mean`` and
+    ``moe_layers_bounded``, which the trainer logs and sets as gauges
+    ``trainer_moe_*`` (docs/observability.md)."""
 
     def loss_fn(params, batch, rng):
         labels = batch["labels"]

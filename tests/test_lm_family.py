@@ -20,6 +20,7 @@ if ROOT not in sys.path:
 from benchmarks.adapters import lm as adapter  # noqa: E402
 from benchmarks.reference import blocks  # noqa: E402
 from benchmarks.reference import lfm2_moe as ref  # noqa: E402
+from perceiver_io_tpu.models.core import hybrid  # noqa: E402
 from perceiver_io_tpu.models.core.hybrid import ShortConv, SparseExperts, causal_depthwise_conv  # noqa: E402
 from perceiver_io_tpu.models.text.lm import DecoderLM, DecoderLMConfig  # noqa: E402
 from perceiver_io_tpu.ops.attention import dot_product_attention  # noqa: E402
@@ -81,6 +82,7 @@ def test_logits_loss_and_every_gradient_match_the_reference_in_float32():
     # two expert layers, every expert held: every pair is computed
     assert float(stats["moe_assignments_held"]) == 2 * 2 * 128 * 2
     assert float(stats["moe_expert_load_max_over_mean"]) >= 1.0
+    assert float(stats["moe_layers_bounded"]) == 2.0  # a whole layer's bound is its worst case
 
 
 def test_bfloat16_program_stays_near_the_float32_reference():
@@ -142,18 +144,28 @@ def _cut(p, offset, count):
             "moe.down": p["moe.down"][take]}
 
 
+@pytest.mark.parametrize("row_tile", [512, 32], ids=["one_path", "cond"])
 @pytest.mark.parametrize("held_offset,expect_pairs", [(3, 192), (5, 0)])
-def test_no_pair_is_dropped_when_every_token_chooses_one_held_expert(held_offset, expect_pairs):
+def test_no_pair_is_dropped_when_every_token_chooses_one_held_expert(
+        monkeypatch, held_offset, expect_pairs, row_tile):
     """A bias that sends every token to expert 3 (and, second, to 0): the one
     held expert gets every token, or none; both match the reference, values
-    and gradients."""
+    and gradients. 384 pairs, one expert of 8 held: with a row tile of 32
+    the layer's bound is 96 rows, so it chooses by ``lax.cond``; 192 held
+    pairs are over it, the worst-case branch runs and reports 0; none is
+    under it. With the tile of 512 the bound is the worst case: one path."""
+    monkeypatch.setattr(hybrid, "_ROW_TILE", row_tile)
+    chooses = hybrid.expected_rows(192, 2, 1, 8) < 384
+    assert chooses == (row_tile == 32)
     p = _expert_layer_params(jax.random.PRNGKey(4))
     p["moe.bias"] = jnp.zeros(8).at[3].set(100.0).at[0].set(50.0)
     u = jax.random.normal(jax.random.PRNGKey(5), (2, 96, 64))
     cfg = {**SMALL, "num_experts": 1, "expert_offset": held_offset}
     layer, held = _layer(held_offset, 1), _held(p, held_offset, 1)
+    assert ("cond" in str(jax.make_jaxpr(lambda q, x: layer.apply({"params": q}, x))(held, u))) == chooses
     out, stats = layer.apply({"params": held}, u)
     assert float(stats[0]) == expect_pairs
+    assert float(stats[2]) == (0.0 if chooses and expect_pairs > 96 else 1.0)
     np.testing.assert_allclose(out, ref.experts(u, _cut(p, held_offset, 1), "moe", cfg), atol=1e-5, rtol=1e-5)
     g_prog = jax.grad(lambda q, x: (layer.apply({"params": q}, x)[0] ** 2).sum(), argnums=(0, 1))(held, u)
     g_ref = jax.grad(lambda q, x: (ref.experts(x, q, "moe", cfg) ** 2).sum(), argnums=(0, 1))(
@@ -163,13 +175,62 @@ def test_no_pair_is_dropped_when_every_token_chooses_one_held_expert(held_offset
         np.testing.assert_allclose(g_prog[0][ours], g_ref[0][theirs], atol=1e-4, rtol=1e-4, err_msg=ours)
 
 
-def test_rows_a_grouped_product_leaves_unwritten_are_never_read(monkeypatch):
+def _routed(key, t=192, c=64, f=48, width=8, held=3, top_k=2):
+    """Tokens, their routing over ``width`` experts and the weights of the
+    ``held`` experts from 2 on, for :func:`hybrid.held_experts_output`."""
+    ks = jax.random.split(key, 5)
+    tokens = jax.random.normal(ks[0], (t, c))
+    router = 0.3 * jax.random.normal(ks[1], (c, width))
+    gate, up = (0.3 * jax.random.normal(k, (held, c, f)) for k in ks[2:4])
+    down = 0.3 * jax.random.normal(ks[4], (held, f, c))
+    return tokens, router, gate, up, down, top_k
+
+
+def _loss_on_rows(rows):
+    def loss(tokens, router, gate, up, down):
+        indices, weights = hybrid.route(tokens, router, None, 2, True, 1.0)
+        out, sizes = hybrid.held_experts_output(tokens, indices, weights, gate, up, down, 2, rows)
+        return (out ** 2).sum(), (out, sizes)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+
+
+@pytest.mark.parametrize("rows", ["exactly_held", "held_rounded_up", "twice_held", "every_pair"])
+def test_any_row_count_that_holds_the_held_pairs_gives_the_worst_case_result(rows):
+    """One implementation for every ``rows``: on exactly the held pairs (no
+    row to spare: a pair past ``rows`` must not read the last held row), on
+    them rounded up to 8, on twice that and on all ``t * top_k`` the output
+    and the gradients (tokens, the router through the weights, gate, up,
+    down) are the worst-case buffer's."""
+    *args, top_k = _routed(jax.random.PRNGKey(11))
+    (_, (want_out, sizes)), want = _loss_on_rows(None)(*args)
+    held = int(sizes.sum())
+    every = args[0].shape[0] * top_k
+    assert 0 < held < every // 2
+    rows = {"exactly_held": held, "held_rounded_up": -(-held // 8) * 8, "twice_held": 2 * held,
+            "every_pair": every}[rows]
+    (_, (out, got_sizes)), got = _loss_on_rows(rows)(*args)
+    np.testing.assert_array_equal(got_sizes, sizes)
+    np.testing.assert_allclose(out, want_out, atol=1e-5, rtol=1e-5)
+    for a, b, name in zip(got, want, ("tokens", "router", "gate", "up", "down")):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+def test_the_row_bound_is_twice_the_uniform_share_in_whole_tiles():
+    assert hybrid.expected_rows(16384, 4, 8, 64) == 16384  # the cell: a quarter of 65,536
+    assert hybrid.expected_rows(16384, 4, 64, 64) >= 65536  # a whole layer: its worst case, no cond
+    assert hybrid.expected_rows(1000, 4, 3, 64) == 512 and hybrid.expected_rows(3000, 4, 3, 64) == 1536
+
+
+@pytest.mark.parametrize("row_tile", [512, 32], ids=["one_path", "bounded_rows"])
+def test_rows_a_grouped_product_leaves_unwritten_are_never_read(monkeypatch, row_tile):
     """The TPU's kernel for ``ragged_dot`` writes only the rows of its groups;
     what lies past them is whatever the memory held (the first chip run of
     PR 28 read gradients of 1e5 and NaN from there). Poison those rows,
-    forward and backward, and the layer's output and gradients stay put."""
-    from perceiver_io_tpu.models.core import hybrid
-
+    forward and backward, and the layer's output and gradients stay put: on
+    the worst-case buffer (384 rows), and with a row tile of 32 on the
+    bounded one (288 rows, twice the 3 held experts' uniform share)."""
+    monkeypatch.setattr(hybrid, "_ROW_TILE", row_tile)
     clean = hybrid.grouped_matmul
 
     @jax.custom_vjp
@@ -194,7 +255,9 @@ def test_rows_a_grouped_product_leaves_unwritten_are_never_read(monkeypatch):
     want = jax.value_and_grad(loss, argnums=(0, 1))(held, u)
     monkeypatch.setattr(hybrid, "grouped_matmul", poisoned)
     got = jax.value_and_grad(loss, argnums=(0, 1))(held, u)
-    assert float(layer.apply({"params": held}, u)[1][0]) < 2 * 96 * 2  # some pairs are not held
+    stats = layer.apply({"params": held}, u)[1]
+    assert float(stats[0]) < min(384, hybrid.expected_rows(192, 2, 3, 8))  # rows past the held pairs, in either buffer
+    assert float(stats[2]) == 1.0
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(got[1]), jax.tree_util.tree_leaves(want[1])):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
@@ -250,12 +313,24 @@ def test_grouped_heads_match_repeated_key_value_heads(impl, causal):
         np.testing.assert_allclose(got, want, atol=5e-5)
 
 
-def test_recomputation_by_layer_gives_the_same_loss_and_gradients():
+@pytest.mark.parametrize("held", [8, 2], ids=["whole_layers", "cond_inside_the_layer"])
+def test_recomputation_by_layer_gives_the_same_loss_and_gradients(monkeypatch, held):
+    """With 2 of the 8 experts held and a row tile of 32 the expert layers
+    choose their rows by ``lax.cond`` (256 of 512), inside the rematerialised
+    layer."""
     batch = _batch()
     tree = adapter.common.seeded_tree(ref, SMALL, adapter.path_of, SEED)
-    plain = jax.value_and_grad(lambda p: lm_loss_fn(_program())(p, batch, None)[0])(tree)
-    remat = jax.value_and_grad(
-        lambda p: lm_loss_fn(_program(activation_checkpointing=True))(p, batch, None)[0])(tree)
+    model = {}
+    if held < 8:
+        monkeypatch.setattr(hybrid, "_ROW_TILE", 32)
+        model = {"num_experts": held, "expert_offset": 2}
+        stacked = lambda path: path[-2].key == "moe" and path[-1].key in ("gate", "up", "down")
+        tree = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: leaf[2:2 + held] if stacked(path) else leaf, tree)
+    loss = lambda **kw: (lambda p: lm_loss_fn(_program(**model, **kw))(p, batch, None)[0])
+    assert ("cond" in str(jax.make_jaxpr(loss(activation_checkpointing=True))(tree))) == (held < 8)
+    plain = jax.value_and_grad(loss())(tree)
+    remat = jax.value_and_grad(loss(activation_checkpointing=True))(tree)
     assert float(plain[0]) == float(remat[0])
     for a, b in zip(jax.tree_util.tree_leaves(plain[1]), jax.tree_util.tree_leaves(remat[1])):
         np.testing.assert_allclose(a, b, atol=1e-6)
@@ -304,8 +379,25 @@ def test_two_step_fit_through_the_cli_with_and_without_recomputation(tmp_path):
         # 8 rows of 64 tokens, 2 a token, two expert layers, every expert held
         assert gauges["trainer_moe_assignments_held"] == 2 * 8 * 64 * 2
         assert gauges["trainer_moe_expert_load_max_over_mean"] >= 1.0
+        assert gauges["trainer_moe_layers_bounded"] == 2.0
     with open(os.path.join(tmp_path, "logs_true", "metrics.jsonl")) as f:
         assert "train/moe_assignments_held" in f.read()
+
+
+def test_bounded_layers_gauge_has_help_text_and_a_benchmark_reader(monkeypatch):
+    """``benchmarks/metrics/moe_bounded_layers.py``: nothing from a program
+    that never set the gauge (the parent), then the gauge's value."""
+    import perceiver_io_tpu.observability as observability
+    from benchmarks import harness
+    from perceiver_io_tpu.observability.exporters import HELP_TEXT
+
+    registry = observability.MetricsRegistry()
+    monkeypatch.setattr(observability, "default_registry", lambda: registry)
+    read = harness.load_reader(os.path.join(ROOT, "benchmarks"), "moe_bounded_layers")
+    assert "trainer_moe_layers_bounded" in HELP_TEXT
+    assert read({}) is None
+    registry.set_gauge("trainer_moe_layers_bounded", 4.0)
+    assert read({}) == 4.0
 
 
 def test_serve_refuses_the_family_loudly(tmp_path):

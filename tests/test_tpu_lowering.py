@@ -10,6 +10,7 @@ before the chip bring-up: a pad-mask BlockSpec that broke the (8, 128) tiling
 rule at batch > 1, and a Mosaic kernel under a multi-device mesh without
 ``shard_map``. Numerical parity of the compiled kernels is ``chip_smoke.py``'s.
 """
+import math
 import re
 
 import jax
@@ -355,3 +356,48 @@ def test_expert_layer_lowers_for_tpu_without_a_dispatch_array_over_all_experts()
         assert size <= max(tokens * k * c, held * c * f), shape
     # the router's scores are the only array over the router's width
     assert (tokens, width) in _tensor_shapes(text)
+
+
+def test_expert_layer_at_the_cells_shapes_lowers_with_a_bounded_and_a_worst_case_branch():
+    """``lfm2moe-train-8k``'s expert layer (16,384 tokens, 4 of 64 experts a
+    token, 8 held, 2048 x 1536), forward and backward from shapes alone: the
+    layer chooses its rows by ``lax.cond`` (one in the forward, one in the
+    backward, whose branches take the operands and keep no residuals for
+    each other), and both branches lower for the TPU. On the bounded rows
+    (16,384) nothing has 65,536 rows but vectors (the permutation, its
+    inverse, the pairs' weights and their gradient) and one array: the
+    dispatch's backward gathers every pair's place from the 16,384 gradient
+    rows, ``(4, 16384, 2048)``, which the chip ran faster than a scatter-add
+    (PERF.md, PR 31). On every pair's rows the buffers are ``(65536, ...)``."""
+    from perceiver_io_tpu.models.core import hybrid
+
+    b, n, c, f, width, held, k = 2, 8192, 2048, 1536, 64, 8, 4
+    pairs, rows = b * n * k, hybrid.expected_rows(b * n, k, held, width)
+    assert (pairs, rows) == (65536, 16384)
+    layer = hybrid.SparseExperts(num_channels=c, hidden_channels=f, router_width=width,
+                                 num_experts=held, top_k=k, dtype=jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((b, n, c), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), u)
+    loss = lambda p, u: jnp.sum(layer.apply(p, u)[0].astype(jnp.float32) ** 2)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, u).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("stablehlo.case") == 2
+    assert {(pairs, c), (rows, c), (pairs, f), (rows, f)} <= _tensor_shapes(text)
+
+    def forward_and_backward_on(rows):
+        def run(tokens, weights, gate, up, down, order, inverse, sizes, g):
+            out, pull = jax.vjp(
+                lambda *a: hybrid.sorted_rows_output(*a, order, inverse, sizes, rows=rows),
+                tokens, weights, gate, up, down)
+            return out, pull(g)
+        shaped = jax.ShapeDtypeStruct
+        args = (shaped((b * n, c), jnp.bfloat16), shaped((b * n, k), jnp.float32),
+                *(shaped(s, jnp.float32) for s in ((held, c, f), (held, c, f), (held, f, c))),
+                shaped((pairs,), jnp.int32), shaped((pairs,), jnp.int32), shaped((held,), jnp.int32),
+                shaped((b * n, c), jnp.bfloat16))
+        shapes = _tensor_shapes(jax.jit(run).trace(*args).lower(lowering_platforms=("tpu",)).as_text())
+        # of every pair's size, vectors and index columns aside
+        return {s for s in shapes if math.prod(s) >= pairs * min(c, f) and math.prod(s) % pairs == 0}
+
+    assert forward_and_backward_on(rows) == {(k, b * n, c)}
+    assert {(pairs, c), (pairs, f)} <= forward_and_backward_on(pairs)
