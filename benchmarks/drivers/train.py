@@ -38,9 +38,9 @@ class Stream:
     ``k`` (from 1) feeds step ``k``; when it is asked for, steps ``< k``
     have been dispatched and ``trainer.state`` is the state after them."""
 
-    def __init__(self, run, trainer, batches, leaves, start_copy, b1):
+    def __init__(self, run, trainer, batches, leaves, seeded_tree, handed, b1):
         self.run, self.trainer, self.batches = run, trainer, batches
-        self.leaves, self.start_copy, self.b1 = leaves, start_copy, b1
+        self.leaves, self.seeded_tree, self.handed, self.b1 = leaves, seeded_tree, handed, b1
         self.k = 0
         self.check_batches, self.losses = [], []
         self.grad_norms = self.delta_norms = None
@@ -75,6 +75,12 @@ class Stream:
         k, cfg = self.k, self.trainer.config
         if k <= self.first_window_step:
             self.run.stamp(f"batch_{k}_asked")
+        if k == 1:
+            # the state exists and holds a copy of its own of the tree ``fit``
+            # was handed: from here on the state's parameters are the only ones
+            for leaf in jax.tree_util.tree_leaves(self.handed):
+                leaf.delete()
+            self.handed = None
         if k <= CHECK_STEPS + 1:
             # the trainer logs a mean loss per cadence; one step per flush
             # while the checked steps run gives each step's own loss
@@ -87,10 +93,13 @@ class Stream:
                 self.grad_norms = jax.jit(
                     lambda t: _norms({n: v * scale for n, v in t.items()}))(mu)
             if k == CHECK_STEPS + 1:
+                # the start is made again from the seed, for this one
+                # reading: step 3's loss has been fetched, so no step is in
+                # flight, and the device holds the state and this tree
                 now = self.leaves(self.trainer.state.params)
+                start = self.leaves(self.seeded_tree())
                 self.delta_norms = jax.jit(
-                    lambda a, b: _norms({n: a[n] - b[n] for n in a}))(now, self.start_copy)
-                self.start_copy = None
+                    lambda a, b: _norms({n: a[n] - b[n] for n in a}))(now, start)
                 cfg.log_every_n_steps = self.cadence
             batch = self.batches.next_batch()
             if k <= CHECK_STEPS:
@@ -143,7 +152,6 @@ def reference_readings(ref, config, optimizer, trainer_seed, seed, check_batches
     gradient's norm by leaf, and the norm of each leaf's change after them."""
     with blocks.precision(precision):
         params = ref_training.seeded_params(ref, config, seed)
-        start = params
         block = ref_training.make_block(ref, config)
         losses, grad_norms, state = [], None, None
         for i, batch in enumerate(check_batches, start=1):
@@ -153,6 +161,8 @@ def reference_readings(ref, config, optimizer, trainer_seed, seed, check_batches
             if i == 1:
                 grad_norms = ref_training.leaf_norms(grads)
             params, state = ref_training.adamw_step(optimizer, params, grads, state)
+        del state  # the update was made in place: the start is made again
+        start = ref_training.seeded_params(ref, config, seed)
         delta = ref_training.leaf_norms({k: params[k] - start[k] for k in params})
     return {"losses": losses, "grad_norms": grad_norms, "delta_norms": delta}
 
@@ -206,14 +216,17 @@ def run(run):
         trainer, optimizer = adapter.build_fit(config, mix["fit"], run.work_dir("fit"))
         names = sorted(ref.param_shapes(config))
         leaves = lambda tree: adapter.common.leaves_by_name(tree, names, adapter.path_of)
-        params = adapter.common.seeded_tree(ref, config, adapter.path_of, run.seed)
-        start_copy = leaves(adapter.common.seeded_tree(ref, config, adapter.path_of, run.seed))
+        seeded_tree = lambda: adapter.common.seeded_tree(ref, config, adapter.path_of, run.seed)
+        params = seeded_tree()
         run.stamp("trainer_and_weights")
         batches = TrainBatches(mix["feed"], run.seed)
     run.stamp("corpus")
-    stream = Stream(run, trainer, batches, leaves, start_copy, optimizer["b1"])
-    del start_copy
+    stream = Stream(run, trainer, batches, leaves, seeded_tree, params, optimizer["b1"])
     try:
+        # ``fit`` copies this tree into its state; the stream deletes it as
+        # the first batch is asked for (an init function handed to ``fit``
+        # would make the seed a constant of the state's program: a compile a
+        # seed)
         trainer.fit(lambda: params, stream, val_data=None, initial_params=params)
         raise RuntimeError("fit returned before the window closed: max_steps too low")
     except _WindowClosed:

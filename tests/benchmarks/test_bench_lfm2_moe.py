@@ -14,25 +14,11 @@ from benchmarks.adapters import lm as adapter
 from benchmarks.reference import lfm2_moe as ref
 from benchmarks.rooflines import grouped, work
 from benchmarks.rooflines import lfm2_moe as lfm2_work
-from conftest import ROOT, build_toy_root
+from conftest import ROOT, build_toy_lfm2_root
+from conftest import TOY_LFM2 as TOY
+from conftest import TOY_LFM2_LIMITS as TOY_LIMITS
 
 FILES = os.path.join(ROOT, "benchmarks")
-TOY = {
-    "name": "toy-lfm2", "reference": "lfm2_moe", "program": "lm",
-    "hidden_size": 64, "intermediate_size": 96, "moe_intermediate_size": 48,
-    "num_attention_heads": 2, "num_key_value_heads": 1, "conv_L_cache": 3, "norm_eps": 1e-5,
-    "norm_topk_prob": True, "num_experts": 4, "router_width": 8, "expert_offset": 2,
-    "num_experts_per_tok": 2, "routed_scaling_factor": 1, "use_expert_bias": True,
-    "vocab_size": 262, "rope_parameters": {"rope_theta": 1000000}, "max_position_embeddings": 512,
-    "layer_types": ["conv", "conv", "full_attention", "conv"], "first_layer": 1, "num_layers": 3,
-    "num_dense_layers": 1,
-}
-#: the toy cell's limits, set as the real ones are: three times what sound
-#: runs of the toy program (bfloat16) read on the CPU over three seeds (loss
-#: 9.4e-6, first gradient 0.0017, change 0.0015) and below the float8 control
-#: and the left-out expert (the next test); the experts held are 2..5 of 8,
-#: so a layout that mistook the offset would read of the order of 1
-TOY_LIMITS = {"loss1": 3e-5, "loss2": 3e-5, "loss3": 3e-5, "grad_leaf": 0.005, "delta_leaf": 0.005}
 
 
 def test_adapter_lays_every_leaf_out_and_reads_it_back():
@@ -59,31 +45,7 @@ def test_adapter_lays_every_leaf_out_and_reads_it_back():
 
 @pytest.fixture
 def toy_lfm2_root(tmp_path):
-    root, files = build_toy_root(tmp_path)
-    with open(os.path.join(root, "cfg", "toy-lfm2.json"), "w") as f:
-        json.dump(TOY, f)
-    mix = {"driver": "train", "feed": {"task": "clm", "batch": 8, "seq_len": 128, "corpus_tokens": 20000},
-           "fit": {"trainer": {"max_steps": 100000, "enable_tensorboard": False},
-                   "model": {"activation_checkpointing": True}},
-           "warmup_steps": 1, "trace_steps": 2, "reference_rows": 2,
-           "trace": {"step_module": "jit_step"}, "limits": TOY_LIMITS}
-    with open(os.path.join(files, "traffic", "mixes", "toy-fit-lfm2.json"), "w") as f:
-        json.dump(mix, f)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    bench["configs"].append({"name": "toy-lfm2", "source": "toy", "reduced": [],
-                             "file": "cfg/toy-lfm2.json", "why": "toy"})
-    bench["workloads"].append({"name": "toy-lfm2-train", "config": "toy-lfm2",
-                               "traffic": "toy-fit-lfm2", "chips": 1, "why": "toy"})
-    bench["end_to_end"][0]["workloads"].append("toy-lfm2-train")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        real = {m["name"]: m for m in json.load(f)["per_layer"]}
-    for name in ("expert_load_max_over_mean", "expert_matmul_device_ms", "expert_matmul_roofline",
-                 "moe_routing_device_ms", "short_conv_device_ms"):
-        bench["per_layer"].append({**real[name], "workloads": ["toy-lfm2-train"]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
-    return root, files
+    return build_toy_lfm2_root(tmp_path)
 
 
 @pytest.mark.parametrize("trace", [False, True], ids=["trace0", "trace1"])
@@ -254,3 +216,61 @@ def test_expert_roofline_and_load_read_the_programs_gauges(monkeypatch):
     assert roofline(ctx) == pytest.approx(100.0 * least / 0.12875)
     assert 0 < roofline(ctx) < 100 and load(ctx) == 1.25
 
+
+
+@pytest.mark.parametrize("reference,layers", [("lfm2_moe", 4), ("fake_moe", 5)])
+def test_expert_roofline_asks_the_configurations_own_roofline_module(reference, layers, monkeypatch):
+    """How many expert layers share the held pairs is
+    ``rooflines/<reference>.py::expert_layers``'s to say: a second
+    architecture (here one that counts a prediction module's expert layer
+    beside its four) joins the metric with a file of its own."""
+    import sys
+    import types
+
+    fake = types.ModuleType("benchmarks.rooflines.fake_moe")
+    fake.expert_layers = lambda config: lfm2_work.expert_layers(config) + 1
+    monkeypatch.setitem(sys.modules, "benchmarks.rooflines.fake_moe", fake)
+    with open(os.path.join(ROOT, "benchmarks", "configs", "lfm2-24b-a2b-ep8.json")) as f:
+        config = {**json.load(f), "reference": reference}
+    peak = {"flops_per_s_bf16": 197e12, "bytes_per_s": 819e9}
+    monkeypatch.setattr(scopes, "tables", lambda cell: (TABLE, FUSED))
+    monkeypatch.setattr(phases, "program_gauge", lambda name: 65536.0)
+    read = harness.load_reader(FILES, "expert_matmul_roofline")
+    least = layers * grouped.grouped_least_time(
+        grouped.expert_products(config, 65536.0 / layers), 8, peak)
+    assert read(_ctx(_trace(), peak=peak, config=config)) == pytest.approx(100.0 * least / 0.12875)
+
+
+def _kernel(instruction: str) -> str:
+    return f'%{instruction} = bf16[2,32,8192,64] custom-call(%x), custom_call_target="tpu_custom_call"'
+
+
+@pytest.mark.parametrize("others", [{}, {"ragged-dot-none.9": 25.0, "ragged-dot-metadata.10": 0.25}],
+                         ids=["flash_only", "with_ragged_dot"])
+def test_flash_roofline_is_over_the_flash_kernels_time_alone(others):
+    """XLA's own Mosaic kernels for the grouped products are custom calls
+    too: they are the experts' (``expert_matmul_roofline``), not the
+    denominator of the flash kernels' share. A step whose only Mosaic
+    kernels are the flash kernels reads as it did over every custom call."""
+    with open(os.path.join(ROOT, "benchmarks", "configs", "lfm2-24b-a2b-ep8.json")) as f:
+        config = json.load(f)
+    peak = {"flops_per_s_bf16": 197e12, "bytes_per_s": 819e9}
+    flash = {"flash_fwd.3": 42.0, "flash_bwd_dkv.1": 25.0, "flash_bwd_dq": 13.0}
+    device, t = trace_reduce.DeviceTrace("/device:TPU:0"), 0.0
+    for _ in range(2):
+        start = t
+        for instruction, ms in {**flash, **others, "fusion.7": 100.0}.items():
+            name = _kernel(instruction) if instruction != "fusion.7" else "%fusion.7 = bf16[8] fusion(%x)"
+            device.ops.append((name, t, ms * 1e-3))
+            t += ms * 1e-3
+        device.modules.append(("jit_step(1)", start, t - start))
+    trace = trace_reduce.Trace([device], [], 0.0)
+    ctx = _ctx(trace, peak=peak, config=config, window={"batch": 2, "seq_len": 8192})
+    read = harness.load_reader(FILES, "flash_roofline")
+    calls = lfm2_work.train_step_work(config, 2, 8192)["attentions"]
+    least = work.flash_least_time(calls, peak, itemsize=2, training=True)["seconds"]
+    assert read(ctx) == pytest.approx(100.0 * least / 0.080)
+    assert trace.custom_call_s() == pytest.approx(2e-3 * (80.0 + sum(others.values())))
+    no_flash = trace_reduce.Trace([trace_reduce.DeviceTrace("/device:TPU:0", ops=[
+        (_kernel("ragged-dot-none.9"), 0.0, 1.0)], modules=[("jit_step(1)", 0.0, 1.0)])], [], 0.0)
+    assert read({**ctx, "trace": no_flash}) is None and read({**ctx, "trace": None}) is None
