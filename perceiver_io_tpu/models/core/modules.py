@@ -250,6 +250,72 @@ class MultiHeadAttention(nn.Module):
         return self.attend(q, k, v, pad_mask=pad_mask, deterministic=deterministic)
 
 
+class LatentAttention(nn.Module):
+    """Causal self-attention whose queries, keys and values come out of
+    low-rank latents (multi-head latent attention, the DeepSeek-V3 form;
+    docs/lm.md): ``cq = rms(u Wqa)`` and ``q = cq Wqb`` to ``num_heads`` heads
+    of ``qk_rope_head_dim`` rotary and ``qk_nope_head_dim`` other channels;
+    ``u Wkva`` gives ``ckv`` (``kv_lora_rank`` channels, then ``rms``) and one
+    rotary key head beside it, which every head shares; ``ckv Wkvb`` gives
+    each head its ``qk_nope_head_dim`` key channels and ``v_head_dim`` values.
+    Scores are over the ``qk_rope_head_dim + qk_nope_head_dim`` channels of a
+    head, scaled by their number; no bias anywhere.
+
+    The training form: keys and values are expanded to every head and the
+    rotary key is broadcast, so the kernels of ``dot_product_attention`` see
+    plain heads. A head's channels are laid out rotary first (``q_b_proj``'s
+    columns by head ``[rotary | other]``), so that :class:`RotaryEmbedding`,
+    which rotates a head's leading channels in adjacent pairs, runs on the
+    projection's flat output as in :class:`MultiHeadAttention`; ``kv_b_proj``'s
+    columns are by head ``[key | value]``. The scopes ``latent_q``,
+    ``latent_kv`` and ``latent_assemble`` name the two low-rank paths and what
+    stands between them and the kernels (docs/observability.md).
+    """
+
+    num_heads: int
+    num_input_channels: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    norm_eps: float = 1e-5
+    init_scale: float = 0.02
+    dtype: Any = jnp.float32
+    attention_impl: str = "auto"
+
+    @nn.compact
+    def __call__(
+        self, u: jnp.ndarray, pad_mask: Optional[jnp.ndarray] = None,
+        rot_pos_emb: Optional[RotaryEmbedding] = None,
+    ) -> jnp.ndarray:
+        b, n, _ = u.shape
+        h, dn, dr, dv = self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
+        dense = lambda features, name: _dense(features, False, self.init_scale, self.dtype, name)
+        norm = lambda name: RMSNorm(self.norm_eps, self.dtype, name=name)
+        with jax.named_scope("latent_q"):
+            q_flat = dense(h * (dr + dn), "q_b_proj")(norm("q_a_norm")(dense(self.q_lora_rank, "q_a_proj")(u)))
+        with jax.named_scope("latent_kv"):
+            kv_a = dense(self.kv_lora_rank + dr, "kv_a_proj")(u)
+            kv = dense(h * (dn + dv), "kv_b_proj")(norm("kv_a_norm")(kv_a[..., :self.kv_lora_rank]))
+        with jax.named_scope("latent_assemble"):
+            q_flat = q_flat * ((dr + dn) ** -0.5)
+            k_rot = kv_a[..., self.kv_lora_rank:]
+            if rot_pos_emb is not None:
+                with jax.named_scope("rotary"):
+                    q_flat = rot_pos_emb.rotate(q_flat, h)
+                    k_rot = rot_pos_emb.rotate(k_rot, 1)
+            kv = kv.reshape(b, n, h, dn + dv)
+            k = jnp.concatenate(
+                [jnp.broadcast_to(k_rot[:, :, None, :], (b, n, h, dr)), kv[..., :dn]], axis=-1)
+            q = q_flat.reshape(b, n, h, dr + dn).transpose(0, 2, 1, 3)
+            k, v = k.transpose(0, 2, 1, 3), kv[..., dn:].transpose(0, 2, 1, 3)
+        o = dot_product_attention(q, k, v, pad_mask=pad_mask, causal=True, impl=self.attention_impl)
+        with jax.named_scope("latent_assemble"):
+            o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
+        return dense(self.num_input_channels, "o_proj")(o)
+
+
 class CrossAttention(nn.Module):
     """Pre-layer-norm cross-attention with the Perceiver-AR ``x_kv_prefix``
     path: keys/values = concat(prefix, query) so latents self-attend at the

@@ -1,11 +1,15 @@
 """Decoder-only language model whose layers are declared one by one
 (docs/lm.md): every layer is ``h = h + operator(rms(h))`` then
 ``h = h + ffn(rms(h))``, where the operator is a gated short convolution
-(``"conv"``) or causal grouped-query attention with RMS-normed q and k and
-whole-head rotary (``"full_attention"``), and the feed-forward is a gated
-MLP in the first ``num_dense_layers`` layers and a layer of sparse experts
-in the others. After the last layer one more RMS norm, then logits against
-the embedding table.
+(``"conv"``), causal grouped-query attention with RMS-normed q and k and
+whole-head rotary (``"full_attention"``) or causal attention out of low-rank
+latents with one shared rotary key head (``"latent_attention"``), and the
+feed-forward is a gated MLP in the first ``num_dense_layers`` layers and a
+layer of sparse experts, with ``num_shared_experts`` experts beside them that
+every token takes, in the others. After the last layer one more RMS norm,
+then logits against the embedding table or, untied, a head of its own. With
+``num_nextn_predict_layers`` one more expert layer predicts the token after
+the next from the last hidden state and the next token's embedding.
 """
 from __future__ import annotations
 
@@ -13,16 +17,22 @@ from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from perceiver_io_tpu.models.core.config import register_config
 from perceiver_io_tpu.models.core.hybrid import GatedMLP, ShortConv, SparseExperts
-from perceiver_io_tpu.models.core.modules import MultiHeadAttention, RMSNorm, _remat_policy
+from perceiver_io_tpu.models.core.modules import (
+    LatentAttention,
+    MultiHeadAttention,
+    RMSNorm,
+    _remat_policy,
+)
 from perceiver_io_tpu.models.sequence import TiedOutputAdapter
 from perceiver_io_tpu.ops.position import RotaryEmbedding, frequency_position_encoding, positions
 
-LAYER_TYPES = ("conv", "full_attention")
+LAYER_TYPES = ("conv", "full_attention", "latent_attention")
 
 
 @register_config
@@ -34,7 +44,15 @@ class DecoderLMConfig:
     ``expert_channels``. ``num_experts`` is how many of those experts this
     model holds, from ``expert_offset`` on: equal to ``router_width`` (and
     offset 0) for the whole model, fewer for one chip's share of an
-    expert-parallel layer, whose output is then the held experts' part."""
+    expert-parallel layer, whose output is then the held experts' part.
+    ``num_shared_experts`` more experts of the same width stand beside them
+    as one gated MLP that takes every token, unweighted, and that every share
+    holds whole. ``latent_attention`` layers take their widths from
+    ``q_lora_rank``, ``kv_lora_rank`` and the three head widths, not from
+    ``num_channels / num_heads``. ``tie_word_embeddings`` off gives the model
+    a ``(num_channels, vocab_size)`` head of its own.
+    ``num_nextn_predict_layers`` (0 or 1) adds the multi-token-prediction
+    module, whose loss ``lm_loss_fn`` adds under ``mtp_loss_weight``."""
 
     vocab_size: int = 262
     max_seq_len: int = 4096
@@ -49,6 +67,7 @@ class DecoderLMConfig:
     num_experts: int = 8
     expert_offset: int = 0
     experts_per_token: int = 2
+    num_shared_experts: int = 0
     use_expert_bias: bool = True
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
@@ -57,14 +76,31 @@ class DecoderLMConfig:
     rope_theta: float = 1000000.0
     init_scale: float = 0.02
     activation_checkpointing: bool = False
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    tie_word_embeddings: bool = True
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
         self.layer_types = tuple(self.layer_types)
         unknown = set(self.layer_types) - set(LAYER_TYPES)
         if unknown:
             raise ValueError(f"layer_types has {sorted(unknown)}; known: {LAYER_TYPES}")
-        if self.num_channels % self.num_heads:
+        if "full_attention" in self.layer_types and self.num_channels % self.num_heads:
             raise ValueError("num_channels must be divisible by num_heads")
+        if "latent_attention" in self.layer_types:
+            widths = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
+            missing = [w for w in widths if getattr(self, w) <= 0]
+            if missing or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"latent_attention needs {widths} positive and qk_rope_head_dim even; "
+                    f"not set: {missing}")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("num_nextn_predict_layers is 0 or 1: one prediction module is implemented")
 
     @property
     def num_layers(self) -> int:
@@ -72,7 +108,7 @@ class DecoderLMConfig:
 
     @property
     def has_experts(self) -> bool:
-        return self.num_dense_layers < self.num_layers
+        return self.num_dense_layers < self.num_layers or self.num_nextn_predict_layers > 0
 
 
 class DecoderLayer(nn.Module):
@@ -95,6 +131,14 @@ class DecoderLayer(nn.Module):
             op = ShortConv(
                 cfg.num_channels, cfg.conv_kernel_size, cfg.init_scale, self.dtype, name="conv"
             )(u, pad_mask)
+        elif self.layer_type == "latent_attention":
+            op = LatentAttention(
+                num_heads=cfg.num_heads, num_input_channels=cfg.num_channels,
+                q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+                qk_nope_head_dim=cfg.qk_nope_head_dim, qk_rope_head_dim=cfg.qk_rope_head_dim,
+                v_head_dim=cfg.v_head_dim, norm_eps=cfg.norm_eps, init_scale=cfg.init_scale,
+                dtype=self.dtype, attention_impl=self.attention_impl, name="attention",
+            )(u, pad_mask, rot)
         else:
             op = MultiHeadAttention(
                 num_heads=cfg.num_heads, num_q_input_channels=cfg.num_channels,
@@ -118,7 +162,45 @@ class DecoderLayer(nn.Module):
                 routed_scaling_factor=cfg.routed_scaling_factor, init_scale=cfg.init_scale,
                 dtype=self.dtype, name="moe",
             )(u)
+            if cfg.num_shared_experts:
+                out = out + GatedMLP(
+                    cfg.num_channels, cfg.num_shared_experts * cfg.expert_channels, cfg.init_scale,
+                    self.dtype, name="shared_expert")(u)
         return h + out, stats
+
+
+def _layer_class(cfg: DecoderLMConfig):
+    """``DecoderLayer``, recomputed in the backward pass if the config says so."""
+    if cfg.activation_checkpointing:
+        return nn.remat(DecoderLayer, policy=_remat_policy(offload=False))
+    return DecoderLayer
+
+
+class NextTokenModule(nn.Module):
+    """The multi-token-prediction module (the DeepSeek-V3 form): at position
+    ``i`` the last layer's hidden state ``h_i`` and the next token's embedding
+    go, each through an RMS norm of its own, side by side through ``eh_proj``
+    (``2 c -> c``), then through one whole expert layer of the last layer's
+    operator kind, then one more RMS norm. Returns that and the layer's
+    stats; the model's own embedding and head stand before and after it."""
+
+    config: DecoderLMConfig
+    dtype: Any = jnp.float32
+    attention_impl: str = "auto"
+
+    @nn.compact
+    def __call__(self, h, next_emb, pad_mask: Optional[jnp.ndarray], rot: Optional[RotaryEmbedding]):
+        cfg = self.config
+        norm = lambda name: RMSNorm(cfg.norm_eps, self.dtype, name=name)
+        z = jnp.concatenate([norm("embed_norm")(next_emb), norm("hidden_norm")(h)], axis=-1)
+        z = nn.Dense(
+            cfg.num_channels, use_bias=False, dtype=self.dtype, name="eh_proj",
+            kernel_init=nn.initializers.normal(stddev=cfg.init_scale),
+        )(z)
+        z, stats = _layer_class(cfg)(
+            cfg, cfg.layer_types[-1], False, self.dtype, self.attention_impl, name="layer",
+        )(z, pad_mask, rot)
+        return norm("out_norm")(z), stats
 
 
 class DecoderLM(nn.Module):
@@ -126,7 +208,11 @@ class DecoderLM(nn.Module):
     ``return_stats`` also ``{"moe_assignments_held", "moe_expert_load_max_over_mean",
     "moe_layers_bounded"}``: token-expert pairs computed by the held experts,
     summed over the expert layers; the worst layer's fullest held expert over
-    its mean; and the expert layers whose held pairs fitted the row bound."""
+    its mean; and the expert layers whose held pairs fitted the row bound.
+    With ``next_ids`` ``(b, n)``, the token after each position, a model with
+    the prediction module returns ``(logits, mtp_logits)`` in the logits'
+    place: ``mtp_logits[:, i]`` predicts the token after ``next_ids[:, i]``,
+    and the module's expert layer counts in the stats."""
 
     config: DecoderLMConfig
     dtype: Any = jnp.float32
@@ -138,33 +224,59 @@ class DecoderLM(nn.Module):
             cfg.vocab_size, cfg.num_channels,
             embedding_init=nn.initializers.normal(stddev=cfg.init_scale), name="embed",
         )
-        layer_cls = DecoderLayer
-        if cfg.activation_checkpointing:
-            layer_cls = nn.remat(DecoderLayer, policy=_remat_policy(offload=False))
+        layer_cls = _layer_class(cfg)
         self.layers = [
             layer_cls(cfg, kind, i < cfg.num_dense_layers, self.dtype, self.attention_impl,
                       name=f"layers_{i}")
             for i, kind in enumerate(cfg.layer_types)
         ]
         self.out_norm = RMSNorm(cfg.norm_eps, self.dtype, name="out_norm")
-        self.output_adapter = TiedOutputAdapter(
-            vocab_size=cfg.vocab_size, emb_bias=False, dtype=self.dtype, name="output_adapter")
+        if cfg.tie_word_embeddings:
+            self.output_adapter = TiedOutputAdapter(
+                vocab_size=cfg.vocab_size, emb_bias=False, dtype=self.dtype, name="output_adapter")
+        else:
+            self.head = nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=self.dtype, name="head",
+                kernel_init=nn.initializers.normal(stddev=cfg.init_scale),
+            )
+        if cfg.num_nextn_predict_layers:
+            self.mtp = NextTokenModule(cfg, self.dtype, self.attention_impl, name="mtp")
+
+    def _logits(self, x: jnp.ndarray) -> jnp.ndarray:
+        if self.config.tie_word_embeddings:
+            return self.output_adapter(x, self.embed.embedding)
+        return self.head(x)
 
     def __call__(self, x: jnp.ndarray, pad_mask: Optional[jnp.ndarray] = None,
-                 return_stats: bool = False):
+                 return_stats: bool = False, next_ids: Optional[jnp.ndarray] = None):
         cfg = self.config
         if x.shape[1] > cfg.max_seq_len:
             raise ValueError(f"sequence length ({x.shape[1]}) exceeds max_seq_len ({cfg.max_seq_len})")
+        if next_ids is not None and not cfg.num_nextn_predict_layers:
+            raise ValueError("next_ids are the prediction module's: num_nextn_predict_layers is 0")
         shift = None if pad_mask is None else pad_mask.sum(axis=1, keepdims=True)
-        angles = frequency_position_encoding(
-            positions(*x.shape, shift=shift), cfg.num_channels // cfg.num_heads, cfg.rope_theta)
-        rot = RotaryEmbedding(angles)
+        pos = positions(*x.shape, shift=shift)
+        # rotary tables by operator kind: over a whole head, or over a latent
+        # head's rotary channels
+        widths = {"full_attention": cfg.num_channels // cfg.num_heads,
+                  "latent_attention": cfg.qk_rope_head_dim}
+        rots = {
+            kind: RotaryEmbedding(frequency_position_encoding(pos, width, cfg.rope_theta))
+            for kind, width in widths.items() if kind in cfg.layer_types
+        }
         h = self.embed(x).astype(self.dtype)
         stats = []
-        for layer in self.layers:
-            h, s = layer(h, pad_mask, rot)
+        for layer, kind in zip(self.layers, cfg.layer_types):
+            h, s = layer(h, pad_mask, rots.get(kind))
             stats.append(s)
-        logits = self.output_adapter(self.out_norm(h), self.embed.embedding)
+        logits = self._logits(self.out_norm(h))
+        if next_ids is not None:
+            with jax.named_scope("mtp"):
+                next_emb = self.embed(next_ids).astype(self.dtype)
+            z, s = self.mtp(h, next_emb, pad_mask, rots.get(cfg.layer_types[-1]))
+            stats.append(s)
+            with jax.named_scope("mtp"):
+                logits = logits, self._logits(z)
         if not return_stats:
             return logits
         stats = jnp.stack(stats)

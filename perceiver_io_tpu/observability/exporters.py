@@ -126,6 +126,8 @@ HELP_TEXT = {
     "trainer_moe_assignments_held": "Token-expert pairs the held experts computed in a training step, summed over the expert layers (mean of the log window).",
     "trainer_moe_expert_load_max_over_mean": "Fullest held expert over the mean held expert, the worst expert layer of a training step (mean of the log window).",
     "trainer_moe_layers_bounded": "Expert layers of a training step whose held pairs fitted the row bound, so that dispatch, experts and combine ran on it and not on the worst-case buffer (mean of the log window; the number of expert layers unless routing has moved onto the held experts).",
+    "trainer_lm_loss": "Next-token term of the training loss of an lm model with the prediction module (mean of the log window).",
+    "trainer_mtp_loss": "Second term of that loss, the prediction module's token after the next, before its weight (mean of the log window).",
     "fleet_requests_submitted_total": "Requests accepted fleet-wide.",
     "fleet_requests_completed_total": "Fleet requests completed exactly once.",
     "fleet_requests_shed_total": "Submissions shed by fleet-level max_pending backpressure.",

@@ -44,9 +44,13 @@ _TP_KERNEL_RULES: Tuple[Tuple[str, int], ...] = (
     (r"o_proj/kernel$", 0),
     (r"mlp/hidden/kernel$", 1),
     (r"mlp/out/kernel$", 0),
-    # gated MLP (models/core/hybrid.py): gate and up by column, down by row
-    (r"mlp/(gate|up)/kernel$", 1),
-    (r"mlp/down/kernel$", 0),
+    # gated MLP (models/core/hybrid.py): gate and up by column, down by row;
+    # the shared expert beside the routed ones is one
+    (r"(mlp|shared_expert)/(gate|up)/kernel$", 1),
+    (r"(mlp|shared_expert)/down/kernel$", 0),
+    # latent attention (models/core/modules.py): the up-projections' columns
+    # are by head; the low-rank down-projections and their norms are not split
+    (r"(q_b_proj|kv_b_proj)/kernel$", 1),
     # stacked expert weights (experts, in, out): the experts' hidden width,
     # never the expert dimension
     (r"moe/(gate|up)$", 2),

@@ -1,5 +1,6 @@
 """Decoder-only language model CLI: layers declared one by one (gated short
-convolutions, grouped-query attention, sparse experts; docs/lm.md):
+convolutions, grouped-query attention, latent attention, sparse experts with
+shared ones beside them, a multi-token-prediction module; docs/lm.md):
 
     python -m perceiver_io_tpu.scripts.text.lm fit --data=wikitext \
         --data.dataset_dir=.cache/wikitext --trainer.max_steps=10000 \
@@ -22,7 +23,11 @@ FAMILY = ModelFamily(
     data_registry=DATA,
     build_model=lambda cfg, dm: DecoderLM(cfg, dtype=jnp.bfloat16),
     make_loss=lambda model, cfg: lm_loss_fn(model),
-    init_args=lambda cfg, batch: ((jnp.asarray(batch["input_ids"][:1]),), {}),
+    init_args=lambda cfg, batch: (
+        (jnp.asarray(batch["input_ids"][:1]),),
+        # the prediction module's parameters exist once it has been called
+        {"next_ids": jnp.asarray(batch["input_ids"][:1])} if cfg.num_nextn_predict_layers else {},
+    ),
     link=_link,
     defaults={
         "data.task": "clm",

@@ -66,18 +66,37 @@ def lm_loss_fn(model) -> Callable:
     position, pad labels ignored. With expert layers the step's metrics carry
     ``moe_assignments_held``, ``moe_expert_load_max_over_mean`` and
     ``moe_layers_bounded``, which the trainer logs and sets as gauges
-    ``trainer_moe_*`` (docs/observability.md)."""
+    ``trainer_moe_*`` (docs/observability.md).
+
+    A model with the prediction module (``num_nextn_predict_layers``) adds a
+    second term: the module reads the labels as the next tokens and predicts
+    the token after them, so its labels are the labels shifted by one more; a
+    row's last position has none, nor has a position whose own label is
+    ignored. ``loss = lm_loss + mtp_loss_weight * mtp_loss``, each a mean over
+    its own labels, and the metrics carry both terms."""
+    cfg = model.config
 
     def loss_fn(params, batch, rng):
         labels = batch["labels"]
         pad_mask = batch.get("pad_mask")
         if pad_mask is not None:
             labels = jnp.where(pad_mask, IGNORE_INDEX, labels)
-        logits, stats = model.apply(
-            {"params": params}, batch["input_ids"], pad_mask=pad_mask, return_stats=True
+        if not cfg.num_nextn_predict_layers:
+            logits, stats = model.apply(
+                {"params": params}, batch["input_ids"], pad_mask=pad_mask, return_stats=True
+            )
+            loss = masked_cross_entropy(logits, labels)
+            return loss, stats if cfg.has_experts else {}
+        (logits, mtp_logits), stats = model.apply(
+            {"params": params}, batch["input_ids"], pad_mask=pad_mask, return_stats=True,
+            next_ids=jnp.maximum(labels, 0),
         )
-        loss = masked_cross_entropy(logits, labels)
-        return loss, stats if model.config.has_experts else {}
+        lm_loss = masked_cross_entropy(logits, labels)
+        after = jnp.pad(labels[:, 1:], ((0, 0), (0, 1)), constant_values=IGNORE_INDEX)
+        with jax.named_scope("mtp"):
+            mtp_loss = masked_cross_entropy(mtp_logits, jnp.where(labels == IGNORE_INDEX, IGNORE_INDEX, after))
+        loss = lm_loss + cfg.mtp_loss_weight * mtp_loss
+        return loss, {**stats, "lm_loss": lm_loss, "mtp_loss": mtp_loss}
 
     return loss_fn
 

@@ -331,6 +331,37 @@ def test_grouped_head_attention_lowers_for_tpu_without_repeating_keys_or_values(
     assert f"tensor<{b}x{h}x{n}x{n}" not in grouped  # nor a score matrix
 
 
+def test_latent_attention_at_the_cells_flash_shapes_lowers_with_a_two_kernel_backward():
+    """``glm47flash-train-8k``'s attention call: 20 heads on 20, 8192 x 8192
+    causal, 256-wide query-key and value heads, bfloat16. One head's float32
+    dQ is 8 MiB against the fused kernel's 2 MiB, so the backward lowers as
+    ``flash_bwd_dkv`` and ``flash_bwd_dq`` beside ``flash_fwd``, through the
+    module's own path (``LatentAttention``, ``attention_impl`` ``flash``), with
+    no score matrix and no einsum fallback."""
+    from perceiver_io_tpu.models.core.modules import LatentAttention
+    from perceiver_io_tpu.ops.position import RotaryEmbedding
+
+    b, n, c, h = 1, 8192, 2048, 20
+    q = jax.ShapeDtypeStruct((b, h, n, 256), jnp.bfloat16)
+    assert flash_attention.supported(q, q, q, causal=True) and not flash_attention._dq_fits_vmem(q, q)
+    op = LatentAttention(
+        num_heads=h, num_input_channels=c, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, dtype=jnp.bfloat16, attention_impl="flash")
+    u = jax.ShapeDtypeStruct((b, n, c), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((b, n, 64), jnp.float32)
+    rot = jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(RotaryEmbedding(jnp.zeros((1, 2, 2)))), (tables, tables))
+    params = jax.eval_shape(
+        lambda: op.init(jax.random.PRNGKey(0), jnp.zeros((1, 128, c), jnp.bfloat16), None, None))
+    loss = lambda p, u, rot: jnp.sum(op.apply(p, u, None, rot).astype(jnp.float32))
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, u, rot).lower(
+        lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert f"tensor<{b}x{h}x{n}x256xbf16>" in text  # q, k, v and o at 20 plain heads
+    assert f"x{n}x{n}x" not in text  # no score matrix
+
+
 def test_expert_layer_lowers_for_tpu_without_a_dispatch_array_over_all_experts():
     """The expert layer, forward and backward: tokens are sorted by held
     expert and multiplied by ``ragged_dot``; no ``(tokens, 64, ...)`` one-hot
