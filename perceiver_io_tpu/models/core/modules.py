@@ -11,7 +11,8 @@ TPU-first:
   (fp32 softmax, Pallas flash dispatch);
 - activation checkpointing maps to ``flax.linen.remat`` over attention layers
   (the fairscale ``checkpoint_wrapper`` equivalent, reference
-  ``modules.py:347-348,452-454``);
+  ``modules.py:347-348,452-454``), which keeps each flash call's output and
+  log-sum-exp and recomputes all else (:func:`_remat_policy`);
 - dtype policy: parameters are fp32; ``dtype`` selects the computation dtype
   (bf16 on TPU keeps the MXU fed at full rate).
 
@@ -69,17 +70,26 @@ class RMSNorm(nn.Module):
 
 
 def _remat_policy(offload: bool):
-    """Remat saving policy for activation checkpointing. ``offload=False``
-    saves nothing (pure rematerialization). ``offload=True`` is the TPU-native
-    equivalent of the reference's ``checkpoint_wrapper(offload_to_cpu=True)``
-    (reference ``modules.py:347-348``): the layer-boundary inputs (tagged
-    ``remat_layer_input`` via ``checkpoint_name``) are saved but moved to
-    pinned host memory, everything else is rematerialized — HBM holds no
-    per-layer activations between forward and backward."""
+    """Remat saving policy for activation checkpointing: what a recomputed
+    layer keeps between its forward and its backward. Either way it keeps, by
+    name, each flash call's output and log-sum-exp
+    (``flash_attention.SAVED_NAMES``: ``o`` ``(b, h, i, dv)`` in the compute
+    dtype and ``lse`` ``(b, h, i, 128)`` float32 a call), so the backward's
+    re-run of the layer hands them to the flash backward and the forward
+    kernel does not run a second time; on the einsum path no such name exists
+    and nothing is kept. All else is rematerialized. ``offload=True`` is the
+    TPU-native equivalent of the reference's
+    ``checkpoint_wrapper(offload_to_cpu=True)`` (reference
+    ``modules.py:347-348``): the layer-boundary inputs (tagged
+    ``remat_layer_input`` via ``checkpoint_name``) are saved too, but moved to
+    pinned host memory — HBM holds no per-layer activations between forward
+    and backward but the kernels' two."""
+    from perceiver_io_tpu.ops.flash_attention import SAVED_NAMES
+
     if not offload:
-        return None
+        return jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
     return jax.checkpoint_policies.save_and_offload_only_these_names(
-        names_which_can_be_saved=[],
+        names_which_can_be_saved=list(SAVED_NAMES),
         names_which_can_be_offloaded=["remat_layer_input"],
         offload_src="device",
         offload_dst="pinned_host",

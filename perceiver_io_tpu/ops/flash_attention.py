@@ -54,6 +54,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -79,6 +80,8 @@ _DQ_VMEM_BUDGET_BYTES = 2 * 1024 * 1024
 _FUSED_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 # backwards traced as two kernels because dQ is over the budget (docs/observability.md)
 _TWO_CALL_COUNTER = "flash_backward_two_call_total"
+# ``checkpoint_name`` of the forward's output and log-sum-exp under a gradient
+SAVED_NAMES = ("flash_out", "flash_lse")
 
 
 def _pick_block(n: int) -> Optional[int]:
@@ -178,6 +181,10 @@ def _flash(q, k, v, pad, causal):
 
 def _flash_fwd(q, k, v, pad, causal):
     o, lse = _forward(q, k, v, pad, causal)
+    # named so that a layer's recomputation can keep them (``SAVED_NAMES``):
+    # its backward then hands these to ``_flash_bwd`` without a second forward
+    o = checkpoint_name(o, SAVED_NAMES[0])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q, k, v, pad, o, lse)
 
 

@@ -216,25 +216,119 @@ def test_backward_is_one_kernel_while_dq_fits_the_budget():
     assert kernels(4, 4, rows // 4 + 128) == ({"flash_fwd", "flash_bwd_dkv"}, 0)
 
 
-def test_two_call_counter_has_help_text_and_a_benchmark_reader(monkeypatch):
-    """``benchmarks/metrics/flash_bwd_two_call_shapes.py``: nothing from a
-    program without the counter, 0 once a fused backward was traced, then the
-    count of two-call ones."""
+def _benchmark_reader(monkeypatch, metric):
+    """``benchmarks/metrics/<metric>.py``'s ``read``, the repository's root on the path."""
     import os
-
-    import perceiver_io_tpu.observability as observability
-    from perceiver_io_tpu.observability.exporters import HELP_TEXT
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
     from benchmarks import harness
 
+    return harness.load_reader(os.path.join(root, "benchmarks"), metric)
+
+
+def test_two_call_counter_has_help_text_and_a_benchmark_reader(monkeypatch):
+    """``benchmarks/metrics/flash_bwd_two_call_shapes.py``: nothing from a
+    program without the counter, 0 once a fused backward was traced, then the
+    count of two-call ones."""
+    import perceiver_io_tpu.observability as observability
+    from perceiver_io_tpu.observability.exporters import HELP_TEXT
+
     registry = observability.MetricsRegistry()
     monkeypatch.setattr(observability, "default_registry", lambda: registry)
-    read = harness.load_reader(os.path.join(root, "benchmarks"), "flash_bwd_two_call_shapes")
+    read = _benchmark_reader(monkeypatch, "flash_bwd_two_call_shapes")
     assert "flash_backward_two_call_total" in HELP_TEXT
     assert read({}) is None  # the parent's program never declares it
     _traced_backward(1, 1, 1024)
     assert read({}) == 0.0
     _traced_backward(1, 1, 8192)
     assert read({}) == 1.0
+
+
+def _operator(kind):
+    """``(module class, its fields, its call's arguments after the parameters)``
+    at batch 4, 256 positions, bfloat16, on the kernel path."""
+    from perceiver_io_tpu.models.core.modules import LatentAttention, MultiHeadAttention
+
+    b, n = 4, 256
+    if kind == "grouped_heads":  # 8 query heads on 2
+        x = jnp.zeros((b, n, 512), jnp.bfloat16)
+        fields = dict(num_heads=8, num_q_input_channels=512, num_kv_input_channels=512,
+                      causal_attention=True, qkv_bias=False, out_bias=False, num_kv_heads=2, qk_norm=True)
+        return MultiHeadAttention, fields, (x, x)
+    fields = dict(num_heads=4, num_input_channels=128, q_lora_rank=48, kv_lora_rank=32,
+                  qk_nope_head_dim=48, qk_rope_head_dim=16, v_head_dim=64)
+    return LatentAttention, fields, (jnp.zeros((b, n, 128), jnp.bfloat16), None, None)
+
+
+def _recomputed_layers_kernels(kind, policy, mesh=None):
+    """The Mosaic kernels, by name, in the TPU lowering of the gradient of one
+    recomputed layer (``nn.remat`` with ``policy`` around the operator) whose
+    output something after it reads, as the next layer does."""
+    import re
+    from contextlib import nullcontext
+
+    import flax.linen as nn
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    cls, fields, args = _operator(kind)
+    layer = nn.remat(cls, policy=policy)(**fields, dtype=jnp.bfloat16, attention_impl="flash")
+    params = layer.init(jax.random.PRNGKey(0), *(a if a is None else a[:1] for a in args))
+
+    def loss(p, x):
+        rest = tuple(a if a is None else x for a in args[1:])
+        ambient = nullcontext() if mesh is None else jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+        with ambient:
+            return jnp.sum(layer.apply(p, x, *rest).astype(jnp.float32) ** 2)
+
+    x = jax.ShapeDtypeStruct(args[0].shape, args[0].dtype,
+                             sharding=None if mesh is None else NamedSharding(mesh, P("data")))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    if mesh is not None:
+        assert "sdy.manual_computation" in text  # the calls stand in shard_map
+    return sorted(re.findall(r'kernel_name = "([^"]*)"', text))
+
+
+@pytest.mark.parametrize("over", ["one_device", "data2_model2"])
+@pytest.mark.parametrize("kind", ["grouped_heads", "latent_attention"])
+def test_recomputed_layer_runs_the_flash_forward_once(devices, kind, over):
+    """``_remat_policy`` keeps the forward's output and log-sum-exp by name
+    (``flash_attention.SAVED_NAMES``), so the backward's re-run of the layer
+    holds no ``flash_fwd``; a policy that keeps nothing holds it a second
+    time. The same under a mesh of four devices, where the call stands in
+    ``shard_map``, and with the offloading policy."""
+    from perceiver_io_tpu.models.core.modules import _remat_policy
+    from perceiver_io_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    mesh = None if over == "one_device" else make_mesh(MeshConfig(data=2, model=2), devices=devices[:4])
+    once = ["flash_bwd_dkv", "flash_fwd"]
+    assert _recomputed_layers_kernels(kind, None, mesh) == once + ["flash_fwd"]
+    assert _recomputed_layers_kernels(kind, _remat_policy(offload=False), mesh) == once
+    assert _recomputed_layers_kernels(kind, _remat_policy(offload=True), mesh) == once
+
+
+def test_forward_runs_per_step_reader_counts_the_forward_kernels_events_alone(monkeypatch):
+    """``benchmarks/metrics/flash_fwd_runs_per_step.py``: 12 ``flash_fwd``
+    events over 2 steps on one device read 6.0 (the backward's kernels and
+    XLA's ``ragged-dot-*`` are not counted), over two devices the same a
+    device, and a run without a trace reads nothing."""
+    read = _benchmark_reader(monkeypatch, "flash_fwd_runs_per_step")
+    from benchmarks.trace_reduce import DeviceTrace, Trace
+
+    call = '= (bf16[1,20,8192,256]) custom-call(...), custom_call_target="tpu_custom_call"'
+
+    def device(name):
+        kernels = ["%flash_fwd", "%flash_fwd.1", "%flash_fwd.12", "%flash_fwd.3.remat",
+                   "%flash_fwd.4", "%flash_fwd.5", "%flash_bwd_dkv.1", "%flash_bwd_dq.2",
+                   "%ragged-dot-none.7", "%ragged-dot-metadata"]
+        ops = [(f"{k} {call}", 0.001 * t, 0.0005) for t, k in enumerate(kernels * 2)]
+        ops.append(("%fusion.9 = bf16[8] fusion(%flash_fwd.1)", 0.5, 0.001))
+        return DeviceTrace(name, ops=ops, modules=[("jit_step(123)", 0.0, 0.2), ("jit_step(123)", 0.2, 0.2),
+                                                   ("jit_other(7)", 0.4, 0.1)])
+
+    ctx = lambda trace: {"trace": trace, "mix": {"trace": {"step_module": "jit_step"}}}
+    assert read(ctx(Trace([device("/device:TPU:0")], [], 0.0))) == 6.0
+    assert read(ctx(Trace([device("/device:TPU:0"), device("/device:TPU:1")], [], 0.0))) == 6.0
+    assert read(ctx(None)) is None
+    assert read(ctx(Trace([], [], 0.0))) is None  # the CPU has no device plane

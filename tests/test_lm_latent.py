@@ -186,6 +186,41 @@ def test_recomputation_by_layer_covers_the_modules_layer(remat):
         np.testing.assert_allclose(a, b, atol=1e-6)
 
 
+#: a convolution layer and grouped-query attention (4 heads of 32 on 2), the flash kernels' least shapes
+GROUPED = dict(
+    vocab_size=64, max_seq_len=256, num_channels=128, num_heads=4, num_kv_heads=2,
+    layer_types=("conv", "full_attention"), num_dense_layers=1, mlp_channels=96, expert_channels=48,
+    router_width=8, num_experts=8, experts_per_token=2)
+
+
+@pytest.mark.parametrize("base", [GROUPED, LATENT], ids=["full_attention", "latent_attention"])
+def test_recomputation_on_the_kernel_path_keeps_the_forwards_outputs_and_the_gradients(base):
+    """On the flash path (interpreted here) a recomputed layer keeps the
+    forward kernel's output and log-sum-exp: the gradient's trace holds as
+    many ``flash_fwd`` calls with ``activation_checkpointing`` as without (one
+    an attention layer, the prediction module's too), and loss and every
+    gradient leaf are what they are without."""
+    from perceiver_io_tpu.training.tasks import lm_loss_fn
+
+    x = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0, 64)
+    batch = {"input_ids": x[:, :-1], "labels": x[:, 1:], "pad_mask": jnp.zeros((2, 128), bool)}
+    more = {"next_ids": x[:1, 1:]} if base.get("num_nextn_predict_layers") else {}
+    params = DecoderLM(DecoderLMConfig(**base)).init(jax.random.PRNGKey(0), x[:1, :-1], **more)["params"]
+    attentions = sum(t != "conv" for t in base["layer_types"]) + base.get("num_nextn_predict_layers", 0)
+
+    def run(remat):
+        model = DecoderLM(DecoderLMConfig(**{**base, "activation_checkpointing": remat}), attention_impl="flash")
+        grad = jax.value_and_grad(lambda p: lm_loss_fn(model)(p, batch, None)[0])
+        # a kernel is traced for the TPU and for the interpreter: two a call
+        assert str(jax.make_jaxpr(grad)(params)).count("name=flash_fwd") == 2 * attentions
+        return jax.jit(grad)(params)
+
+    (want, want_grads), (loss, grads) = run(False), run(True)
+    assert float(loss) == float(want)
+    for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(a, b, atol=1e-6)
+
+
 def test_partition_rules_for_the_new_leaves(devices):
     """Tensor parallelism splits the latent up-projections by head (their
     columns), ``o_proj`` by row, the shared expert as a gated MLP; the low-rank

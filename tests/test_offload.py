@@ -49,3 +49,33 @@ def test_offload_grads_finite_and_match_plain_remat():
         jax.tree_util.tree_leaves(grads_p), jax.tree_util.tree_leaves(grads_o)
     ):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+
+
+def test_offloading_policy_moves_the_layer_input_to_the_host_and_keeps_the_flash_residuals_on_the_device():
+    """Traced, not run: with offloading each recomputed layer's backward takes
+    its input from the host (``remat_layer_input``) and the flash forward's
+    output ``(b, h, n, dv)`` and log-sum-exp ``(b, h, n, 128)`` from the
+    device, by name, so the forward kernel is traced once a layer (in the two
+    branches of its platform switch) as it is without recomputation."""
+    import re
+
+    from perceiver_io_tpu.models.core.modules import SelfAttentionBlock
+
+    b, n, c, h = 2, 128, 128, 2
+    x = jnp.zeros((b, n, c))
+
+    def traced(**how):
+        block = SelfAttentionBlock(num_layers=2, num_heads=h, num_channels=c, attention_impl="flash", **how)
+        params = block.init(jax.random.PRNGKey(0), x)
+        return str(jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(block.apply(p, x) ** 2)))(params, x))
+
+    plain = traced()
+    offload = traced(activation_checkpointing=True, activation_offloading=True)
+    assert offload.count("name=flash_fwd") == plain.count("name=flash_fwd") == 4
+    assert "<host>" not in plain
+    assert set(re.findall(r"f32<host>\[([\d,]+)\]", offload)) == {f"{b},{n},{c}"}
+    # what a layer's recomputation is handed: its input from the host, o and lse as the kernel wrote them
+    handed = [line for line in offload.splitlines() if "lambda ;" in line and "<host>" in line]
+    assert len(handed) == 2
+    for line in handed:
+        assert f":f32[{b},{h},{n},{c // h}]" in line and f":f32[{b},{h},{n},128]" in line
