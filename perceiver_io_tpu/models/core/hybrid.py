@@ -40,6 +40,10 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 _ROWS_OVER_UNIFORM = 2
 #: rows of a tile of the grouped product's kernel on the TPU (``ragged-dot-none``)
 _ROW_TILE = 512
+#: what stands between an expert's gate and up projections
+_ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+#: how the router's logits become choices and weights (:func:`route`)
+ROUTER_SCORES = ("sigmoid", "softmax_topk")
 
 
 def _dense(features: int, init_scale: float, dtype, name: str) -> nn.Dense:
@@ -170,15 +174,27 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def route(tokens, router, bias, top_k: int, normalise: bool, scaling: float):
-    """``(indices, weights)``, both ``(t, top_k)``: scores are
-    ``sigmoid(tokens @ router)`` in float32 (six-pass products: a choice
-    that flips with the rounding of a bfloat16 product is a different
-    model), the ``top_k`` highest of ``scores + bias`` are chosen, and the
-    weights are those scores themselves, over their sum + 1e-6 if
-    ``normalise``, times ``scaling``. ``bias`` only chooses: it takes no
-    gradient."""
+def route(tokens, router, bias, top_k: int, normalise: bool, scaling: float,
+          score: str = "sigmoid"):
+    """``(indices, weights)``, both ``(t, top_k)``, from the logits ``tokens @
+    router`` in float32 (six-pass products: a choice that flips with the
+    rounding of a bfloat16 product is a different model).
+
+    ``score="sigmoid"``: scores are ``sigmoid(logits)``, the ``top_k`` highest
+    of ``scores + bias`` are chosen, and the weights are those scores
+    themselves, over their sum + 1e-6 if ``normalise``.
+    ``score="softmax_topk"``: the ``top_k`` highest of ``logits + bias`` are
+    chosen and the weights are a softmax over the chosen logits alone, which
+    sum to one (``normalise`` has nothing left to do). Either way times
+    ``scaling``; ``bias`` only chooses: it takes no gradient."""
+    if score not in ROUTER_SCORES:
+        raise ValueError(f"router score {score!r}; known: {ROUTER_SCORES}")
     logits = jnp.dot(tokens.astype(jnp.float32), router.astype(jnp.float32), precision=_HIGHEST)
+    if score == "softmax_topk":
+        chosen = logits if bias is None else logits + jax.lax.stop_gradient(bias.astype(jnp.float32))
+        _, indices = jax.lax.top_k(chosen, top_k)
+        weights = jax.nn.softmax(jnp.take_along_axis(logits, indices, axis=-1), axis=-1)
+        return indices, weights * scaling
     scores = jax.nn.sigmoid(logits)
     chosen = scores if bias is None else scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
     _, indices = jax.lax.top_k(chosen, top_k)
@@ -213,14 +229,16 @@ def sort_pairs(indices, weights, expert_offset: int, held: int):
     return order, inverse, group_sizes, weights
 
 
-def sorted_rows_output(tokens, weights, gate, up, down, order, inverse, group_sizes, rows: int):
+def sorted_rows_output(tokens, weights, gate, up, down, order, inverse, group_sizes, rows: int,
+                       activation: str = "silu"):
     """The held experts' part of the output for ``tokens`` ``(t, c)``, from
     the first ``rows`` pairs of :func:`sort_pairs`' order. ``rows`` is static
     and must hold every held pair (``group_sizes.sum() <= rows``), which sort
     first; ``order.shape[0]`` always does.
 
     The ``rows`` token rows are gathered, each projection is one grouped
-    product over the held experts' rows, and the rows go back to their tokens
+    product over the held experts' rows (``down(activation(gate x) * up x)``,
+    ``activation`` ``silu`` or ``relu``), and the rows go back to their tokens
     under their weights. What a grouped product leaves in the rows past the
     held pairs is never read: the output's are selected away before the
     combine, and their gradient is dropped where the rows were gathered."""
@@ -229,15 +247,17 @@ def sorted_rows_output(tokens, weights, gate, up, down, order, inverse, group_si
         valid = jnp.arange(rows) < group_sizes.sum()
         x = _take_tokens(tokens, head // weights.shape[1], valid, inverse)
     with jax.named_scope("experts"):
-        hidden = nn.silu(grouped_matmul(x, gate, group_sizes)) * grouped_matmul(x, up, group_sizes)
+        act = _ACTIVATIONS[activation]
+        hidden = act(grouped_matmul(x, gate, group_sizes)) * grouped_matmul(x, up, group_sizes)
         out_rows = grouped_matmul(hidden, down, group_sizes)
     with jax.named_scope("combine"):
         out_rows = jnp.where(valid[:, None], out_rows, jnp.zeros((), out_rows.dtype))
         return _combine(out_rows, weights, head)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _bounded_or_full(rows, fits, tokens, weights, gate, up, down, order, inverse, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _bounded_or_full(rows, activation, fits, tokens, weights, gate, up, down, order, inverse,
+                     group_sizes):
     """:func:`sorted_rows_output` on ``rows`` rows if ``fits`` (they hold the
     held pairs), else on every pair's: the same code at two sizes, chosen by
     ``lax.cond`` on a count of this call's own routing. Forward and backward
@@ -250,23 +270,24 @@ def _bounded_or_full(rows, fits, tokens, weights, gate, up, down, order, inverse
     stand outside the phase scopes, the scopes inside the branches."""
     return jax.lax.cond(
         fits,
-        functools.partial(sorted_rows_output, rows=rows),
-        functools.partial(sorted_rows_output, rows=order.shape[0]),
+        functools.partial(sorted_rows_output, rows=rows, activation=activation),
+        functools.partial(sorted_rows_output, rows=order.shape[0], activation=activation),
         tokens, weights, gate, up, down, order, inverse, group_sizes,
     )
 
 
-def _bounded_or_full_fwd(rows, fits, *operands):
-    return _bounded_or_full(rows, fits, *operands), (fits, operands)
+def _bounded_or_full_fwd(rows, activation, fits, *operands):
+    return _bounded_or_full(rows, activation, fits, *operands), (fits, operands)
 
 
-def _bounded_or_full_bwd(rows, res, g):
+def _bounded_or_full_bwd(rows, activation, res, g):
     fits, (*inputs, order, inverse, group_sizes) = res
 
     def pull_back(on_rows):
         def branch(g, *inputs):
             run = functools.partial(
-                sorted_rows_output, order=order, inverse=inverse, group_sizes=group_sizes, rows=on_rows)
+                sorted_rows_output, order=order, inverse=inverse, group_sizes=group_sizes,
+                rows=on_rows, activation=activation)
             return jax.vjp(run, *inputs)[1](g)
         return branch
 
@@ -278,7 +299,8 @@ _bounded_or_full.defvjp(_bounded_or_full_fwd, _bounded_or_full_bwd)
 
 
 def held_experts_output(
-    tokens, indices, weights, gate, up, down, expert_offset: int, rows: Optional[int] = None
+    tokens, indices, weights, gate, up, down, expert_offset: int, rows: Optional[int] = None,
+    activation: str = "silu",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' part of the layer's output for ``tokens`` ``(t, c)``
     and the pairs each held expert was given ``(held,)``, computed on the
@@ -287,12 +309,14 @@ def held_experts_output(
     On every pair none is dropped whatever the routing."""
     order, inverse, group_sizes, weights = sort_pairs(indices, weights, expert_offset, gate.shape[0])
     out = sorted_rows_output(
-        tokens, weights, gate, up, down, order, inverse, group_sizes, rows=rows or order.shape[0])
+        tokens, weights, gate, up, down, order, inverse, group_sizes, rows=rows or order.shape[0],
+        activation=activation)
     return out, group_sizes
 
 
 def bounded_experts_output(
-    tokens, indices, weights, gate, up, down, expert_offset: int, rows_expected: int
+    tokens, indices, weights, gate, up, down, expert_offset: int, rows_expected: int,
+    activation: str = "silu",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """:func:`held_experts_output` on ``rows_expected`` rows when this call's
     held pairs fit them and on every pair when they do not
@@ -302,10 +326,11 @@ def bounded_experts_output(
     order, inverse, group_sizes, weights = sort_pairs(indices, weights, expert_offset, gate.shape[0])
     operands = (tokens, weights, gate, up, down, order, inverse, group_sizes)
     if rows_expected >= order.shape[0]:
-        out = sorted_rows_output(*operands, rows=order.shape[0])
+        out = sorted_rows_output(*operands, rows=order.shape[0], activation=activation)
         return out, group_sizes, jnp.ones((), jnp.float32)
     fits = group_sizes.sum() <= rows_expected
-    return _bounded_or_full(rows_expected, fits, *operands), group_sizes, fits.astype(jnp.float32)
+    out = _bounded_or_full(rows_expected, activation, fits, *operands)
+    return out, group_sizes, fits.astype(jnp.float32)
 
 
 class SparseExperts(nn.Module):
@@ -324,6 +349,12 @@ class SparseExperts(nn.Module):
     fit them and on every pair's when they do not
     (:func:`bounded_experts_output`: the same code at two sizes, chosen each
     call from the routing it finds; no option).
+
+    ``router_score`` and ``activation`` say how the logits become weights
+    (:func:`route`) and what gates an expert (``silu``, ``relu``). The router
+    reads the experts' own input unless the call hands it another
+    (``router_input``: a model whose router stands before the attention gives
+    the attention's normed input); the experts always compute on ``u``.
 
     Returns ``(output, stats)``; ``stats`` is ``[pairs computed here, fullest
     held expert over the mean held expert, 1.0 if the held pairs fitted the
@@ -346,9 +377,15 @@ class SparseExperts(nn.Module):
     routed_scaling_factor: float = 1.0
     init_scale: float = 0.02
     dtype: Any = jnp.float32
+    router_score: str = "sigmoid"
+    activation: str = "silu"
 
     @nn.compact
-    def __call__(self, u: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def __call__(
+        self, u: jnp.ndarray, router_input: Optional[jnp.ndarray] = None
+    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        if self.activation not in _ACTIVATIONS:
+            raise ValueError(f"activation {self.activation!r}; known: {sorted(_ACTIVATIONS)}")
         if not 0 <= self.expert_offset <= self.router_width - self.num_experts:
             raise ValueError(
                 f"experts {self.expert_offset}..{self.expert_offset + self.num_experts - 1} "
@@ -365,29 +402,31 @@ class SparseExperts(nn.Module):
         up = self.param("up", init, (e, c, f))
         down = self.param("down", init, (e, f, c))
 
-        def tokens_part(x, router, bias, gate, up, down, axes=()):
+        def tokens_part(x, seen, router, bias, gate, up, down, axes=()):
             tokens = x.reshape(-1, c)
             with jax.named_scope("router"):
                 indices, weights = route(
-                    tokens, router, bias, self.top_k, self.norm_topk_prob,
-                    self.routed_scaling_factor,
+                    tokens if seen is None else seen.reshape(-1, c), router, bias, self.top_k,
+                    self.norm_topk_prob, self.routed_scaling_factor, self.router_score,
                 )
             out, sizes, fitted = bounded_experts_output(
                 tokens, indices, weights, gate, up, down, self.expert_offset,
-                expected_rows(tokens.shape[0], self.top_k, e, self.router_width))
+                expected_rows(tokens.shape[0], self.top_k, e, self.router_width), self.activation)
             if axes:  # every shard chose for its own tokens: bounded if all were
                 sizes, fitted = jax.lax.psum(sizes, axes), jax.lax.pmin(fitted, axes)
             sizes = sizes.astype(jnp.float32)
             stats = jnp.stack([sizes.sum(), sizes.max() / jnp.maximum(sizes.mean(), 1.0), fitted])
             return out.reshape(x.shape), stats
 
-        args = (u.astype(self.dtype), router, bias, gate, up, down)
+        seen = None if router_input is None else router_input.astype(self.dtype)
+        args = (u.astype(self.dtype), seen, router, bias, gate, up, down)
         axes = _batch_axes_dividing(u.shape[0])
         if not axes:
             return tokens_part(*args)
         from jax.sharding import PartitionSpec as P
 
-        specs = (P(axes),) + tuple(None if a is None else P() for a in args[1:])
+        specs = (P(axes), None if seen is None else P(axes)) + tuple(
+            None if a is None else P() for a in args[2:])
         return jax.shard_map(
             lambda *a: tokens_part(*a, axes=axes), mesh=jax.sharding.get_abstract_mesh(),
             in_specs=specs, out_specs=(P(axes), P()), check_vma=False,

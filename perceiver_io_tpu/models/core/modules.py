@@ -108,7 +108,9 @@ class MultiHeadAttention(nn.Module):
     attention paths index the shared head and repeat nothing. ``qk_norm``
     puts an :class:`RMSNorm` over each head's channels of q and of k (one
     gain per channel, shared by the heads) before the scale and the rotation.
-    Neither changes the program of a module that leaves them unset.
+    ``window`` (with ``causal_attention``) is a sliding window: a query sees
+    the ``window`` latest keys, its own position among them. None of the three
+    changes the program of a module that leaves it unset.
     """
 
     num_heads: int
@@ -128,6 +130,7 @@ class MultiHeadAttention(nn.Module):
     num_kv_heads: Optional[int] = None
     qk_norm: bool = False
     norm_eps: float = 1e-5
+    window: Optional[int] = None
 
     def _channels(self) -> Tuple[int, int, int]:
         qk = self.num_qk_channels or self.num_q_input_channels
@@ -243,6 +246,7 @@ class MultiHeadAttention(nn.Module):
             dropout_rng=dropout_rng,
             max_heads_parallel=self.max_heads_parallel,
             impl=self.attention_impl,
+            window=self.window,
         )
         return self.project_out(o)
 

@@ -1,12 +1,17 @@
 """Decoder-only language model whose layers are declared one by one
 (docs/lm.md): every layer is ``h = h + operator(rms(h))`` then
 ``h = h + ffn(rms(h))``, where the operator is a gated short convolution
-(``"conv"``), causal grouped-query attention with RMS-normed q and k and
-whole-head rotary (``"full_attention"``) or causal attention out of low-rank
-latents with one shared rotary key head (``"latent_attention"``), and the
-feed-forward is a gated MLP in the first ``num_dense_layers`` layers and a
-layer of sparse experts, with ``num_shared_experts`` experts beside them that
-every token takes, in the others. After the last layer one more RMS norm,
+(``"conv"``), causal grouped-query attention over every earlier position
+(``"full_attention"``) or over the ``sliding_window`` latest
+(``"window_attention"``), each with RMS-normed q and k unless ``qk_norm`` is
+off and with whole-head rotary if its kind is among ``rotary_layer_types``,
+or causal attention out of low-rank latents with one shared rotary key head
+(``"latent_attention"``), and the feed-forward is a gated MLP in the first
+``num_dense_layers`` layers and a layer of sparse experts, with
+``num_shared_experts`` experts beside them that every token takes, in the
+others (their router scores by a sigmoid or by a softmax over the chosen
+logits and reads the layer's own normed input or the operator's; an expert
+gates with ``silu`` or ``relu``). After the last layer one more RMS norm,
 then logits against the embedding table or, untied, a head of its own. With
 ``num_nextn_predict_layers`` one more expert layer predicts the token after
 the next from the last hidden state and the next token's embedding.
@@ -32,7 +37,11 @@ from perceiver_io_tpu.models.core.modules import (
 from perceiver_io_tpu.models.sequence import TiedOutputAdapter
 from perceiver_io_tpu.ops.position import RotaryEmbedding, frequency_position_encoding, positions
 
-LAYER_TYPES = ("conv", "full_attention", "latent_attention")
+LAYER_TYPES = ("conv", "full_attention", "window_attention", "latent_attention")
+#: the operator kinds that are grouped-query attention over plain heads
+_HEAD_ATTENTION = ("full_attention", "window_attention")
+#: the named scope around each kind's attention (docs/observability.md)
+ATTENTION_SCOPES = {"full_attention": "global_attention", "window_attention": "window_attention"}
 
 
 @register_config
@@ -52,7 +61,21 @@ class DecoderLMConfig:
     ``num_channels / num_heads``. ``tie_word_embeddings`` off gives the model
     a ``(num_channels, vocab_size)`` head of its own.
     ``num_nextn_predict_layers`` (0 or 1) adds the multi-token-prediction
-    module, whose loss ``lm_loss_fn`` adds under ``mtp_loss_weight``."""
+    module, whose loss ``lm_loss_fn`` adds under ``mtp_loss_weight``.
+
+    ``full_attention`` and ``window_attention`` layers have ``num_heads``
+    query heads on ``num_kv_heads`` key-value heads of ``head_dim`` channels
+    (0: ``num_channels / num_heads``), RMS-normed q and k if ``qk_norm``; a
+    ``window_attention`` layer sees the ``sliding_window`` latest positions,
+    its own among them. ``rotary_layer_types`` names the operator kinds whose
+    q and k are rotated: a kind left out gets no position signal and no
+    rotary table is built for it. Experts: ``router_score`` is ``sigmoid``
+    (the chosen experts' sigmoid scores, normalised if ``norm_topk_prob``) or
+    ``softmax_topk`` (top-k on the logits, a softmax over the chosen);
+    ``expert_activation`` is ``silu`` or ``relu``; ``router_input`` says
+    what the router reads: ``ffn``, the expert layer's own normed input, or
+    ``operator``, the normed input of the layer's operator (a router that
+    stands before the attention)."""
 
     vocab_size: int = 262
     max_seq_len: int = 4096
@@ -84,14 +107,29 @@ class DecoderLMConfig:
     tie_word_embeddings: bool = True
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
+    head_dim: int = 0
+    qk_norm: bool = True
+    sliding_window: int = 0
+    rotary_layer_types: Tuple[str, ...] = ("full_attention", "window_attention", "latent_attention")
+    router_score: str = "sigmoid"
+    expert_activation: str = "silu"
+    router_input: str = "ffn"
 
     def __post_init__(self):
         self.layer_types = tuple(self.layer_types)
-        unknown = set(self.layer_types) - set(LAYER_TYPES)
+        self.rotary_layer_types = tuple(self.rotary_layer_types)
+        unknown = (set(self.layer_types) | set(self.rotary_layer_types)) - set(LAYER_TYPES)
         if unknown:
             raise ValueError(f"layer_types has {sorted(unknown)}; known: {LAYER_TYPES}")
-        if "full_attention" in self.layer_types and self.num_channels % self.num_heads:
-            raise ValueError("num_channels must be divisible by num_heads")
+        if set(_HEAD_ATTENTION) & set(self.layer_types):
+            if not self.head_dim and self.num_channels % self.num_heads:
+                raise ValueError("num_channels must be divisible by num_heads (or set head_dim)")
+            if self.attention_head_dim % 2:
+                raise ValueError("a rotated head has an even number of channels")
+        if "window_attention" in self.layer_types and self.sliding_window < 1:
+            raise ValueError("window_attention layers need sliding_window >= 1")
+        if self.router_input not in ("ffn", "operator"):
+            raise ValueError(f"router_input is 'ffn' or 'operator', not {self.router_input!r}")
         if "latent_attention" in self.layer_types:
             widths = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
             missing = [w for w in widths if getattr(self, w) <= 0]
@@ -105,6 +143,11 @@ class DecoderLMConfig:
     @property
     def num_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def attention_head_dim(self) -> int:
+        """Channels of a ``full_attention`` / ``window_attention`` head."""
+        return self.head_dim or self.num_channels // self.num_heads
 
     @property
     def has_experts(self) -> bool:
@@ -140,14 +183,21 @@ class DecoderLayer(nn.Module):
                 dtype=self.dtype, attention_impl=self.attention_impl, name="attention",
             )(u, pad_mask, rot)
         else:
-            op = MultiHeadAttention(
-                num_heads=cfg.num_heads, num_q_input_channels=cfg.num_channels,
-                num_kv_input_channels=cfg.num_channels, causal_attention=True,
-                qkv_bias=False, out_bias=False, init_scale=cfg.init_scale, dtype=self.dtype,
-                attention_impl=self.attention_impl, num_kv_heads=cfg.num_kv_heads,
-                qk_norm=True, norm_eps=cfg.norm_eps, name="attention",
-            )(u, u, pad_mask=pad_mask, rot_pos_emb_q=rot, rot_pos_emb_k=rot)
+            # the published head width, whatever num_channels / num_heads is;
+            # left unset where the two agree, as the module always was built
+            width = cfg.num_heads * cfg.head_dim if cfg.head_dim else None
+            window = cfg.sliding_window if self.layer_type == "window_attention" else None
+            with jax.named_scope(ATTENTION_SCOPES[self.layer_type]):
+                op = MultiHeadAttention(
+                    num_heads=cfg.num_heads, num_q_input_channels=cfg.num_channels,
+                    num_kv_input_channels=cfg.num_channels, num_qk_channels=width,
+                    causal_attention=True, qkv_bias=False, out_bias=False,
+                    init_scale=cfg.init_scale, dtype=self.dtype,
+                    attention_impl=self.attention_impl, num_kv_heads=cfg.num_kv_heads,
+                    qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps, window=window, name="attention",
+                )(u, u, pad_mask=pad_mask, rot_pos_emb_q=rot, rot_pos_emb_k=rot)
         h = h + op
+        seen = u if cfg.router_input == "operator" else None  # what the router reads, if not its own
         u = RMSNorm(cfg.norm_eps, self.dtype, name="ffn_norm")(h)
         if self.dense:
             out = GatedMLP(
@@ -160,8 +210,9 @@ class DecoderLayer(nn.Module):
                 expert_offset=cfg.expert_offset, top_k=cfg.experts_per_token,
                 use_expert_bias=cfg.use_expert_bias, norm_topk_prob=cfg.norm_topk_prob,
                 routed_scaling_factor=cfg.routed_scaling_factor, init_scale=cfg.init_scale,
-                dtype=self.dtype, name="moe",
-            )(u)
+                dtype=self.dtype, router_score=cfg.router_score,
+                activation=cfg.expert_activation, name="moe",
+            )(u, seen)
             if cfg.num_shared_experts:
                 out = out + GatedMLP(
                     cfg.num_channels, cfg.num_shared_experts * cfg.expert_channels, cfg.init_scale,
@@ -257,13 +308,18 @@ class DecoderLM(nn.Module):
         shift = None if pad_mask is None else pad_mask.sum(axis=1, keepdims=True)
         pos = positions(*x.shape, shift=shift)
         # rotary tables by operator kind: over a whole head, or over a latent
-        # head's rotary channels
-        widths = {"full_attention": cfg.num_channels // cfg.num_heads,
-                  "latent_attention": cfg.qk_rope_head_dim}
-        rots = {
-            kind: RotaryEmbedding(frequency_position_encoding(pos, width, cfg.rope_theta))
-            for kind, width in widths.items() if kind in cfg.layer_types
-        }
+        # head's rotary channels; one table a width, none for a kind whose
+        # layers are not rotated
+        widths = {"latent_attention": cfg.qk_rope_head_dim}
+        if set(_HEAD_ATTENTION) & set(cfg.layer_types):
+            widths.update(dict.fromkeys(_HEAD_ATTENTION, cfg.attention_head_dim))
+        tables, rots = {}, {}
+        for kind, width in widths.items():
+            if kind in cfg.layer_types and kind in cfg.rotary_layer_types:
+                if width not in tables:
+                    tables[width] = RotaryEmbedding(
+                        frequency_position_encoding(pos, width, cfg.rope_theta))
+                rots[kind] = tables[width]
         h = self.embed(x).astype(self.dtype)
         stats = []
         for layer, kind in zip(self.layers, cfg.layer_types):

@@ -74,6 +74,7 @@ HELP_TEXT = {
     "compile_ledger_fallback_total": "Executors demoted from AOT ledger dispatch to plain jit.",
     "attention_einsum_fallback_total": "Traced attention shapes that impl='auto' on a TPU left to the einsum path because the flash kernel refused them.",
     "flash_backward_two_call_total": "Traced flash-attention backwards that run as two kernels (flash_bwd_dq beside flash_bwd_dkv) because the float32 dQ of one (batch, key-value head) is over the fused kernel's VMEM budget.",
+    "flash_window_call_total": "Traced flash-attention forward calls that carried a sliding window (the kernels' grids then walk the band alone); declared at the first flash call, so a program without window layers exports 0.",
     "hbm_bytes_in_use": "Live device memory from memory_stats() (absent on CPU).",
     "kv_cache_resident_bytes": "Live slot-KV bytes: allocated pages + latent-stack caches under the paged layout; equals capacity when dense.",
     "kv_cache_capacity_bytes": "Worst-case slot-KV bytes from the resolved layout's dtype: pool blocks (+ int8 dequant scales) when paged, dense per-slot caches at full context otherwise, + latent-stack caches.",

@@ -57,6 +57,7 @@ def dot_product_attention(
     dropout_rng: Optional[jax.Array] = None,
     max_heads_parallel: Optional[int] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Attention over pre-projected (and pre-scaled, pre-rotated) heads.
 
@@ -77,11 +78,18 @@ def dot_product_attention(
     :param max_heads_parallel: process at most this many heads at once
         (memory bound); ``None`` = all heads.
     :param impl: ``'auto' | 'xla' | 'flash'``.
+    :param window: with ``causal``, a sliding window: query ``t`` sees key
+        ``s`` iff ``0 <= t + (j - i) - s < window`` (its own position counts).
+        Both paths apply it; the kernels skip the block pairs outside the band.
     :return: ``(b, h, i, cv)``.
     """
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window} needs causal=True and at least one key")
     if impl == "ring":
         if dropout_rate > 0.0:
             raise ValueError("ring attention does not support attention dropout")
+        if window is not None:
+            raise ValueError("ring attention does not support a sliding window")
         mesh = _ambient_mesh()
         if mesh is None or "seq" not in mesh.axis_names or mesh.shape["seq"] == 1:
             # No seq-sharded mesh in scope (e.g. model.init outside the mesh
@@ -109,8 +117,8 @@ def dot_product_attention(
 
         if impl == "flash" and dropout_rate > 0.0:
             raise ValueError("flash attention does not support attention dropout")
-        if flash_attention.supported(q, k, v, causal=causal):
-            return _flash_over_mesh(q, k, v, pad_mask, causal)
+        if flash_attention.supported(q, k, v, causal=causal, window=window):
+            return _flash_over_mesh(q, k, v, pad_mask, causal, window)
         if impl == "flash":
             raise ValueError(
                 f"flash attention requested but unsupported for shapes q={q.shape} k={k.shape}"
@@ -120,9 +128,9 @@ def dot_product_attention(
 
     num_heads = q.shape[1]
     if k.shape[1] != num_heads:
-        return _attention_xla_grouped(q, k, v, pad_mask, causal, dropout_rate, dropout_rng)
+        return _attention_xla_grouped(q, k, v, pad_mask, causal, dropout_rate, dropout_rng, window)
     if max_heads_parallel is None or max_heads_parallel >= num_heads:
-        return _attention_xla(q, k, v, pad_mask, causal, dropout_rate, dropout_rng)
+        return _attention_xla(q, k, v, pad_mask, causal, dropout_rate, dropout_rng, window=window)
 
     chunks = []
     for h0 in range(0, num_heads, max_heads_parallel):
@@ -132,7 +140,8 @@ def dot_product_attention(
             dropout_rng, rng = jax.random.split(dropout_rng)
         chunks.append(
             _attention_xla(
-                q[:, h0:h1], k[:, h0:h1], v[:, h0:h1], pad_mask, causal, dropout_rate, rng
+                q[:, h0:h1], k[:, h0:h1], v[:, h0:h1], pad_mask, causal, dropout_rate, rng,
+                window=window,
             )
         )
     return jnp.concatenate(chunks, axis=1)
@@ -145,7 +154,7 @@ def _ambient_mesh():
     return None if mesh.empty else mesh
 
 
-def _flash_over_mesh(q, k, v, pad_mask, causal):
+def _flash_over_mesh(q, k, v, pad_mask, causal, window=None):
     """The flash kernel, inside ``shard_map`` when the ambient mesh has more
     than one device: batch over the ``data``/``fsdp`` axes, heads over
     ``model``. A dim its axes do not divide stays replicated (a batch-1
@@ -157,7 +166,7 @@ def _flash_over_mesh(q, k, v, pad_mask, causal):
 
     mesh = _ambient_mesh()
     if mesh is None or mesh.size == 1:
-        return flash_attention(q, k, v, pad_mask=pad_mask, causal=causal)
+        return flash_attention(q, k, v, pad_mask=pad_mask, causal=causal, window=window)
     if mesh.shape.get(AXIS_SEQ, 1) > 1:
         raise ValueError(
             "flash attention cannot run on a mesh whose 'seq' axis is sharded "
@@ -179,7 +188,7 @@ def _flash_over_mesh(q, k, v, pad_mask, causal):
         args, in_specs = args + (pad_mask,), in_specs + (P(batch_ax, None),)
 
     def body(q_, k_, v_, pad_=None):
-        return flash_attention(q_, k_, v_, pad_mask=pad_, causal=causal)
+        return flash_attention(q_, k_, v_, pad_mask=pad_, causal=causal, window=window)
 
     return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
@@ -219,6 +228,7 @@ def _attention_xla(
     dropout_rate: float,
     dropout_rng: Optional[jax.Array],
     causal_rows: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     i, j = q.shape[-2], k.shape[-2]
     logits = jnp.einsum("bhic,bhjc->bhij", q, k, preferred_element_type=jnp.float32)
@@ -227,12 +237,11 @@ def _attention_xla(
     if pad_mask is not None:
         logits = jnp.where(pad_mask[:, None, None, :], _mask_value(), logits)
     if causal:
-        allowed = jnp.arange(j)[None, :] <= jnp.arange(i)[:, None] + (j - i)
+        allowed = _causal_allowed(jnp.arange(i), j, j - i, window)
         logits = jnp.where(allowed[None, None], logits, _mask_value())
     elif causal_rows is not None:
         # grouped heads folded into the rows: row r is position r % causal_rows
-        pos = jnp.arange(i) % causal_rows
-        allowed = jnp.arange(j)[None, :] <= pos[:, None] + (j - causal_rows)
+        allowed = _causal_allowed(jnp.arange(i) % causal_rows, j, j - causal_rows, window)
         logits = jnp.where(allowed[None, None], logits, _mask_value())
 
     attn = jax.nn.softmax(logits, axis=-1)
@@ -243,7 +252,15 @@ def _attention_xla(
     return jnp.einsum("bhij,bhjc->bhic", attn, v)
 
 
-def _attention_xla_grouped(q, k, v, pad_mask, causal, dropout_rate, dropout_rng):
+def _causal_allowed(pos, j: int, offset: int, window: Optional[int]):
+    """``(rows, j)``: key ``s`` is allowed for the query at ``pos`` iff ``s <=
+    pos + offset`` and, under a window, ``s > pos + offset - window``."""
+    cols, last = jnp.arange(j)[None, :], pos[:, None] + offset
+    allowed = cols <= last
+    return allowed if window is None else allowed & (cols > last - window)
+
+
+def _attention_xla_grouped(q, k, v, pad_mask, causal, dropout_rate, dropout_rng, window=None):
     """The einsum path with fewer key-value heads than query heads: the
     query heads are viewed ``(b, hk, h // hk, i, c)`` and each group
     contracts with its one key-value head, so k and v keep ``hk`` heads."""
@@ -254,6 +271,6 @@ def _attention_xla_grouped(q, k, v, pad_mask, causal, dropout_rate, dropout_rng)
     # fold the group into the query rows: (b, hk, g * i, c) against (b, hk, j, c)
     o = _attention_xla(
         q.reshape(b, hk, (h // hk) * i, c), k, v, pad_mask, False, dropout_rate, dropout_rng,
-        causal_rows=i if causal else None,
+        causal_rows=i if causal else None, window=window,
     )
     return o.reshape(b, h, i, v.shape[-1])

@@ -19,6 +19,13 @@ Perceiver specifics the stock kernels don't cover:
 - **key padding masks** (``True`` = pad, reference ``modules.py:97``) for the
   left-padded batches the text models use. Kernels are statically
   specialized on pad presence, so the common unpadded call streams no mask.
+- **a sliding window** on a causal call (``window=W``): query ``t`` sees key
+  ``s`` iff ``0 <= t + offset - s < W``. The window is a second static bound
+  on the same mask, and the kernels' grids walk the band alone: the innermost
+  grid dimension counts the blocks a band can touch (``_Band``), an index map
+  adds the band's first block, and what little of that grid lies outside the
+  band is skipped with its block index clamped, so it fetches nothing. A call
+  without a window compiles to the kernels it always did.
 
 Layout notes (mirroring what Mosaic compiles well): grid is
 ``(b, h, i_blocks, j_blocks)`` with the kv dimension innermost and
@@ -49,6 +56,7 @@ scope, and the head split is the last thing before this kernel.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -80,6 +88,8 @@ _DQ_VMEM_BUDGET_BYTES = 2 * 1024 * 1024
 _FUSED_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 # backwards traced as two kernels because dQ is over the budget (docs/observability.md)
 _TWO_CALL_COUNTER = "flash_backward_two_call_total"
+# traced forward calls that carried a window (docs/observability.md)
+_WINDOW_COUNTER = "flash_window_call_total"
 # ``checkpoint_name`` of the forward's output and log-sum-exp under a gradient
 SAVED_NAMES = ("flash_out", "flash_lse")
 
@@ -115,9 +125,11 @@ def pallas_call_on_lowering_platform(kernel, *args, name: str, **spec):
     )
 
 
-def supported(q, k, v, *, causal: bool) -> bool:
+def supported(q, k, v, *, causal: bool, window: Optional[int] = None) -> bool:
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         return False
+    if window is not None and (not causal or window < 1):
+        return False  # the window is a bound on the causal mask
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     if q.dtype != k.dtype or q.dtype != v.dtype:
@@ -143,6 +155,7 @@ def flash_attention(
     *,
     pad_mask: Optional[jnp.ndarray] = None,
     causal: bool = False,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Flash attention with Perceiver masking semantics.
 
@@ -154,6 +167,10 @@ def flash_attention(
     :param v: ``(b, hk, j, dv)`` values.
     :param pad_mask: optional boolean ``(b, j)``, True marks padding.
     :param causal: right-aligned causal masking (offset ``j - i``).
+    :param window: with ``causal``, the keys a query sees: query ``t`` sees
+        key ``s`` iff ``0 <= t + (j - i) - s < window`` (its own position
+        counts). Block pairs outside that band are not computed and not
+        fetched, in the forward and in both backward kernels.
 
     Under a mesh with more than one device the caller wraps this in
     ``jax.shard_map`` (:func:`perceiver_io_tpu.ops.attention.dot_product_attention`
@@ -169,18 +186,27 @@ def flash_attention(
     """
     # (b, 1, j): the kernels block it as (1, 1, bj), whose second-to-last dim
     # equals the array's, so the TPU (8, 128) tiling rule holds at any batch.
+    from perceiver_io_tpu.observability import default_registry
+
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window} needs causal=True and at least one key")
+    # trace time, so once per traced call; declared here so that a program
+    # that calls the kernels without a window exports 0
+    default_registry().declare_counters(_WINDOW_COUNTER)
+    if window is not None:
+        default_registry().inc(_WINDOW_COUNTER)
     pad = None if pad_mask is None else pad_mask.astype(jnp.float32)[:, None, :]
-    return _flash(q, k, v, pad, causal)
+    return _flash(q, k, v, pad, causal, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _flash(q, k, v, pad, causal):
-    o, _ = _forward(q, k, v, pad, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash(q, k, v, pad, causal, window):
+    o, _ = _forward(q, k, v, pad, causal, window)
     return o
 
 
-def _flash_fwd(q, k, v, pad, causal):
-    o, lse = _forward(q, k, v, pad, causal)
+def _flash_fwd(q, k, v, pad, causal, window):
+    o, lse = _forward(q, k, v, pad, causal, window)
     # named so that a layer's recomputation can keep them (``SAVED_NAMES``):
     # its backward then hands these to ``_flash_bwd`` without a second forward
     o = checkpoint_name(o, SAVED_NAMES[0])
@@ -188,7 +214,7 @@ def _flash_fwd(q, k, v, pad, causal):
     return o, (q, k, v, pad, o, lse)
 
 
-def _flash_bwd(causal, res, do):
+def _flash_bwd(causal, window, res, do):
     from perceiver_io_tpu.observability import default_registry
 
     q, k, v, pad, o, lse = res
@@ -196,12 +222,12 @@ def _flash_bwd(causal, res, do):
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, LANES))
     default_registry().declare_counters(_TWO_CALL_COUNTER)
     if _dq_fits_vmem(q, k):
-        dk, dv, dq = _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq=True)
+        dk, dv, dq = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window, with_dq=True)
     else:
         # trace time, so once per traced backward; correct, only slower: no warning
         default_registry().inc(_TWO_CALL_COUNTER)
-        dq = _backward_dq(q, k, v, pad, lse, delta, do, causal)
-        dk, dv = _backward_dkv(q, k, v, pad, lse, delta, do, causal)
+        dq = _backward_dq(q, k, v, pad, lse, delta, do, causal, window)
+        dk, dv = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window)
     dpad = None if pad is None else jnp.zeros_like(pad)
     return dq, dk, dv, dpad
 
@@ -216,7 +242,8 @@ def _dq_fits_vmem(q, k) -> bool:
     return group * i * max(d, LANES) * 4 <= _DQ_VMEM_BUDGET_BYTES
 
 
-def _block_mask(i_idx, j_idx, bi: int, bj: int, offset: int, causal: bool, pad_blk):
+def _block_mask(i_idx, j_idx, bi: int, bj: int, offset: int, causal: bool, pad_blk,
+                window: Optional[int] = None):
     """Boolean (bi, bj) "allowed" mask for the current block pair, or None
     when the block is unconstrained."""
     allowed = None
@@ -226,15 +253,73 @@ def _block_mask(i_idx, j_idx, bi: int, bj: int, offset: int, causal: bool, pad_b
         rows = jax.lax.broadcasted_iota(jnp.int32, (bi, bj), 0) + i_idx * bi
         cols = jax.lax.broadcasted_iota(jnp.int32, (bi, bj), 1) + j_idx * bj
         cm = cols <= rows + offset
+        if window is not None:
+            cm = jnp.logical_and(cm, cols > rows + (offset - window))
         allowed = cm if allowed is None else jnp.logical_and(allowed, cm)
     return allowed
 
 
-def _run_block(i_idx, j_idx, bi: int, bj: int, offset: int, causal: bool):
+def _run_block(i_idx, j_idx, bi: int, bj: int, offset: int, causal: bool,
+               window: Optional[int] = None):
     """Whether this (i, j) block intersects the allowed region."""
     if not causal:
         return None  # statically always
-    return j_idx * bj <= i_idx * bi + (bi - 1) + offset
+    run = j_idx * bj <= i_idx * bi + (bi - 1) + offset
+    if window is not None:  # its last key is inside the first row's window
+        run = jnp.logical_and(run, j_idx * bj + (bj - 1) > i_idx * bi + (offset - window))
+    return run
+
+
+def _floor0(x):
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Band:
+    """The block pairs a windowed causal call computes: q block ``i`` (rows
+    ``i * bi`` on) meets kv blocks ``first_j(i) .. last_j(i)``, kv block ``j``
+    q blocks ``first_i(j) .. last_i(j)`` (which may lie past the last q block,
+    or before the first where no query sees a key of the block). The kernels'
+    innermost grid dimension counts ``kv_blocks`` (``q_blocks``), the most a
+    block of the other side meets; block indices take plain ints (the counts)
+    and the kernels' scalars alike."""
+
+    bi: int
+    bj: int
+    ni: int
+    nj: int
+    offset: int
+    window: int
+
+    def first_j(self, i_idx):
+        return _floor0(i_idx * self.bi + (self.offset - self.window + 1)) // self.bj
+
+    def last_j(self, i_idx):
+        return (i_idx * self.bi + (self.bi - 1 + self.offset)) // self.bj
+
+    def first_i(self, j_idx):
+        return _floor0(j_idx * self.bj - self.offset) // self.bi
+
+    def last_i(self, j_idx):
+        return (j_idx * self.bj + (self.bj - 1 - self.offset + self.window - 1)) // self.bi
+
+    @property
+    def kv_blocks(self) -> int:
+        return max(self.last_j(i) - self.first_j(i) + 1 for i in range(self.ni))
+
+    @property
+    def q_blocks(self) -> int:
+        return max(1, max(min(self.last_i(j), self.ni - 1) - self.first_i(j) + 1
+                          for j in range(self.nj)))
+
+    def kv_block(self, i_idx, step):
+        """The kv block of grid step ``step`` of q block ``i_idx``, held at the
+        band's last one past it (a block index that does not move fetches
+        nothing)."""
+        return jnp.minimum(self.first_j(i_idx) + step, self.last_j(i_idx))
+
+    def q_block(self, j_idx, step):
+        return jnp.minimum(self.first_i(j_idx) + step, self.ni - 1)
 
 
 def _maybe_when(run, body):
@@ -272,6 +357,28 @@ def _pad_spec(bj, by_dim2=False):
     return pl.BlockSpec((1, 1, bj), lambda b_, h_, x_, y_: (b_, 0, y_))
 
 
+def _q_grid_specs(bi, bj, d, dv, group: int, has_pad: bool, band: Optional[_Band]) -> list:
+    """Blocks of q, k, v (and the pad mask) under a grid whose dim 2 walks the
+    q blocks: dim 3 walks every kv block or, under a window, counts the kv
+    blocks of each q block's band."""
+    if band is None:
+        specs = [
+            _qk_spec(bi, d, by_dim2=True),
+            _qk_spec(bj, d, by_dim2=False, group=group),
+            _qk_spec(bj, dv, by_dim2=False, group=group),
+        ]
+        return specs + [_pad_spec(bj)] if has_pad else specs
+    specs = [_qk_spec(bi, d, by_dim2=True)] + [
+        pl.BlockSpec((1, 1, bj, w),
+                     lambda b_, h_, x_, y_: (b_, h_ // group, band.kv_block(x_, y_), 0))
+        for w in (d, dv)
+    ]
+    if has_pad:
+        specs.append(pl.BlockSpec(
+            (1, 1, bj), lambda b_, h_, x_, y_: (b_, 0, band.kv_block(x_, y_))))
+    return specs
+
+
 _DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
 )
@@ -282,7 +389,7 @@ _DIM_SEMANTICS_RESIDENT_DQ = pltpu.CompilerParams(
 )
 
 
-def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, h, i, d = q.shape
     j, dv = k.shape[2], v.shape[3]
     group = h // k.shape[1]
@@ -290,6 +397,8 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
     offset = j - i
     nj = j // bj
     has_pad = pad is not None
+    band = None if window is None else _Band(bi, bj, i // bi, nj, offset, window)
+    steps = nj if band is None else band.kv_blocks  # grid dim 3
 
     def kernel(q_ref, k_ref, v_ref, *rest):
         if has_pad:
@@ -297,9 +406,10 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
         else:
             o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
             pad_ref = None
-        i_idx, j_idx = pl.program_id(2), pl.program_id(3)
+        i_idx, step = pl.program_id(2), pl.program_id(3)
+        j_idx = step if band is None else band.first_j(i_idx) + step
 
-        @pl.when(j_idx == 0)
+        @pl.when(step == 0)
         def _():
             m_sc[:] = jnp.full_like(m_sc, -jnp.inf)
             l_sc[:] = jnp.zeros_like(l_sc)
@@ -312,7 +422,7 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
             )
             allowed = _block_mask(
                 i_idx, j_idx, bi, bj, offset, causal,
-                pad_ref[0] if has_pad else None,
+                pad_ref[0] if has_pad else None, window,
             )
             if allowed is not None:
                 s = jnp.where(allowed, s, _MASK)
@@ -331,9 +441,9 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
             m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
             l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
 
-        _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal), body)
+        _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal, window), body)
 
-        @pl.when(j_idx == nj - 1)
+        @pl.when(step == steps - 1)
         def _():
             l = l_sc[:, :1]
             safe_l = jnp.where(l > 0.0, l, 1.0)
@@ -342,21 +452,14 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
                 m_sc[:, :1] + jnp.log(safe_l), lse_ref.shape[2:]
             )
 
-    in_specs = [
-        _qk_spec(bi, d, by_dim2=True),
-        _qk_spec(bj, d, by_dim2=False, group=group),
-        _qk_spec(bj, dv, by_dim2=False, group=group),
-    ]
-    args = [q, k, v]
-    if has_pad:
-        in_specs.append(_pad_spec(bj))
-        args.append(pad)
+    args = [q, k, v] + ([pad] if has_pad else [])
+    in_specs = _q_grid_specs(bi, bj, d, dv, group, has_pad, band)
 
     out = pallas_call_on_lowering_platform(
         kernel,
         *args,
         name="flash_fwd",
-        grid=(b, h, i // bi, nj),
+        grid=(b, h, i // bi, steps),
         in_specs=in_specs,
         out_specs=[
             _qk_spec(bi, dv, by_dim2=True),
@@ -376,7 +479,7 @@ def _forward(q, k, v, pad, causal) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return out[0], out[1]
 
 
-def _backward_dq(q, k, v, pad, lse, delta, do, causal):
+def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
     b, h, i, d = q.shape
     j, dv = k.shape[2], v.shape[3]
     group = h // k.shape[1]
@@ -384,6 +487,8 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
     offset = j - i
     nj = j // bj
     has_pad = pad is not None
+    band = None if window is None else _Band(bi, bj, i // bi, nj, offset, window)
+    steps = nj if band is None else band.kv_blocks  # grid dim 3
 
     def kernel(q_ref, k_ref, v_ref, *rest):
         if has_pad:
@@ -391,9 +496,10 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
         else:
             lse_ref, delta_ref, do_ref, dq_ref, dq_sc = rest
             pad_ref = None
-        i_idx, j_idx = pl.program_id(2), pl.program_id(3)
+        i_idx, step = pl.program_id(2), pl.program_id(3)
+        j_idx = step if band is None else band.first_j(i_idx) + step
 
-        @pl.when(j_idx == 0)
+        @pl.when(step == 0)
         def _():
             dq_sc[:] = jnp.zeros_like(dq_sc)
 
@@ -405,7 +511,7 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
             )
             allowed = _block_mask(
                 i_idx, j_idx, bi, bj, offset, causal,
-                pad_ref[0] if has_pad else None,
+                pad_ref[0] if has_pad else None, window,
             )
             p = jnp.exp(s - lse_ref[0, 0][:, :1])
             if allowed is not None:
@@ -420,21 +526,14 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
                 preferred_element_type=jnp.float32,
             )
 
-        _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal), body)
+        _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal, window), body)
 
-        @pl.when(j_idx == nj - 1)
+        @pl.when(step == steps - 1)
         def _():
             dq_ref[0, 0] = dq_sc[:].astype(dq_ref.dtype)
 
-    in_specs = [
-        _qk_spec(bi, d, by_dim2=True),
-        _qk_spec(bj, d, by_dim2=False, group=group),
-        _qk_spec(bj, dv, by_dim2=False, group=group),
-    ]
-    args = [q, k, v]
-    if has_pad:
-        in_specs.append(_pad_spec(bj))
-        args.append(pad)
+    args = [q, k, v] + ([pad] if has_pad else [])
+    in_specs = _q_grid_specs(bi, bj, d, dv, group, has_pad, band)
     in_specs += [
         _qk_spec(bi, LANES, by_dim2=True),
         _qk_spec(bi, LANES, by_dim2=True),
@@ -446,7 +545,7 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
         kernel,
         *args,
         name="flash_bwd_dq",
-        grid=(b, h, i // bi, nj),
+        grid=(b, h, i // bi, steps),
         in_specs=in_specs,
         out_specs=_qk_spec(bi, d, by_dim2=True),
         out_shape=jax.ShapeDtypeStruct((b, h, i, d), q.dtype),
@@ -455,7 +554,7 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal):
     )
 
 
-def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
+def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, with_dq: bool = False):
     """dK and dV, and with ``with_dq`` dQ as a third output of the same
     kernel (the caller has checked :func:`_dq_fits_vmem`)."""
     b, h, i, d = q.shape
@@ -465,6 +564,9 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
     offset = j - i
     ni, nj = i // bi, j // bj
     has_pad = pad is not None
+    band = None if window is None else _Band(bi, bj, ni, nj, offset, window)
+    # q blocks a query head walks in grid dim 3: all, or those of a kv block's band
+    nq = ni if band is None else band.q_blocks
 
     # Grid dim 1 walks the key-value heads, dim 2 kv blocks, dim 3 the q
     # blocks of every query head that shares the key-value head (innermost,
@@ -474,7 +576,11 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
     # dims 2 and 3: each kv block adds its part in ascending order, as
     # ``_backward_dq`` sums them, and the last rounds the rows once into the
     # output block, which is resident as long and written back when
-    # ``(b, hk)`` moves on.
+    # ``(b, hk)`` moves on. Under a window grid dim 3 walks, for each query
+    # head, the ``nq`` q blocks from the kv block's first (``inside`` says the
+    # step's q block exists; past the last one it is held there and skipped),
+    # and a q block's rows of dQ are zeroed by the first kv block of its band
+    # and rounded by the last.
     def kernel(q_ref, k_ref, v_ref, *rest):
         pad_ref = None
         if has_pad:
@@ -484,9 +590,15 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
         else:
             lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
         j_idx, t_idx = pl.program_id(2), pl.program_id(3)
-        i_idx = t_idx if group == 1 else t_idx % ni
+        if band is None:
+            i_idx = t_idx if group == 1 else t_idx % ni
+            row_block, inside = t_idx, None
+        else:
+            i_idx = band.first_i(j_idx) + t_idx % nq
+            inside = i_idx < ni
+            row_block = t_idx // nq * ni + jnp.minimum(i_idx, ni - 1)
         if with_dq:
-            dq_rows = pl.ds(pl.multiple_of(t_idx * bi, bi), bi)
+            dq_rows = pl.ds(pl.multiple_of(row_block * bi, bi), bi)
 
         @pl.when(t_idx == 0)
         def _():
@@ -494,7 +606,9 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
             dv_sc[:] = jnp.zeros_like(dv_sc)
 
         if with_dq:
-            @pl.when(j_idx == 0)
+            dq_first = j_idx == 0 if band is None else inside & (j_idx == band.first_j(i_idx))
+
+            @pl.when(dq_first)
             def _():
                 dq_sc[dq_rows, :] = jnp.zeros((bi, d), jnp.float32)
 
@@ -506,7 +620,7 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
             )
             allowed = _block_mask(
                 i_idx, j_idx, bi, bj, offset, causal,
-                pad_ref[0] if has_pad else None,
+                pad_ref[0] if has_pad else None, window,
             )
             p = jnp.exp(s - lse_ref[0, 0][:, :1])
             if allowed is not None:
@@ -530,19 +644,26 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
                     preferred_element_type=jnp.float32,
                 )
 
-        _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal), body)
+        run = _run_block(i_idx, j_idx, bi, bj, offset, causal, window)
+        _maybe_when(run if band is None else inside & run, body)
 
-        @pl.when(t_idx == group * ni - 1)
+        @pl.when(t_idx == group * nq - 1)
         def _():
             dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
 
         if with_dq:
-            @pl.when(j_idx == nj - 1)
+            dq_last = j_idx == nj - 1 if band is None else inside & (j_idx == band.last_j(i_idx))
+
+            @pl.when(dq_last)
             def _():
                 dq_ref[0, 0, dq_rows, :] = dq_sc[dq_rows, :].astype(dq_ref.dtype)
 
-    if group == 1:
+    if band is not None:
+        q_side = lambda width: pl.BlockSpec(
+            (1, 1, bi, width),
+            lambda b_, h_, x_, y_: (b_, h_ * group + y_ // nq, band.q_block(x_, y_ % nq), 0))
+    elif group == 1:
         q_side = lambda width: _qk_spec(bi, width, by_dim2=False)  # q blocks walk grid dim 3
     else:
         q_side = lambda width: _group_q_spec(bi, width, group, ni)
@@ -581,7 +702,7 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, with_dq: bool = False):
         kernel,
         *args,
         name="flash_bwd_dkv",
-        grid=(b, hk, nj, group * ni),
+        grid=(b, hk, nj, group * nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,

@@ -40,6 +40,9 @@ from perceiver_io_tpu.parallel.mesh import (
 # Column-parallel (output dim): q/k/v projections, MLP up-projection.
 # Row-parallel (input dim): attention output projection, MLP down-projection.
 _TP_KERNEL_RULES: Tuple[Tuple[str, int], ...] = (
+    # by head, whatever a head's width (the lm family's ``head_dim`` makes the
+    # heads' channels another number than the inputs': q 2560 x 3584, o 3584 x
+    # 2560) and whatever the layer's mask (full or a sliding window)
     (r"(q_proj|k_proj|v_proj)/kernel$", 1),
     (r"o_proj/kernel$", 0),
     (r"mlp/hidden/kernel$", 1),

@@ -1,6 +1,7 @@
 """Decoder-only language model CLI: layers declared one by one (gated short
-convolutions, grouped-query attention, latent attention, sparse experts with
-shared ones beside them, a multi-token-prediction module; docs/lm.md):
+convolutions, grouped-query attention over every earlier position or a
+sliding window, latent attention, sparse experts with shared ones beside
+them, a multi-token-prediction module; docs/lm.md):
 
     python -m perceiver_io_tpu.scripts.text.lm fit --data=wikitext \
         --data.dataset_dir=.cache/wikitext --trainer.max_steps=10000 \

@@ -103,7 +103,7 @@ def test_auto_dispatch_depends_on_dropout_and_backend_alone(rng, monkeypatch):
     calls = []
     monkeypatch.setattr(
         attention, "_flash_over_mesh",
-        lambda *a: calls.append(a[0].shape) or attention._attention_xla(*a, 0.0, None),
+        lambda *a: calls.append(a[0].shape) or attention._attention_xla(*a[:5], 0.0, None, window=a[5]),
     )
     q, k, v = _qkv(rng, 1, 2, 128, 256, 64)
     dot_product_attention(q, k, v, causal=True, impl="auto")
@@ -332,3 +332,139 @@ def test_forward_runs_per_step_reader_counts_the_forward_kernels_events_alone(mo
     assert read(ctx(Trace([device("/device:TPU:0"), device("/device:TPU:1")], [], 0.0))) == 6.0
     assert read(ctx(None)) is None
     assert read(ctx(Trace([], [], 0.0))) is None  # the CPU has no device plane
+
+
+# -- sliding window ----------------------------------------------------------
+def _band_from_positions(i, j, window):
+    """Key ``s`` is allowed for query ``t`` iff ``0 <= t + (j - i) - s < window``."""
+    back = np.arange(i)[:, None] + (j - i) - np.arange(j)[None, :]
+    return (back >= 0) & (back < window)
+
+
+def _masked_reference(q, k, v, allowed, pad=None):
+    """Softmax attention under an explicit ``(i, j)`` mask, grouped heads by
+    repeating the key-value heads: nothing of the paths under test."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    allowed = jnp.asarray(allowed)[None, None]
+    if pad is not None:
+        allowed = allowed & ~pad[:, None, None, :]
+    s = jnp.where(allowed, jnp.einsum("bhid,bhjd->bhij", q, k), -1e30)
+    return jnp.einsum("bhij,bhjd->bhid", jax.nn.softmax(s, axis=-1), v)
+
+
+WINDOW_CASES = {
+    # (h, hk, i, j, window, padded keys): blocks are 128 wide unless a dim is 256 or 512
+    "smaller_than_a_block": (2, 2, 256, 256, 40, 0),
+    "equal_to_a_block": (2, 2, 384, 384, 128, 0),
+    "larger_than_a_block": (2, 2, 384, 384, 200, 0),
+    "larger_than_the_row": (2, 2, 256, 256, 1000, 0),
+    "right_aligned_unequal": (2, 1, 128, 384, 50, 0),  # keys that no query sees
+    "right_aligned_two_blocks": (2, 2, 256, 640, 300, 0),
+    "with_padding": (4, 2, 256, 256, 130, 5),
+    "group_of_7": (14, 2, 384, 384, 129, 0),
+    "one_key": (2, 2, 256, 256, 1, 0),
+}
+
+
+@pytest.mark.parametrize("backward", ["one_kernel", "two_kernels"])
+@pytest.mark.parametrize("case", sorted(WINDOW_CASES))
+def test_window_forward_and_both_backward_forms_match_einsum_and_a_mask_from_positions(
+        rng, monkeypatch, case, backward):
+    """The windowed kernels (forward, the fused backward and the two-kernel
+    backward, each on a grid over the band) against the einsum path with the
+    same window and against a mask built from positions."""
+    h, hk, i, j, window, padded = WINDOW_CASES[case]
+    d = 32
+    q = jnp.asarray(rng.standard_normal((2, h, i, d)), jnp.float32) * d**-0.5
+    k = jnp.asarray(rng.standard_normal((2, hk, j, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, hk, j, d)), jnp.float32)
+    cot = jnp.asarray(rng.standard_normal((2, h, i, d)), jnp.float32)
+    pad = None
+    if padded:
+        # left padding; a query whose whole window is padding is a dead row
+        # (zero here, uniform on the einsum path): left out of the comparison
+        pad = jnp.zeros((2, j), bool).at[:, :padded].set(True)
+        cot = cot.at[:, :, :max(0, padded - (j - i))].set(0.0)
+    monkeypatch.setattr(
+        flash_attention, "_DQ_VMEM_BUDGET_BYTES", 1 << 40 if backward == "one_kernel" else 0)
+    paths = {
+        "flash": lambda q, k, v: flash_attention.flash_attention(
+            q, k, v, pad_mask=pad, causal=True, window=window),
+        "einsum": lambda q, k, v: dot_product_attention(
+            q, k, v, pad_mask=pad, causal=True, window=window, impl="xla"),
+        "mask": lambda q, k, v: _masked_reference(q, k, v, _band_from_positions(i, j, window), pad),
+    }
+    found = {name: jax.value_and_grad(lambda q, k, v: jnp.sum(fn(q, k, v) * cot), (0, 1, 2))(q, k, v)
+             for name, fn in paths.items()}
+    for name in ("flash", "einsum"):
+        np.testing.assert_allclose(found[name][0], found["mask"][0], rtol=1e-4, err_msg=name)
+        for a, b, leaf in zip(found[name][1], found["mask"][1], "qkv"):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=f"{name} d{leaf}")
+
+
+def test_a_window_that_holds_every_key_is_the_causal_call(rng):
+    q, k, v = _qkv(rng, 1, 2, 256, 384, 32)
+    wide = flash_attention.flash_attention(q, k, v, causal=True, window=384)
+    np.testing.assert_allclose(wide, flash_attention.flash_attention(q, k, v, causal=True), atol=1e-6)
+
+
+@pytest.mark.parametrize("i,j,window,kv_blocks,q_blocks", [
+    (2048, 2048, 512, 2, 2),    # blocks of 512: a q block's band touches its own kv block and the one before
+    (2048, 2048, 513, 2, 2),  # its first key is the first of the block before
+    (2048, 2048, 514, 3, 3),
+    (16384, 16384, 4096, 9, 9),  # the cell's: 288 grid steps a head where the full grid has 1,024
+    (1024, 4608, 700, 3, 2),    # right-aligned: blocks 512 over 512
+    (256, 256, 1, 1, 1),
+])
+def test_the_band_grid_counts_the_blocks_a_band_touches(i, j, window, kv_blocks, q_blocks):
+    """``_Band``: every block pair that holds an allowed query-key pair lies in
+    the walked grid, and the grid's inner dimension is the most any block of
+    the other side meets."""
+    bi, bj = flash_attention._pick_block(i), flash_attention._pick_block(j)
+    band = flash_attention._Band(bi, bj, i // bi, j // bj, j - i, window)
+    assert (band.kv_blocks, band.q_blocks) == (kv_blocks, q_blocks)
+    rows, cols = np.arange(i)[:, None] + (j - i), np.arange(j)[None, :]
+    allowed = (cols <= rows) & (cols > rows - window)
+    pairs = allowed.reshape(i // bi, bi, j // bj, bj).any(axis=(1, 3))
+    for a in range(i // bi):
+        meets = np.flatnonzero(pairs[a])
+        assert (band.first_j(a), band.last_j(a)) == (meets[0], meets[-1])
+    for b in range(j // bj):
+        meets = np.flatnonzero(pairs[:, b])
+        if len(meets):
+            assert band.first_i(b) == meets[0] and min(band.last_i(b), i // bi - 1) == meets[-1]
+        else:
+            assert band.last_i(b) < band.first_i(b) or band.last_i(b) < 0
+
+
+def test_window_needs_causal_and_is_refused_elsewhere(rng):
+    q, k, v = _qkv(rng, 1, 2, 128, 128, 32)
+    assert flash_attention.supported(q, k, v, causal=True, window=16)
+    assert not flash_attention.supported(q, k, v, causal=False, window=16)
+    assert not flash_attention.supported(q, k, v, causal=True, window=0)
+    for call in (flash_attention.flash_attention, dot_product_attention):
+        with pytest.raises(ValueError, match="window"):
+            call(q, k, v, causal=False, window=16)
+    with pytest.raises(ValueError, match="sliding window"):
+        dot_product_attention(q, k, v, causal=True, window=16, impl="ring")
+
+
+def test_window_counter_counts_traced_window_calls_and_is_declared_by_any_call(monkeypatch):
+    import perceiver_io_tpu.observability as observability
+    from perceiver_io_tpu.observability import MetricsRegistry
+
+    registry = MetricsRegistry()
+    monkeypatch.setattr(observability, "default_registry", lambda: registry)
+    q = jnp.zeros((1, 2, 128, 32))
+    assert "flash_window_call_total" not in registry.counters()
+    jax.eval_shape(lambda: flash_attention.flash_attention(q, q, q, causal=True))
+    assert registry.counters()["flash_window_call_total"] == 0.0
+    jax.eval_shape(lambda: dot_product_attention(q, q, q, causal=True, window=64, impl="flash"))
+    assert registry.counters()["flash_window_call_total"] == 1.0
+    # the einsum path carries the window too, and is no kernel call
+    jax.eval_shape(lambda: dot_product_attention(q, q, q, causal=True, window=64, impl="xla"))
+    assert registry.counters()["flash_window_call_total"] == 1.0
+    from perceiver_io_tpu.observability.exporters import HELP_TEXT
+
+    assert "flash_window_call_total" in HELP_TEXT

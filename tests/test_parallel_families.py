@@ -272,3 +272,56 @@ def test_lm_family_train_step_on_a_data_by_fsdp_mesh_matches_one_device():
         losses[name] = out
     assert losses["one"][0][1] == 2 * 8 * 16 * 2 * 8 / 16 or losses["one"][0][1] > 0
     np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=2e-4)
+
+
+def _lm_window_model():
+    from perceiver_io_tpu.models.text.lm import DecoderLM, DecoderLMConfig
+
+    cfg = DecoderLMConfig(
+        vocab_size=64, max_seq_len=64, num_channels=40, num_heads=14, num_kv_heads=2, head_dim=8,
+        qk_norm=False, layer_types=("full_attention", "window_attention"), sliding_window=6,
+        rotary_layer_types=("window_attention",), num_dense_layers=0, expert_channels=24,
+        router_width=16, num_experts=8, experts_per_token=3, use_expert_bias=False,
+        router_score="softmax_topk", expert_activation="relu", router_input="operator",
+        tie_word_embeddings=False,
+    )
+    return DecoderLM(cfg, attention_impl="xla")
+
+
+@pytest.mark.parametrize("axes", [dict(data=2, model=2, fsdp=2), dict(data=4, model=2)],
+                         ids=["data2xmodel2xfsdp2", "data4xmodel2"])
+def test_lm_window_model_shards_its_own_head_width_and_matches_one_device(axes):
+    """Window and global layers with 14 query heads on 2 of 8 channels over 40
+    inputs, a router on the attention's input: ``q_proj`` (40 x 112) and
+    ``k_proj`` (40 x 16) split by column over ``model``, ``o_proj`` (112 x 40) by
+    row, the untied head and the router's second input follow the rules that
+    were there; two steps on the mesh are the steps on one device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from perceiver_io_tpu.training.tasks import lm_loss_fn
+
+    model = _lm_window_model()
+    init = lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))["params"]
+    shapes = jax.eval_shape(init)
+    mesh = make_mesh(MeshConfig(**axes))
+    specs = infer_param_specs(shapes, mesh, min_fsdp_size=0)
+    attn = specs["layers_1"]["attention"]
+    assert attn["q_proj"]["kernel"][1] == "model" and attn["k_proj"]["kernel"][1] == "model"
+    assert attn["o_proj"]["kernel"][0] == "model"
+    for path, spec in jax.tree_util.tree_leaves_with_path(specs, is_leaf=lambda s: isinstance(s, P)):
+        NamedSharding(mesh, spec).shard_shape(dict(jax.tree_util.tree_leaves_with_path(shapes))[path].shape)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 64, size=(8, 17)).astype(np.int32)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:], "pad_mask": np.zeros((8, 16), bool)}
+    losses = {}
+    for name, mesh_axes in (("one", dict(data=1)), ("mesh", axes)):
+        devices = jax.devices()[:1] if name == "one" else None
+        mesh = make_mesh(MeshConfig(**mesh_axes), devices=devices)
+        state, shardings = create_train_state(init, optax.adamw(1e-2), mesh, min_fsdp_size=0)
+        step = make_train_step(lm_loss_fn(model), mesh, shardings)
+        out = []
+        for i in range(2):
+            state, metrics = step(state, shard_batch(batch, mesh), jax.random.PRNGKey(i))
+            out.append((float(metrics["loss"]), float(metrics["moe_assignments_held"])))
+        losses[name] = out
+    np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=2e-4)

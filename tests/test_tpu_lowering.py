@@ -432,3 +432,97 @@ def test_expert_layer_at_the_cells_shapes_lowers_with_a_bounded_and_a_worst_case
 
     assert forward_and_backward_on(rows) == {(k, b * n, c)}
     assert {(pairs, c), (pairs, f)} <= forward_and_backward_on(pairs)
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_dkv_with_dq"])
+@pytest.mark.parametrize("pad", [False, True], ids=["nopad", "pad_b2"])
+def test_each_windowed_flash_kernel_lowers_through_mosaic_on_a_grid_over_the_band(kernel, pad):
+    """The three kernels (and the fused backward) under a window: Mosaic takes
+    the band's index maps (a first block from the q block, a clamp), the
+    kernel keeps its name, and the grid's inner dimension counts the band's
+    blocks (``_Band``)."""
+    b, h, hk, n, d, window = 2, 4, 2, 2048, 64, 200
+    q = do = jax.ShapeDtypeStruct((b, h, n, d), jnp.bfloat16)
+    k = v = jax.ShapeDtypeStruct((b, hk, n, d), jnp.bfloat16)
+    lse = delta = jax.ShapeDtypeStruct((b, h, n, flash_attention.LANES), jnp.float32)
+    pad_mask = jax.ShapeDtypeStruct((b, 1, n), jnp.float32) if pad else None
+    call = {
+        "flash_fwd": lambda q, k, v, pad_mask, lse, delta, do: flash_attention._forward(
+            q, k, v, pad_mask, True, window),
+        "flash_bwd_dq": lambda q, k, v, pad_mask, lse, delta, do: flash_attention._backward_dq(
+            q, k, v, pad_mask, lse, delta, do, True, window),
+        "flash_bwd_dkv": lambda q, k, v, pad_mask, lse, delta, do: flash_attention._backward_dkv(
+            q, k, v, pad_mask, lse, delta, do, True, window),
+        "flash_bwd_dkv_with_dq": lambda q, k, v, pad_mask, lse, delta, do: flash_attention._backward_dkv(
+            q, k, v, pad_mask, lse, delta, do, True, window, with_dq=True),
+    }[kernel]
+    args = (q, k, v, pad_mask, lse, delta, do)
+    name = kernel.replace("_with_dq", "")
+    assert _kernel_names(call, *args) == ([name], {name})
+    text = jax.jit(call).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    # 2048 rows in blocks of 512: a band of 200 keys meets 2 of a row's 4 blocks
+    bi = flash_attention._pick_block(n)
+    band = flash_attention._Band(bi, bi, n // bi, n // bi, 0, window)
+    assert (band.kv_blocks, band.q_blocks) == (2, 2)
+    assert jax.jit(call).trace(*args).lower(lowering_platforms=("cpu",)).as_text().count(
+        "tpu_custom_call") == 0
+    assert text.count("tpu_custom_call") >= 1
+
+
+def test_window_attention_at_the_cells_shapes_lowers_on_the_band_with_a_two_kernel_backward():
+    """``smallthinker-train-16k``'s window layers: 28 query heads on 4 of 128,
+    16,384 x 16,384 causal under a window of 4,096, bfloat16, through the
+    module's own path. A group's float32 dQ is 56 MiB against the fused
+    kernel's 2 MiB, so the backward is two kernels; each kernel's grid walks
+    9 blocks a band where the row has 32; no score matrix, no key or value at
+    the query heads' count, no einsum fallback."""
+    from perceiver_io_tpu.models.core.modules import MultiHeadAttention
+    from perceiver_io_tpu.ops.position import RotaryEmbedding
+
+    b, n, c, h, hk, d, window = 1, 16384, 2560, 28, 4, 128, 4096
+    q = jax.ShapeDtypeStruct((b, h, n, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, hk, n, d), jnp.bfloat16)
+    assert flash_attention.supported(q, k, k, causal=True, window=window)
+    assert not flash_attention._dq_fits_vmem(q, k)
+    band = flash_attention._Band(512, 512, 32, 32, 0, window)
+    assert (band.kv_blocks, band.q_blocks) == (9, 9)
+    op = MultiHeadAttention(
+        num_heads=h, num_q_input_channels=c, num_kv_input_channels=c, num_qk_channels=h * d,
+        causal_attention=True, qkv_bias=False, out_bias=False, dtype=jnp.bfloat16,
+        attention_impl="flash", num_kv_heads=hk, window=window)
+    u = jax.ShapeDtypeStruct((b, n, c), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((b, n, d), jnp.float32)
+    rot = jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(RotaryEmbedding(jnp.zeros((1, 2, 2)))), (tables, tables))
+    x = jnp.zeros((1, 128, c), jnp.bfloat16)
+    params = jax.eval_shape(lambda: op.init(jax.random.PRNGKey(0), x, x))
+    loss = lambda p, u, rot: jnp.sum(
+        op.apply(p, u, u, rot_pos_emb_q=rot, rot_pos_emb_k=rot).astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, u, rot).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert f"tensor<{b}x{h}x{n}x{d}xbf16>" in text and f"tensor<{b}x{hk}x{n}x{d}xbf16>" in text
+    assert f"x{n}x{n}x" not in text and f"tensor<{b}x{hk}x{h // hk}x{n}x{d}" not in text
+    assert params["params"]["q_proj"]["kernel"].shape == (c, h * d)
+    assert params["params"]["k_proj"]["kernel"].shape == (c, hk * d)
+    assert params["params"]["o_proj"]["kernel"].shape == (h * d, c)
+
+
+def test_windowed_flash_call_lowers_under_a_mesh_with_heads_over_model(devices):
+    """A window call through ``dot_product_attention`` on ``data=2 x model=2``:
+    the call shard_maps itself (batch over ``data``, the 2 key-value heads and
+    their 14 query heads over ``model``) and every shard's kernels carry the
+    window: forward and a one-kernel backward (7 heads x 256 rows of dQ fit)."""
+    mesh = make_mesh(MeshConfig(data=2, model=2), devices=devices[:4])
+    q = jax.ShapeDtypeStruct((2, 14, 256, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 2, 256, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            o = dot_product_attention(q, k, v, causal=True, window=100, impl="flash")
+        return jnp.sum(o.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_fwd"]
+    assert "tensor<1x7x256x64xbf16>" in text  # a shard's query heads
