@@ -36,18 +36,26 @@ not the sequence length. Matmuls feed the MXU in the input dtype (bf16 in
 training) with float32 accumulation; softmax math is float32 on the VPU.
 
 The backward is one kernel, ``flash_bwd_dkv``, wherever the float32 dQ of one
-``(batch, key-value head)`` fits ``_DQ_VMEM_BUDGET_BYTES`` — for the same
-reason, every Perceiver shape. Its grid is ``(b, hk, j_blocks, group *
-i_blocks)``: dK and dV of a kv block accumulate over the q blocks (innermost),
-and each block pair's ``ds @ k`` is added to its rows of a dQ accumulator that
-stays in VMEM across both block dimensions and is rounded once, when the last
-kv block has added its part. Scores, mask, ``exp`` and ``dP`` are computed
-once per block pair: five products. Where dQ does not fit (long self-attention
-with grouped heads), dQ has a kernel of its own, ``flash_bwd_dq``, which
-recomputes them (seven products; ``flash_bwd_dkv`` then returns dK and dV
-alone), and the traced backward is counted in
-``flash_backward_two_call_total``. The sums, their order and the rounding are
-the same either way. The choice is made from the shapes at trace time.
+query head fits ``_DQ_VMEM_BUDGET_BYTES`` (16 MiB: 32,768 rows at up to 128
+channels). Its grid is ``(b, hk * slices, j_blocks, g * i_blocks)``: dK and dV
+of a kv block accumulate over the q blocks (innermost), and each block pair's
+``ds @ k`` is added to its rows of a dQ accumulator that stays in VMEM across
+both block dimensions and is rounded once, when the last kv block has added
+its part. Scores, mask, ``exp`` and ``dP`` are computed once per block pair:
+five products. ``g`` is the largest divisor of the group (the query heads that
+share a key-value head) whose dQ fits the budget together
+(``_resident_heads``). The whole group, as in every Perceiver shape (for the
+same reason as above) and with four 64-wide heads on 8192 rows: one grid slice
+a key-value head. A smaller divisor (seven 128-wide heads on 16,384 rows: one):
+``group // g`` slices a key-value head, each writing its own dK and dV in
+float32, which are summed over the slices in float32 and rounded once, as the
+resident accumulators are across the group; the traced backward is counted in
+``flash_backward_sliced_total``. Where one head alone does not fit, dQ has a
+kernel of its own, ``flash_bwd_dq``, which recomputes scores, mask, ``exp``
+and ``dP`` (seven products; ``flash_bwd_dkv`` then returns dK and dV alone),
+and the traced backward is counted in ``flash_backward_two_call_total``. dQ
+is the same sums in the same order in all three forms, dK and dV up to the
+order of a float32 sum. The choice is made from the shapes at trace time.
 
 Queries arrive pre-scaled and pre-rotated (see
 :func:`perceiver_io_tpu.ops.attention.dot_product_attention`): the attention
@@ -70,24 +78,44 @@ LANES = 128
 _BLOCK_CANDIDATES = (512, 256, 128)
 # Large-but-finite mask value (f32 min would overflow when subtracted).
 _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
-# The float32 dQ of one (batch, key-value head) that the backward may keep in
-# VMEM to run as one kernel (``_dq_fits_vmem``). A kernel gets 16 MiB of scoped
-# VMEM unless it asks for more. At 512 x 512 blocks the dK/dV kernel takes
-# 1.75 MiB of it in bfloat16 at 64-wide heads, 7 MiB at 256-wide, and 6.5 to
-# 10 MiB in float32 at 128- to 256-wide (compiled for a v5e without the chip,
-# halving ``vmem_limit_bytes`` until Mosaic refused). Fused, it holds besides
-# the accumulator A (lanes padded to 128) and the dQ output block twice over
-# (A / 2 each in bfloat16, A in float32): 10 + 3 A <= 16 MiB gives 2 MiB,
-# 4096 rows of 128 lanes. The Perceiver latents are far inside it (1024 rows
-# 0.5 MiB, 2048 rows 1 MiB); four query heads on 8192 rows (16 MiB) are not.
-_DQ_VMEM_BUDGET_BYTES = 2 * 1024 * 1024
-# Heads wider than 256 leave the dK/dV kernel itself little of the 16 MiB
-# (float32 at 512-wide: 14.5 MiB), so the fused kernel asks for the default and
-# as much again: whatever compiled as two kernels compiles as one. Mosaic
-# allocates what the kernel needs, not the limit (v5e: 128 MiB of VMEM).
+# The float32 dQ that the one-kernel backward may keep in VMEM across a grid
+# slice's kv blocks (``_resident_heads``): A = heads * rows * max(d, 128) * 4
+# bytes, lanes padded to 128 as Mosaic lays it out. The v5e has 128 MiB of VMEM
+# and a kernel gets 16 MiB of it unless it asks (``vmem_limit_bytes``); Mosaic
+# allocates what the kernel needs, not the limit. What it needs, by compiling
+# for a v5e without the chip and halving the limit until Mosaic refused
+# (512 x 512 blocks): the dK/dV blocks and temporaries B (3.7 MiB in bfloat16
+# at 64-wide heads, 5 at 128-wide with float32 dK/dV slices, 7.5 at 256-wide;
+# in float32 6.4 at 128-wide, 9.7 at 256, 13.7 at 384, 17.2 at 512), the
+# accumulator A, and the dQ output block twice over (A / 2 each in bfloat16,
+# A in float32). So B + 2 A in bfloat16 and B + 3 A in float32:
+#   four 64-wide query heads on 8192 rows (lfm2moe-train-8k)  A 16 MiB  35.8 MiB
+#   one 256-wide head on 8192 rows (glm47flash-train-8k)      A  8 MiB  23.5 MiB
+#   one 128-wide head of 7 on 16,384 rows (smallthinker-...)   A  8 MiB  21.0 MiB
+#   float32, one 256-wide head on 16,384 rows                  A 16 MiB  57.7 MiB
+#   float32, one 512-wide head on 8192 rows                    A 16 MiB  65.2 MiB
+# 16 MiB of A keeps the worst of these at half the v5e's VMEM (limits of 96 to
+# 128 MiB compiled too, but nothing was run there), and holds the three ``lm``
+# cells: 32,768 rows of one head of up to 128 channels, 16,384 of 256. On the
+# chip the one kernel ran 0.68 to 0.76 of the two kernels' time at those three
+# shapes, and a group kept whole beat the same group in 2 or 4 slices by 4 and
+# 5 % (PERF.md, PR 36), hence the largest divisor. ``_fused_vmem_limit`` asks
+# for an upper estimate of B from the blocks' shapes (3 to 5 MiB over each
+# figure above) plus the 2 or 3 A, and never for less than the 32 MiB every
+# such kernel asked for while A was held to 2 MiB, so the Perceiver cells'
+# kernels (A 0.5 and 1 MiB) are the programs they were. A chip with less VMEM
+# than 128 MiB (64 MiB a core on a v7x) wants this constant derived again by
+# the same rule, B + 3 A within half of it: 8 MiB there; a shape past the
+# constant then runs as the two kernels, as one past 16 MiB does here. The
+# choice reads the shapes alone: the lowering platform is not known at trace
+# time, and the v5e is what every cell runs on.
+_DQ_VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+# the one-kernel backward's ``vmem_limit_bytes`` up to 2 MiB of resident dQ, and the least above
 _FUSED_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
-# backwards traced as two kernels because dQ is over the budget (docs/observability.md)
+# backwards traced as two kernels because one head's dQ is over the budget (docs/observability.md)
 _TWO_CALL_COUNTER = "flash_backward_two_call_total"
+# backwards traced as one kernel over slices of a group whose dQ is over it whole
+_SLICED_COUNTER = "flash_backward_sliced_total"
 # traced forward calls that carried a window (docs/observability.md)
 _WINDOW_COUNTER = "flash_window_call_total"
 # ``checkpoint_name`` of the forward's output and log-sum-exp under a gradient
@@ -220,9 +248,12 @@ def _flash_bwd(causal, window, res, do):
     q, k, v, pad, o, lse = res
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, LANES))
-    default_registry().declare_counters(_TWO_CALL_COUNTER)
-    if _dq_fits_vmem(q, k):
-        dk, dv, dq = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window, with_dq=True)
+    default_registry().declare_counters(_TWO_CALL_COUNTER, _SLICED_COUNTER)
+    heads = _resident_heads(q, k)
+    if heads:
+        if heads < q.shape[1] // k.shape[1]:
+            default_registry().inc(_SLICED_COUNTER)  # trace time, as below
+        dk, dv, dq = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window, dq_heads=heads)
     else:
         # trace time, so once per traced backward; correct, only slower: no warning
         default_registry().inc(_TWO_CALL_COUNTER)
@@ -235,11 +266,36 @@ def _flash_bwd(causal, window, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _dq_fits_vmem(q, k) -> bool:
-    """Whether the backward runs as one kernel: the float32 dQ of one
-    ``(batch, key-value head)`` as Mosaic lays it out, against the budget."""
+def _resident_heads(q, k) -> int:
+    """How many of a key-value head's query heads the one-kernel backward
+    keeps dQ resident for at a time: the largest divisor of the group whose
+    float32 dQ, as Mosaic lays it out, is within the budget. The group itself:
+    one pass a key-value head; a smaller divisor: the group in slices; 0: one
+    head alone is over it, and the backward is two kernels."""
     group, i, d = q.shape[1] // k.shape[1], q.shape[2], q.shape[3]
-    return group * i * max(d, LANES) * 4 <= _DQ_VMEM_BUDGET_BYTES
+    head = i * max(d, LANES) * 4
+    return max((g for g in range(1, group + 1)
+                if group % g == 0 and g * head <= _DQ_VMEM_BUDGET_BYTES), default=0)
+
+
+def _fused_vmem_limit(bi: int, bj: int, d: int, dv: int, rows: int, itemsize: int, kv_out_itemsize: int) -> int:
+    """``vmem_limit_bytes`` of the one-kernel backward with ``rows`` rows of
+    dQ resident: an upper estimate of what Mosaic allocates, from the shapes
+    (the figures behind it are at ``_DQ_VMEM_BUDGET_BYTES``), and never under
+    ``_FUSED_VMEM_LIMIT_BYTES``, what every such kernel asked for while the
+    resident dQ was at most 2 MiB."""
+    lanes = lambda n: -(-n // LANES) * LANES
+    d, dv = lanes(d), lanes(dv)
+    blocks = (
+        2 * (bi + bj) * (d + dv) * itemsize    # q, dO, k, v: each block in two buffers
+        + 2 * 2 * bi * LANES * 4               # lse, delta
+        + 2 * bj * (d + dv) * kv_out_itemsize  # the dK, dV output blocks
+        + bj * (d + dv) * 4                    # their float32 accumulators
+        + 4 * bi * bj * 4                      # scores, probabilities, dP, dS
+        + (bj * (d + dv) + bi * d) * 4         # the three products before they are added
+    )
+    resident = rows * d * (4 + 2 * itemsize)   # the accumulator, the dQ output block twice
+    return max(_FUSED_VMEM_LIMIT_BYTES, blocks + resident)
 
 
 def _block_mask(i_idx, j_idx, bi: int, bj: int, offset: int, causal: bool, pad_blk,
@@ -382,12 +438,6 @@ def _q_grid_specs(bi, bj, d, dv, group: int, has_pad: bool, band: Optional[_Band
 _DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
 )
-# the fused backward carries dQ across the kv blocks (grid dim 2) too
-_DIM_SEMANTICS_RESIDENT_DQ = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
-    vmem_limit_bytes=_FUSED_VMEM_LIMIT_BYTES,
-)
-
 
 def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, h, i, d = q.shape
@@ -554,12 +604,20 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
     )
 
 
-def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, with_dq: bool = False):
-    """dK and dV, and with ``with_dq`` dQ as a third output of the same
-    kernel (the caller has checked :func:`_dq_fits_vmem`)."""
+def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: int = 0):
+    """dK and dV, and with ``dq_heads`` dQ as a third output of the same
+    kernel: ``dq_heads`` is how many of a key-value head's query heads keep
+    their float32 dQ in VMEM at a time (:func:`_resident_heads`). The whole
+    group: one grid slice a key-value head. A smaller divisor of it: the
+    group's heads in ``slices`` grid slices, each writing its own float32 dK
+    and dV, which are summed here in float32 and rounded once."""
     b, h, i, d = q.shape
     hk, j, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // hk
+    with_dq = dq_heads > 0
+    walk = dq_heads or group  # the query heads a grid slice walks (grid dim 3)
+    slices = group // walk    # the grid slices of a key-value head (grid dim 1)
+    assert walk * slices == group, (group, dq_heads)
     bi, bj = _pick_block(i), _pick_block(j)
     offset = j - i
     ni, nj = i // bi, j // bj
@@ -568,15 +626,16 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, with_dq: bo
     # q blocks a query head walks in grid dim 3: all, or those of a kv block's band
     nq = ni if band is None else band.q_blocks
 
-    # Grid dim 1 walks the key-value heads, dim 2 kv blocks, dim 3 the q
-    # blocks of every query head that shares the key-value head (innermost,
-    # so the dk/dv accumulators carry across q blocks and across the group).
-    # With dQ, the float32 dQ of the whole key-value head (``group * i`` rows,
+    # Grid dim 1 walks the key-value heads (each ``slices`` times over, slice
+    # ``h_`` reading key-value head ``h_ // slices``), dim 2 kv blocks, dim 3
+    # the q blocks of every query head of the slice (innermost, so the dk/dv
+    # accumulators carry across q blocks and across the slice's heads).
+    # With dQ, the float32 dQ of the slice's heads (``walk * i`` rows,
     # grid step ``t_idx`` owning rows ``t_idx * bi`` on) stays in VMEM across
     # dims 2 and 3: each kv block adds its part in ascending order, as
     # ``_backward_dq`` sums them, and the last rounds the rows once into the
     # output block, which is resident as long and written back when
-    # ``(b, hk)`` moves on. Under a window grid dim 3 walks, for each query
+    # dim 0 or 1 moves on. Under a window grid dim 3 walks, for each query
     # head, the ``nq`` q blocks from the kv block's first (``inside`` says the
     # step's q block exists; past the last one it is held there and skipped),
     # and a q block's rows of dQ are zeroed by the first kv block of its band
@@ -591,7 +650,7 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, with_dq: bo
             lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
         j_idx, t_idx = pl.program_id(2), pl.program_id(3)
         if band is None:
-            i_idx = t_idx if group == 1 else t_idx % ni
+            i_idx = t_idx if walk == 1 else t_idx % ni
             row_block, inside = t_idx, None
         else:
             i_idx = band.first_i(j_idx) + t_idx % nq
@@ -647,7 +706,7 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, with_dq: bo
         run = _run_block(i_idx, j_idx, bi, bj, offset, causal, window)
         _maybe_when(run if band is None else inside & run, body)
 
-        @pl.when(t_idx == group * nq - 1)
+        @pl.when(t_idx == walk * nq - 1)
         def _():
             dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
             dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
@@ -659,18 +718,19 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, with_dq: bo
             def _():
                 dq_ref[0, 0, dq_rows, :] = dq_sc[dq_rows, :].astype(dq_ref.dtype)
 
+    # a slice's query heads are adjacent: grid head ``h_`` walks heads ``h_ * walk`` on
     if band is not None:
         q_side = lambda width: pl.BlockSpec(
             (1, 1, bi, width),
-            lambda b_, h_, x_, y_: (b_, h_ * group + y_ // nq, band.q_block(x_, y_ % nq), 0))
-    elif group == 1:
+            lambda b_, h_, x_, y_: (b_, h_ * walk + y_ // nq, band.q_block(x_, y_ % nq), 0))
+    elif walk == 1:
         q_side = lambda width: _qk_spec(bi, width, by_dim2=False)  # q blocks walk grid dim 3
     else:
-        q_side = lambda width: _group_q_spec(bi, width, group, ni)
+        q_side = lambda width: _group_q_spec(bi, width, walk, ni)
     in_specs = [
         q_side(d),
-        _qk_spec(bj, d, by_dim2=True),    # k blocks walk grid dim 2
-        _qk_spec(bj, dv, by_dim2=True),
+        _qk_spec(bj, d, by_dim2=True, group=slices),    # k blocks walk grid dim 2
+        _qk_spec(bj, dv, by_dim2=True, group=slices),
     ]
     args = [q, k, v]
     if has_pad:
@@ -684,31 +744,39 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, with_dq: bo
         _qk_spec(bj, dv, by_dim2=True),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((b, hk, j, d), k.dtype),
-        jax.ShapeDtypeStruct((b, hk, j, dv), v.dtype),
+        jax.ShapeDtypeStruct((b, hk * slices, j, d), k.dtype if slices == 1 else jnp.float32),
+        jax.ShapeDtypeStruct((b, hk * slices, j, dv), v.dtype if slices == 1 else jnp.float32),
     ]
     scratch_shapes = [
         pltpu.VMEM((bj, d), jnp.float32),
         pltpu.VMEM((bj, dv), jnp.float32),
     ]
+    compiler_params = _DIM_SEMANTICS
     if with_dq:
-        # the group's query heads are adjacent, so (b, hk, group * i, d) is
-        # (b, h, i, d) seen by key-value head: the reshape below moves nothing
-        out_specs.append(pl.BlockSpec((1, 1, group * i, d), lambda b_, h_, x_, y_: (b_, h_, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b, hk, group * i, d), q.dtype))
-        scratch_shapes.append(pltpu.VMEM((group * i, d), jnp.float32))
+        # the query heads are adjacent, so (b, hk * slices, walk * i, d) is
+        # (b, h, i, d) seen by slice: the reshape below moves nothing
+        out_specs.append(pl.BlockSpec((1, 1, walk * i, d), lambda b_, h_, x_, y_: (b_, h_, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, hk * slices, walk * i, d), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((walk * i, d), jnp.float32))
+        # the resident dQ carries across the kv blocks (grid dim 2) too
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_fused_vmem_limit(
+                bi, bj, d, dv, walk * i, q.dtype.itemsize, out_shape[0].dtype.itemsize),
+        )
 
     out = pallas_call_on_lowering_platform(
         kernel,
         *args,
         name="flash_bwd_dkv",
-        grid=(b, hk, nj, group * nq),
+        grid=(b, hk * slices, nj, walk * nq),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=_DIM_SEMANTICS_RESIDENT_DQ if with_dq else _DIM_SEMANTICS,
+        compiler_params=compiler_params,
     )
-    if with_dq:
-        return out[0], out[1], out[2].reshape(q.shape)
-    return out[0], out[1]
+    dk_dv = out[:2]
+    if slices > 1:  # one float32 sum over a key-value head's slices, one rounding
+        dk_dv = [x.reshape(b, hk, slices, j, -1).sum(axis=2).astype(k.dtype) for x in dk_dv]
+    return (*dk_dv, out[2].reshape(q.shape)) if with_dq else tuple(dk_dv)

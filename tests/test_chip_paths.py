@@ -84,6 +84,38 @@ def test_make_mesh_raises_on_a_shape_the_tpu_topology_cannot_hold():
         make_mesh(MeshConfig(data=3), devices=tpus[:3])  # no reshape fallback
 
 
+@pytest.mark.parametrize(
+    "b,h,hk,n,d,dtype,window",
+    [(2, 32, 8, 8192, 64, jnp.bfloat16, None), (1, 20, 20, 8192, 256, jnp.bfloat16, None),
+     (1, 28, 4, 16384, 128, jnp.bfloat16, 4096), (1, 2, 2, 8192, 512, jnp.float32, None)],
+    ids=["lfm2moe_cell", "glm47flash_cell", "smallthinker_cell_banded", "float32_512_wide_at_the_budget"],
+)
+def test_one_kernel_flash_backward_compiles_for_a_v5e_with_the_vmem_it_asks_for(b, h, hk, n, d, dtype, window):
+    """The real TPU compiler, Mosaic included, on a described v5e: the
+    backward that keeps up to 16 MiB of dQ in VMEM fits the
+    ``vmem_limit_bytes`` that ``_fused_vmem_limit`` works out from its
+    shapes, at the three ``lm`` cells' and at the widest float32 head the
+    budget admits (65.2 MiB needed of the 70 asked for)."""
+    pytest.importorskip("libtpu")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from perceiver_io_tpu.ops import flash_attention
+
+    one_chip = SingleDeviceSharding(topologies.get_topology_desc("v5e:2x2", "tpu").devices[0])
+    q = do = jax.ShapeDtypeStruct((b, h, n, d), dtype, sharding=one_chip)
+    k = v = jax.ShapeDtypeStruct((b, hk, n, d), dtype, sharding=one_chip)
+    lse = delta = jax.ShapeDtypeStruct((b, h, n, flash_attention.LANES), jnp.float32, sharding=one_chip)
+    heads = flash_attention._resident_heads(q, k)
+    assert heads > 0
+
+    def backward(q, k, v, lse, delta, do):
+        return flash_attention._backward_dkv(q, k, v, None, lse, delta, do, True, window, dq_heads=heads)
+
+    text = jax.jit(backward).lower(q, k, v, lse, delta, do).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "flash_bwd_dkv" in text
+
+
 def test_ledger_lets_a_compile_error_raise():
     registry = MetricsRegistry()
     ledger = CompileLedger(registry=registry)

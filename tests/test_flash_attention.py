@@ -160,7 +160,7 @@ def test_fused_backward_matches_two_call_and_einsum(rng, monkeypatch, case, dtyp
     pad = None if lengths is None else jnp.asarray(np.arange(j)[None, :] < np.asarray(lengths)[:, None])
     flash = lambda q, k, v: flash_attention.flash_attention(q, k, v, pad_mask=pad, causal=causal)
 
-    assert flash_attention._dq_fits_vmem(q, k)
+    assert flash_attention._resident_heads(q, k) == h // hk
     fused = _grads(flash, q, k, v, cot)
     monkeypatch.setattr(flash_attention, "_DQ_VMEM_BUDGET_BYTES", 0)
     two_call = _grads(flash, q, k, v, cot)
@@ -188,10 +188,10 @@ def test_fused_backward_matches_two_call_and_einsum(rng, monkeypatch, case, dtyp
         np.testing.assert_allclose(a, e, atol=tol * max(1.0, np.abs(e).max()), rtol=tol, err_msg=f"d{name}")
 
 
-def _traced_backward(h, hk, i):
+def _traced_backward(h, hk, i, d=64):
     """The jaxpr of a flash forward and backward at ``i`` query rows, traced and not run."""
-    q = jax.ShapeDtypeStruct((1, h, i, 64), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((1, hk, 128, 64), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, h, i, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, hk, 128, d), jnp.bfloat16)
     loss = lambda q, k, v: jnp.sum(flash_attention.flash_attention(q, k, v).astype(jnp.float32))
     return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv))
 
@@ -199,21 +199,141 @@ def _traced_backward(h, hk, i):
 def test_backward_is_one_kernel_while_dq_fits_the_budget():
     from perceiver_io_tpu.observability import default_registry
 
-    counter = "flash_backward_two_call_total"
+    counters = ("flash_backward_two_call_total", "flash_backward_sliced_total")
     budget = flash_attention._DQ_VMEM_BUDGET_BYTES
     rows = budget // (flash_attention.LANES * 4)  # 64-wide heads take whole lanes all the same
 
     def kernels(h, hk, i):
-        before = default_registry().counter(counter)
+        before = [default_registry().counter(c) for c in counters]
         text = _traced_backward(h, hk, i)
         names = {n for n in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv") if f"name={n}" in text}
-        return names, default_registry().counter(counter) - before
+        return (names, *(default_registry().counter(c) - b for c, b in zip(counters, before)))
 
-    assert kernels(1, 1, rows) == ({"flash_fwd", "flash_bwd_dkv"}, 0)
-    assert kernels(4, 1, rows // 4) == ({"flash_fwd", "flash_bwd_dkv"}, 0)  # the group's heads share it
-    assert kernels(1, 1, rows + 128) == ({"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, 1)
-    assert kernels(4, 1, rows // 4 + 128) == ({"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, 1)
-    assert kernels(4, 4, rows // 4 + 128) == ({"flash_fwd", "flash_bwd_dkv"}, 0)
+    one, two = {"flash_fwd", "flash_bwd_dkv"}, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert kernels(1, 1, rows) == (one, 0, 0)
+    assert kernels(4, 1, rows // 4) == (one, 0, 0)  # the group's heads share it
+    assert kernels(1, 1, rows + 128) == (two, 1, 0)  # one head alone is over it
+    assert kernels(4, 1, rows // 4 + 128) == (one, 0, 1)  # the group in two slices of two heads
+    assert kernels(4, 4, rows // 4 + 128) == (one, 0, 0)
+    assert kernels(7, 1, rows // 4) == (one, 0, 1)  # a prime group: a head a slice
+    assert kernels(4, 1, rows + 128) == (two, 1, 0)
+
+
+MiB = 1 << 20
+# (h, hk, i, d) -> the query heads of a key-value head whose dQ stays resident together
+RESIDENT_HEADS = {
+    "ar8k-train cross-attention": ((8, 8, 1024, 64), 1),  # 0.5 MiB
+    "mlm201m-train decoder": ((8, 8, 2048, 32), 1),  # 1 MiB at 128 lanes
+    "lfm2moe-train-8k": ((32, 8, 8192, 64), 4),  # the whole group, exactly 16 MiB
+    "glm47flash-train-8k": ((20, 20, 8192, 256), 1),  # 8 MiB
+    "smallthinker-train-16k": ((28, 4, 16384, 128), 1),  # a group of 7 is 56 MiB, a head 8
+    "group exactly at the budget": ((8, 1, 4096, 128), 8),
+    "one row block over it, a divisor fits": ((8, 1, 4096 + 512, 128), 4),
+    "one row block over it, group of 6": ((6, 1, 5632, 128), 3),  # 16.5 MiB whole, 8.25 by 3
+    "a prime group over it": ((7, 1, 8192, 128), 1),
+    "a prime group that fits": ((7, 1, 4096, 128), 7),  # 14 MiB
+    "a head exactly at the budget": ((4, 2, 16384, 256), 1),
+    "a head that alone does not fit": ((4, 2, 16384 + 512, 256), 0),
+    "a wide head that does not fit": ((2, 2, 16384, 512), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_HEADS))
+def test_resident_heads_is_the_largest_divisor_of_the_group_within_16_mib(case):
+    """The rule of ``_flash_bwd``, from the shapes alone: the five cells' and
+    the edges. What it chooses decides the kernels and both counters."""
+    import perceiver_io_tpu.observability as observability
+
+    (h, hk, i, d), expected = RESIDENT_HEADS[case]
+    assert flash_attention._DQ_VMEM_BUDGET_BYTES == 16 * MiB
+    q = jax.ShapeDtypeStruct((1, h, i, d), jnp.bfloat16)
+    assert flash_attention._resident_heads(q, jax.ShapeDtypeStruct((1, hk, 128, d), jnp.bfloat16)) == expected
+
+    registry, group = observability.MetricsRegistry(), h // hk
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(observability, "default_registry", lambda: registry)
+        text = _traced_backward(h, hk, i, d)
+    assert ("name=flash_bwd_dq" in text) == (expected == 0)
+    assert registry.counters()["flash_backward_two_call_total"] == float(expected == 0)
+    assert registry.counters()["flash_backward_sliced_total"] == float(0 < expected < group)
+    if 0 < expected < group:  # each slice's dK and dV in float32, group / g slices a key-value head
+        assert f"f32[1,{hk * group // expected},128,{d}]" in text
+
+
+def test_fused_vmem_limit_stays_32_mib_under_2_mib_of_dq_and_follows_the_shape_above():
+    """What the one-kernel backward asks of VMEM: the limit every such kernel
+    had while its dQ was at most 2 MiB, so those kernels are the programs they
+    were; above, the blocks and the resident dQ three times over (bfloat16
+    output: twice), over what Mosaic was found to need (the comment at
+    ``_DQ_VMEM_BUDGET_BYTES``) and under the v5e's 128 MiB."""
+    limit = flash_attention._fused_vmem_limit
+    for d, dv, rows, itemsize in [(64, 64, 1024, 2), (32, 160, 2048, 2), (32, 96, 2048, 2), (64, 64, 4096, 4),
+                                  (256, 256, 1024, 4), (512, 512, 1024, 4)]:
+        assert rows * max(d, 128) * 4 <= 2 * MiB
+        assert limit(512, 512, d, dv, rows, itemsize, itemsize) == 32 * MiB
+    # (bi, bj, d, dv, rows, itemsize, dK/dV's itemsize), the least limit Mosaic compiled it with for a v5e, in MiB
+    needs = {
+        (512, 512, 64, 64, 4 * 8192, 2, 2): 35.8,    # lfm2moe-train-8k
+        (512, 512, 256, 256, 8192, 2, 2): 23.5,      # glm47flash-train-8k
+        (512, 512, 128, 128, 16384, 2, 4): 21.0,     # smallthinker-train-16k, a head a slice
+        (512, 512, 256, 256, 16384, 4, 4): 57.7,     # float32 at the budget
+        (512, 512, 512, 512, 8192, 4, 4): 65.2,
+    }
+    for shape, least in needs.items():
+        assert least * MiB < limit(*shape) <= 72 * MiB, shape
+
+
+SLICED_CASES = {
+    # (h, hk, i, j, d, window, padded keys); blocks of 128
+    "group_of_3_causal": (6, 2, 256, 384, 32, None, 0),
+    "group_of_3_padded_key_block": (3, 1, 256, 384, 32, None, 133),  # key block 0 wholly padded
+    "group_of_7_causal": (7, 1, 256, 256, 32, None, 0),
+    "group_of_7_banded": (14, 2, 384, 384, 32, 129, 0),
+    "group_of_6_by_threes_banded_padded": (6, 1, 256, 384, 32, 200, 5),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(SLICED_CASES))
+def test_sliced_backward_matches_two_kernels_and_einsum(rng, monkeypatch, case, dtype):
+    """A group whose dQ is over the budget whole, walked in slices by the one
+    kernel: dQ bit for bit the two kernels' (the same sums in the same order),
+    dK and dV the float32 sums of the slices' parts, so within float32
+    summation order of theirs before the one rounding; and all three against
+    autodiff of the einsum path."""
+    h, hk, i, j, d, window, padded = SLICED_CASES[case]
+    b, group = 2, h // hk
+    q = (jnp.asarray(rng.standard_normal((b, h, i, d)), jnp.float32) * d**-0.5).astype(dtype)
+    k = jnp.asarray(rng.standard_normal((b, hk, j, d)), jnp.float32).astype(dtype)
+    v = jnp.asarray(rng.standard_normal((b, hk, j, d)), jnp.float32).astype(dtype)
+    cot = jnp.asarray(rng.standard_normal((b, h, i, d)), jnp.float32)
+    pad = None
+    if padded:  # left padding; rows that see padding alone are dead: left out, as above
+        pad = jnp.zeros((b, j), bool).at[:, :padded].set(True)
+        cot = cot.at[:, :, :max(0, padded - (j - i))].set(0.0)
+    flash = lambda q, k, v: flash_attention.flash_attention(q, k, v, pad_mask=pad, causal=True, window=window)
+    head = i * flash_attention.LANES * 4
+    by = 3 if case.startswith("group_of_6") else 1
+
+    monkeypatch.setattr(flash_attention, "_DQ_VMEM_BUDGET_BYTES", by * head)
+    assert flash_attention._resident_heads(q, k) == by < group
+    sliced = _grads(flash, q, k, v, cot)
+    monkeypatch.setattr(flash_attention, "_DQ_VMEM_BUDGET_BYTES", 0)
+    two_call = _grads(flash, q, k, v, cot)
+    np.testing.assert_array_equal(np.asarray(sliced[0], np.float32), np.asarray(two_call[0], np.float32), err_msg="dq")
+    # one rounding of a float32 sum in another order: an ulp of the dtype
+    ulp = 2e-6 if dtype == jnp.float32 else 2.0 ** -7
+    for a, e, name in zip(sliced[1:], two_call[1:], "kv"):
+        a, e = np.asarray(a, np.float32), np.asarray(e, np.float32)
+        np.testing.assert_allclose(a, e, atol=ulp * np.abs(e).max(), rtol=ulp, err_msg=f"d{name}")
+
+    einsum = _grads(
+        lambda q, k, v: dot_product_attention(q, k, v, pad_mask=pad, causal=True, window=window, impl="xla"),
+        q, k, v, cot)
+    tol = 1e-4 if dtype == jnp.float32 else 5e-2
+    for a, e, name in zip(sliced, einsum, "qkv"):
+        a, e = np.asarray(a, np.float32), np.asarray(e, np.float32)
+        np.testing.assert_allclose(a, e, atol=tol * max(1.0, np.abs(e).max()), rtol=tol, err_msg=f"d{name}")
 
 
 def _benchmark_reader(monkeypatch, metric):
@@ -241,8 +361,11 @@ def test_two_call_counter_has_help_text_and_a_benchmark_reader(monkeypatch):
     assert read({}) is None  # the parent's program never declares it
     _traced_backward(1, 1, 1024)
     assert read({}) == 0.0
-    _traced_backward(1, 1, 8192)
+    _traced_backward(1, 1, 8192)  # 4 MiB: one kernel since the budget is 16 MiB
+    assert read({}) == 0.0
+    _traced_backward(1, 1, 32768 + 512)
     assert read({}) == 1.0
+    assert "flash_backward_sliced_total" in HELP_TEXT
 
 
 def _operator(kind):

@@ -121,26 +121,40 @@ def test_each_flash_kernel_lowers_under_its_own_name(kernel):
 
 
 @pytest.mark.parametrize(
-    "b,h,hk,i,j,pad",
-    [(32, 8, 8, 1024, 4608, True), (2, 8, 2, 512, 512, False)],
-    ids=["ar_cross_attention", "grouped_heads"],
+    "b,h,hk,i,j,d,pad,heads",
+    [(32, 8, 8, 1024, 4608, 64, True, 1), (2, 8, 2, 512, 512, 64, False, 4),
+     (2, 32, 8, 8192, 8192, 64, False, 4), (1, 20, 20, 8192, 8192, 256, False, 1),
+     (1, 28, 4, 16384, 16384, 128, False, 1)],
+    ids=["ar_cross_attention", "grouped_heads", "lfm2moe_cell", "glm47flash_cell", "smallthinker_cell_sliced"],
 )
-def test_fused_backward_lowers_as_flash_bwd_dkv_with_three_outputs(b, h, hk, i, j, pad):
-    q = do = jax.ShapeDtypeStruct((b, h, i, 64), jnp.bfloat16)
-    k = v = jax.ShapeDtypeStruct((b, hk, j, 64), jnp.bfloat16)
+def test_fused_backward_lowers_as_flash_bwd_dkv_with_three_outputs(b, h, hk, i, j, d, pad, heads):
+    """The one-kernel backward at the cells' shapes: one ``flash_bwd_dkv``
+    with dK, dV and dQ by grid slice. Where the group is walked in slices
+    (28 query heads on 4: a head a slice) dK and dV come out a slice each in
+    float32; under 2 MiB of resident dQ the kernel asks for the 32 MiB it
+    always did, above for what the shape takes."""
+    q = do = jax.ShapeDtypeStruct((b, h, i, d), jnp.bfloat16)
+    k = v = jax.ShapeDtypeStruct((b, hk, j, d), jnp.bfloat16)
     lse = delta = jax.ShapeDtypeStruct((b, h, i, flash_attention.LANES), jnp.float32)
     pad_mask = jax.ShapeDtypeStruct((b, 1, j), jnp.float32) if pad else None
-    assert flash_attention._dq_fits_vmem(q, k)
+    assert flash_attention._resident_heads(q, k) == heads
+    slices = h // hk // heads
 
     def call(q, k, v, pad_mask, lse, delta, do):
-        return flash_attention._backward_dkv(q, k, v, pad_mask, lse, delta, do, True, with_dq=True)
+        return flash_attention._backward_dkv(q, k, v, pad_mask, lse, delta, do, True, dq_heads=heads)
 
     args = (q, k, v, pad_mask, lse, delta, do)
     assert _kernel_names(call, *args) == (["flash_bwd_dkv"], {"flash_bwd_dkv"})
     text = jax.jit(call).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
     (results,) = re.findall(r"custom_call @tpu_custom_call.*-> \((.*)\)", text)
-    kv, dq = f"tensor<{b}x{hk}x{j}x64xbf16>", f"tensor<{b}x{hk}x{h // hk * i}x64xbf16>"
-    assert results.split(", ") == [kv, kv, dq]  # dK, dV, and dQ by key-value head
+    kv = f"tensor<{b}x{hk * slices}x{j}x{d}x{'bf16' if slices == 1 else 'f32'}>"
+    dq = f"tensor<{b}x{hk * slices}x{heads * i}x{d}xbf16>"
+    assert results.split(", ") == [kv, kv, dq]  # dK, dV, and dQ by key-value head (or slice of one)
+    (limit,) = re.findall(r'scoped_memory_configs.*?\\22size\\22: (\d+)', text)  # ``vmem_limit_bytes``
+    resident = heads * i * max(d, flash_attention.LANES) * 4
+    assert (int(limit) == 32 << 20) if resident <= 8 << 20 else (48 << 20 > int(limit) > 32 << 20)
+    out = jax.eval_shape(call, *args)  # the slices summed and rounded: dK, dV, dQ as their operands
+    assert [(o.shape, o.dtype) for o in out] == [(k.shape, k.dtype), (v.shape, v.dtype), (q.shape, q.dtype)]
 
 
 def test_ragged_kernel_lowers_under_its_own_name():
@@ -331,11 +345,11 @@ def test_grouped_head_attention_lowers_for_tpu_without_repeating_keys_or_values(
     assert f"tensor<{b}x{h}x{n}x{n}" not in grouped  # nor a score matrix
 
 
-def test_latent_attention_at_the_cells_flash_shapes_lowers_with_a_two_kernel_backward():
+def test_latent_attention_at_the_cells_flash_shapes_lowers_with_a_one_kernel_backward():
     """``glm47flash-train-8k``'s attention call: 20 heads on 20, 8192 x 8192
     causal, 256-wide query-key and value heads, bfloat16. One head's float32
-    dQ is 8 MiB against the fused kernel's 2 MiB, so the backward lowers as
-    ``flash_bwd_dkv`` and ``flash_bwd_dq`` beside ``flash_fwd``, through the
+    dQ is 8 MiB of the one-kernel backward's 16, so the backward lowers as one
+    ``flash_bwd_dkv`` beside ``flash_fwd``, through the
     module's own path (``LatentAttention``, ``attention_impl`` ``flash``), with
     no score matrix and no einsum fallback."""
     from perceiver_io_tpu.models.core.modules import LatentAttention
@@ -343,7 +357,7 @@ def test_latent_attention_at_the_cells_flash_shapes_lowers_with_a_two_kernel_bac
 
     b, n, c, h = 1, 8192, 2048, 20
     q = jax.ShapeDtypeStruct((b, h, n, 256), jnp.bfloat16)
-    assert flash_attention.supported(q, q, q, causal=True) and not flash_attention._dq_fits_vmem(q, q)
+    assert flash_attention.supported(q, q, q, causal=True) and flash_attention._resident_heads(q, q) == 1
     op = LatentAttention(
         num_heads=h, num_input_channels=c, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
         qk_rope_head_dim=64, v_head_dim=256, dtype=jnp.bfloat16, attention_impl="flash")
@@ -357,7 +371,7 @@ def test_latent_attention_at_the_cells_flash_shapes_lowers_with_a_two_kernel_bac
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, u, rot).lower(
         lowering_platforms=("tpu",))
     text = lowered.as_text()
-    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_fwd"]
     assert f"tensor<{b}x{h}x{n}x256xbf16>" in text  # q, k, v and o at 20 plain heads
     assert f"x{n}x{n}x" not in text  # no score matrix
 
@@ -454,7 +468,7 @@ def test_each_windowed_flash_kernel_lowers_through_mosaic_on_a_grid_over_the_ban
         "flash_bwd_dkv": lambda q, k, v, pad_mask, lse, delta, do: flash_attention._backward_dkv(
             q, k, v, pad_mask, lse, delta, do, True, window),
         "flash_bwd_dkv_with_dq": lambda q, k, v, pad_mask, lse, delta, do: flash_attention._backward_dkv(
-            q, k, v, pad_mask, lse, delta, do, True, window, with_dq=True),
+            q, k, v, pad_mask, lse, delta, do, True, window, dq_heads=h // hk),
     }[kernel]
     args = (q, k, v, pad_mask, lse, delta, do)
     name = kernel.replace("_with_dq", "")
@@ -469,12 +483,13 @@ def test_each_windowed_flash_kernel_lowers_through_mosaic_on_a_grid_over_the_ban
     assert text.count("tpu_custom_call") >= 1
 
 
-def test_window_attention_at_the_cells_shapes_lowers_on_the_band_with_a_two_kernel_backward():
+def test_window_attention_at_the_cells_shapes_lowers_on_the_band_with_a_sliced_one_kernel_backward():
     """``smallthinker-train-16k``'s window layers: 28 query heads on 4 of 128,
     16,384 x 16,384 causal under a window of 4,096, bfloat16, through the
-    module's own path. A group's float32 dQ is 56 MiB against the fused
-    kernel's 2 MiB, so the backward is two kernels; each kernel's grid walks
-    9 blocks a band where the row has 32; no score matrix, no key or value at
+    module's own path. A group's float32 dQ is 56 MiB against the one-kernel
+    backward's 16 and a head's 8, so the backward is that kernel over the 7
+    heads of a group a slice each, dK and dV a slice in float32; each
+    kernel's grid walks 9 blocks a band where the row has 32; no score matrix, no key or value at
     the query heads' count, no einsum fallback."""
     from perceiver_io_tpu.models.core.modules import MultiHeadAttention
     from perceiver_io_tpu.ops.position import RotaryEmbedding
@@ -483,7 +498,7 @@ def test_window_attention_at_the_cells_shapes_lowers_on_the_band_with_a_two_kern
     q = jax.ShapeDtypeStruct((b, h, n, d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((b, hk, n, d), jnp.bfloat16)
     assert flash_attention.supported(q, k, k, causal=True, window=window)
-    assert not flash_attention._dq_fits_vmem(q, k)
+    assert flash_attention._resident_heads(q, k) == 1
     band = flash_attention._Band(512, 512, 32, 32, 0, window)
     assert (band.kv_blocks, band.q_blocks) == (9, 9)
     op = MultiHeadAttention(
@@ -500,9 +515,10 @@ def test_window_attention_at_the_cells_shapes_lowers_on_the_band_with_a_two_kern
         op.apply(p, u, u, rot_pos_emb_q=rot, rot_pos_emb_k=rot).astype(jnp.float32))
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, u, rot).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_fwd"]
+    assert f"tensor<{b}x{h}x{n}x{d}xf32>" in text  # dK and dV by slice
     assert f"tensor<{b}x{h}x{n}x{d}xbf16>" in text and f"tensor<{b}x{hk}x{n}x{d}xbf16>" in text
-    assert f"x{n}x{n}x" not in text and f"tensor<{b}x{hk}x{h // hk}x{n}x{d}" not in text
+    assert f"x{n}x{n}x" not in text and f"tensor<{b}x{hk}x{h // hk}x{n}x{d}xbf16" not in text
     assert params["params"]["q_proj"]["kernel"].shape == (c, h * d)
     assert params["params"]["k_proj"]["kernel"].shape == (c, hk * d)
     assert params["params"]["o_proj"]["kernel"].shape == (h * d, c)
@@ -526,3 +542,15 @@ def test_windowed_flash_call_lowers_under_a_mesh_with_heads_over_model(devices):
         lowering_platforms=("tpu",)).as_text()
     assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_fwd"]
     assert "tensor<1x7x256x64xbf16>" in text  # a shard's query heads
+
+
+def test_a_head_whose_dq_alone_is_over_the_budget_still_lowers_as_two_kernels():
+    """Past the rule (one 256-wide head on 16,896 rows: 16.5 MiB of float32
+    dQ) the backward is the two kernels it always was, and both lower for the
+    TPU under their own names."""
+    q = jax.ShapeDtypeStruct((1, 2, 16384 + 512, 256), jnp.bfloat16)
+    assert flash_attention.supported(q, q, q, causal=True) and flash_attention._resident_heads(q, q) == 0
+    loss = lambda q, k, v: jnp.sum(flash_attention.flash_attention(q, k, v, causal=True).astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert "scoped_memory_configs" not in text  # no ``vmem_limit_bytes``: each under the 16 MiB a kernel gets without asking
