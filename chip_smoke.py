@@ -89,40 +89,6 @@ TINY = dict(
 MIN_LIVE_BYTES = 32 << 20  # "non-trivial" per-device footprint in the mesh phase
 
 
-class CompileClock:
-    """Totals of JAX's own lower / compile events (a persistent-cache hit is
-    a short compile event), so that set-up seconds can be told from run
-    seconds in each phase. Tracing is left with the run seconds: its events
-    nest, and compilation is what dominates a cold start."""
-
-    SETUP_EVENTS = (
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.setup_s = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, seconds, **_):
-        if event in self.SETUP_EVENTS:
-            self.setup_s += seconds
-        if event == self.SETUP_EVENTS[-1]:
-            self.compiles += 1
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def totals(self):
-        return self.setup_s, self.compiles, self.cache_hits
-
-
 def say(msg):
     print(f"[smoke +{time.monotonic() - _T0:7.1f}s] {msg}", flush=True)
 
@@ -551,16 +517,27 @@ def main():
     os.chdir(out_dir)  # nothing lands in the checkout by default paths
     say(f"output directory {out_dir}; compile cache {cache_dir}")
 
-    clock = CompileClock()
+    from perceiver_io_tpu.observability import default_ledger
+
+    def built():
+        """What JAX has spent building programs so far (lowering and the
+        backend, a persistent-cache hit being a short compile), the programs
+        and the cache's hits among them, by the ledger's one listener.
+        Tracing is left with the run seconds: its events nest, and
+        compilation is what dominates a cold start."""
+        totals = default_ledger().jax_totals()
+        return (totals["lower_s"] + totals["backend_s"], totals["backend_compiles"],
+                totals["cache_hits"])
+
     phases = {}
 
     def phase(name, fn):
         say(f"== phase {name}")
-        setup0, compiles0, hits0 = clock.totals()
+        setup0, compiles0, hits0 = built()
         t0 = time.perf_counter()
         detail = fn()
         seconds = time.perf_counter() - t0
-        setup1, compiles1, hits1 = clock.totals()
+        setup1, compiles1, hits1 = built()
         phases[name] = {
             "seconds": round(seconds, 2),
             "setup_seconds": round(setup1 - setup0, 2),
@@ -613,7 +590,7 @@ def main():
 
         phase("mesh", mesh)
 
-    setup_s, compiles, hits = clock.totals()
+    setup_s, compiles, hits = built()
     total = time.monotonic() - _T0
     summary = json.dumps({
         "device": info,

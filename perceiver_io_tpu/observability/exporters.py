@@ -121,6 +121,11 @@ HELP_TEXT = {
     "trainer_callback_errors_total": "Callbacks that raised and were isolated.",
     "trainer_data_wait_seconds_total": "Seconds the loop waited for the stream's next batch (trainer.data_wait); rate over trainer_steps_total's is the wait a step.",
     "trainer_log_flush_seconds_total": "Seconds the loop waited for the device at a log flush (trainer.log_flush: the host fetch of a cadence's metrics).",
+    "trainer_setup_state_seconds_total": "Seconds fit spent making its state (trainer.setup_state: the state's program traced, compiled or loaded, and dispatched); declared when a fit begins.",
+    "trainer_first_step_seconds_total": "Seconds of each step function's first dispatch in a fit (trainer.first_step): the call in which jax.jit traces, lowers and compiles or loads the step before it returns. Less the lowering and backend seconds it is the Python tracing.",
+    "trainer_first_step_lower_seconds_total": "Of trainer_first_step_seconds_total, the seconds JAX spent lowering the step to StableHLO (jaxpr_to_mlir_module_duration, by the compile ledger's listener).",
+    "trainer_first_step_backend_seconds_total": "Of trainer_first_step_seconds_total, the seconds in the backend (backend_compile_duration): a compile where the step is cold, the persistent cache's load where it is warm.",
+    "trainer_step_recompiles_total": "Step dispatches after a step function's first during which JAX compiled or loaded a program: the step was traced again, as for a batch of another shape (step_recompiled_at in metrics.jsonl says where).",
     "trainer_step_dispatch_ms": "Host dispatch time per step (unfenced; device async).",
     "trainer_step_ms": "Fenced true step time (profiler-trigger runs only).",
     "trainer_steps_per_sec": "Recent steady-state training step rate.",

@@ -58,6 +58,21 @@ run's own), parses the optimized HLO with :func:`parse_op_scopes` and keeps
 the tables. A fusion has one ``op_name`` and may hold operations of several
 scopes: :meth:`CompileLedger.fused_scopes` lists them all.
 
+**What ``jax.jit``'s own dispatch builds** (:meth:`CompileLedger.jax_totals`):
+a function that keeps its own dispatch traces, lowers and compiles (or loads
+from the persistent cache) inside its first call, where no wrapper of the
+ledger's can time it. JAX says what it spent through ``jax.monitoring``, and
+one pair of listeners a process, registered when first asked for, keeps the
+totals: seconds lowering to StableHLO, seconds in the backend (a compile, or
+the persistent cache's load), backend compiles and persistent-cache hits. A
+caller reads the totals before and after a call and takes the difference
+(the trainer around a step function's first dispatch, ``chip_smoke.py``
+around a phase). Tracing is never summed from JAX's events: an inner ``jit``
+traced inside an outer one reports its own ``jaxpr_trace_duration`` and the
+outer one's holds it, so trace time is what is left of a call once lowering
+and the backend are taken off. A listener runs only when JAX builds
+something: a warm dispatch pays nothing.
+
 Failure containment: observation must never change execution semantics. If
 the wrapped callable cannot be lowered (it is not a jitted function) or the
 compiled dispatch rejects the call signature (``TypeError`` — AOT
@@ -287,6 +302,67 @@ def _abstract(tree):
     return jax.tree_util.tree_map(leaf, tree)
 
 
+class _JaxCompileEvents:
+    """Totals of what JAX reports, through ``jax.monitoring``, of the
+    programs it builds in this process, whoever asked for them. Registered
+    with JAX once, when first asked to listen (the package imports no
+    ``jax`` at module level); JAX calls a listener in the thread that
+    compiles, hence the lock."""
+
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    #: around the persistent cache's lookup too: a hit is a short one
+    BACKEND = "/jax/core/compile/backend_compile_duration"
+    CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._listening = False
+        self.lower_s = 0.0
+        self.backend_s = 0.0
+        #: read bare by the trainer, twice a step: an int, never torn
+        self.backend_compiles = 0
+        self.cache_hits = 0
+
+    def listen(self) -> None:
+        if self._listening:
+            return
+        with self._lock:
+            if self._listening:
+                return
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+            jax.monitoring.register_event_listener(self._on_event)
+            self._listening = True
+
+    def _on_duration(self, event: str, seconds: float, **_) -> None:
+        if event == self.LOWER:
+            with self._lock:
+                self.lower_s += seconds
+        elif event == self.BACKEND:
+            with self._lock:
+                self.backend_s += seconds
+                self.backend_compiles += 1
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == self.CACHE_HIT:
+            with self._lock:
+                self.cache_hits += 1
+
+    def totals(self) -> Dict[str, float]:
+        with self._lock:
+            return {
+                "lower_s": self.lower_s,
+                "backend_s": self.backend_s,
+                "backend_compiles": self.backend_compiles,
+                "cache_hits": self.cache_hits,
+            }
+
+
+#: one a process, shared by every ledger: JAX's events are the process's
+_JAX_EVENTS = _JaxCompileEvents()
+
+
 class CompileLedger:
     """Per-executor compile/memory/retrace ledger over one metrics registry.
 
@@ -411,6 +487,26 @@ class CompileLedger:
             entry, (self._clock() - t0) * 1e3, _cost_summary(compiled), _memory_summary(compiled)
         )
         return parse_op_scopes(text)
+
+    def jax_totals(self) -> Dict[str, float]:
+        """One reading of what JAX has built in this process since the
+        ledger (any ledger) first listened: ``lower_s`` (seconds lowering to
+        StableHLO), ``backend_s`` (seconds in the backend: compiles and the
+        persistent cache's loads), ``backend_compiles`` (both kinds) and
+        ``cache_hits`` (the loads among them). Process-wide and monotonic,
+        whichever ledger is asked and whatever :meth:`reset` drops: take the
+        difference of two readings. The first call registers the listeners,
+        so what was built before it is not in any reading."""
+        _JAX_EVENTS.listen()
+        return _JAX_EVENTS.totals()
+
+    def backend_compiles(self) -> int:
+        """``jax_totals()["backend_compiles"]`` as one bare read: what a
+        loop can afford around every dispatch. The same number from two
+        reads says that nothing was compiled or loaded between them (by any
+        thread of the process)."""
+        _JAX_EVENTS.listen()
+        return _JAX_EVENTS.backend_compiles
 
     def attach(self, callback: Callable[[dict], None]) -> Callable[[], None]:
         """Register a per-record callback (the serve CLI forwards records as
@@ -559,10 +655,11 @@ class CompileLedger:
     def snapshot(self) -> dict:
         """JSON-able ledger view: the lifetime rollup plus the per-key
         compile/memory table every durable consumer (``serve_stats``,
-        snapshots, ``obs report``) embeds. The table is
+        snapshots, ``obs report``) embeds, and under ``jax`` the process's
+        totals of what JAX itself built (:meth:`jax_totals`). The table is
         bounded by ``keep`` (oldest rows FIFO out); the rollup keeps
         counting past it."""
-        return {**self.rollup(), "records": self.records()}
+        return {**self.rollup(), "jax": self.jax_totals(), "records": self.records()}
 
     def reset(self) -> None:
         """Drop records and identity history (test isolation; registry

@@ -119,13 +119,25 @@ class TrainerConfig:
 #: steps traced per jax.profiler capture: [profile_start, profile_start + _PROFILE_WINDOW)
 _PROFILE_WINDOW = 3
 
-#: the phases in which the loop waits for something else (the stream; the
-#: device, at a log flush): ``_span("trainer.<phase>")`` adds their seconds to
-#: ``trainer_<phase>_seconds_total``
+#: the phases whose seconds ``_span("trainer.<phase>")`` adds to
+#: ``trainer_<phase>_seconds_total``: those in which the loop waits for
+#: something else (the stream; the device, at a log flush) and the making of
+#: the state when a ``fit`` starts
 _WAITS = ("data_wait", "log_flush")
+_TIMED = _WAITS + ("setup_state",)
 #: counters kept on the process-wide registry as well as on the trainer's
 #: own, so that they can be read after the trainer is gone
 _PROCESS_WIDE = ("trainer_steps_total",) + tuple(f"trainer_{p}_seconds_total" for p in _WAITS)
+#: the same, declared when a ``fit`` begins: the start of a fit by phase
+#: (``_dispatching`` splits a step function's first dispatch) and the steps
+#: that compiled again
+_FIT_START = (
+    "trainer_setup_state_seconds_total",
+    "trainer_first_step_seconds_total",
+    "trainer_first_step_lower_seconds_total",
+    "trainer_first_step_backend_seconds_total",
+    "trainer_step_recompiles_total",
+)
 
 
 def _check_uniform_block(block, k_exec: int) -> None:
@@ -274,14 +286,19 @@ class Trainer:
     :param registry: metrics registry the trainer's counters/histograms live
         on (``trainer_steps_total``, ``trainer_step_ms``, fault counters...);
         defaults to a private one (docs/observability.md).
-        ``trainer_steps_total``, ``trainer_data_wait_seconds_total`` and
-        ``trainer_log_flush_seconds_total`` are counted on
-        ``default_registry()`` as well.
+        ``trainer_steps_total``, ``trainer_data_wait_seconds_total``,
+        ``trainer_log_flush_seconds_total`` and, from the first ``fit`` on,
+        the start-up counters and ``trainer_step_recompiles_total`` are
+        counted on ``default_registry()`` as well.
     :param tracer: optional :class:`~perceiver_io_tpu.observability.Tracer`
-        — one trace per ``fit`` with per-step ``trainer.data_wait`` /
-        ``trainer.step`` / ``trainer.log_flush`` / ``trainer.checkpoint``
-        spans. With or without one, each is a ``jax.profiler`` annotation of
-        the span's name.
+        — one trace per ``fit``: ``trainer.setup_state``, a
+        ``trainer.first_step`` around each step function's first dispatch
+        (attributes ``trace_s``, ``lower_s``, ``backend_s``, ``cache``) and
+        per-step ``trainer.data_wait`` / ``trainer.step`` /
+        ``trainer.log_flush`` / ``trainer.checkpoint`` spans. With or
+        without one, each is a ``jax.profiler`` annotation of the span's
+        name, and the start of a fit is counted by phase
+        (docs/observability.md "The start of a fit").
     :param profiler_trigger: optional
         :class:`~perceiver_io_tpu.observability.ProfilerTrigger` — fed each
         single step's host time; when the p95 regresses, the next step runs
@@ -355,10 +372,11 @@ class Trainer:
 
     @contextlib.contextmanager
     def _span(self, name: str, **attrs):
-        """One phase of the loop, ``trainer.<phase>``: an annotation of that
+        """One phase of a fit, ``trainer.<phase>``: an annotation of that
         name on the profiler's clock and, with a tracer, a span under this
-        fit's trace (``Tracer.span`` enters the annotation itself). The
-        seconds of the ``_WAITS`` go to ``trainer_<phase>_seconds_total``."""
+        fit's trace (``Tracer.span`` enters the annotation itself), which is
+        what the body is given (None without a tracer). The seconds of the
+        ``_TIMED`` go to ``trainer_<phase>_seconds_total``."""
         if self._tracer is None:
             cm = jax.profiler.TraceAnnotation(name)
         else:
@@ -366,15 +384,62 @@ class Trainer:
         phase = name.partition(".")[2]
         t0 = time.perf_counter()
         try:
-            with cm:
-                yield
+            with cm as span:
+                yield span if self._tracer is not None else None
         finally:
-            if phase in _WAITS:
+            if phase in _TIMED:
                 self._count(f"trainer_{phase}_seconds_total", time.perf_counter() - t0)
 
+    @contextlib.contextmanager
+    def _dispatching(self, step_fn, step_idx: int, **attrs):
+        """``trainer.step`` around one dispatch of ``step_fn``, and what the
+        dispatch built besides. The first dispatch of a step function in a
+        fit is the call in which ``jax.jit`` traces, lowers and compiles or
+        loads before it returns: it runs under ``trainer.first_step`` too,
+        whose seconds are split by the ledger's readings of JAX's own
+        events before and after into lowering, the backend and the rest
+        (tracing, the cache's key, the hand-off), counted, written on the
+        span and logged as one ``startup/`` row. No fence: what the device
+        then runs is ``trainer.step``'s business or nobody's. A backend
+        compile heard during any later dispatch is a step that compiled
+        again (a batch of another shape): counted and logged."""
+        ledger = default_ledger()
+        if step_fn not in self._dispatched:
+            self._dispatched.add(step_fn)
+            before = ledger.jax_totals()
+            with self._span("trainer.first_step", step=step_idx) as first:
+                t0 = time.perf_counter()
+                with self._span("trainer.step", step=step_idx, parent=first, **attrs):
+                    yield
+                seconds = time.perf_counter() - t0
+                built = {k: v - before[k] for k, v in ledger.jax_totals().items()}
+                lower_s, backend_s = built["lower_s"], built["backend_s"]
+                # JAX times its events on another clock: never below nought
+                trace_s = max(0.0, seconds - lower_s - backend_s)
+                seconds = trace_s + lower_s + backend_s
+                hit = 0 < built["backend_compiles"] <= built["cache_hits"]
+                if first is not None:
+                    first.attrs.update(trace_s=trace_s, lower_s=lower_s, backend_s=backend_s,
+                                       cache="hit" if hit else "miss")
+            self._count("trainer_first_step_seconds_total", seconds)
+            self._count("trainer_first_step_lower_seconds_total", lower_s)
+            self._count("trainer_first_step_backend_seconds_total", backend_s)
+            self.log_metrics(step_idx, {
+                "setup_state_s": self._setup_state_s,
+                "first_step_s": seconds, "trace_s": trace_s, "lower_s": lower_s,
+                "backend_s": backend_s, "cache_hit": float(hit),
+            }, prefix="startup/")
+            return
+        compiles = ledger.backend_compiles()
+        with self._span("trainer.step", step=step_idx, **attrs):
+            yield
+        if ledger.backend_compiles() != compiles:
+            self._count("trainer_step_recompiles_total")
+            self.log_metrics(step_idx, {"step_recompiled_at": step_idx})
+
     def _count(self, name: str, value: float = 1.0) -> None:
-        """Add to one of the ``_PROCESS_WIDE`` counters, here and on the
-        process-wide registry."""
+        """Add to one of the ``_PROCESS_WIDE`` or ``_FIT_START`` counters,
+        here and on the process-wide registry."""
         self.registry.inc(name, value)
         if self.registry is not default_registry():
             default_registry().inc(name, value)
@@ -511,6 +576,11 @@ class Trainer:
         prev_handler = None
         self._preempted = False
         self._open_writers()  # re-fit after a closed fit reopens (append)
+        self.registry.declare_counters(*_FIT_START)
+        default_registry().declare_counters(*_FIT_START)
+        # one trace a fit, from its first phase on
+        self._fit_trace = None if self._tracer is None else self._tracer.new_trace_id()
+        self._dispatched: set = set()  # the step functions this fit has dispatched
         if cfg.save_state_every_n_steps is not None:
 
             def _on_sigterm(signum, frame):
@@ -547,7 +617,11 @@ class Trainer:
                 "save_state_every_n_steps (it restores the latest "
                 "TrainState snapshot)"
             )
-        self.setup_state(init_params_fn, initial_params=initial_params)
+        counted = self.registry.counter("trainer_setup_state_seconds_total")
+        with self._span("trainer.setup_state"):
+            self.setup_state(init_params_fn, initial_params=initial_params)
+        # this fit's, for its ``startup/`` row
+        self._setup_state_s = self.registry.counter("trainer_setup_state_seconds_total") - counted
         train_step = make_train_step(
             self.loss_fn,
             self.mesh,
@@ -631,6 +705,7 @@ class Trainer:
             clear_cache = getattr(train_step, "clear_cache", None)
             if clear_cache is not None:  # a jitted function has one
                 clear_cache()
+            self._dispatched.clear()  # nor does the trainer keep the fit's step functions
         return self.state
 
     def _block_ok(self, cfg, start: int, k: int, val_data, resume_mgr) -> bool:
@@ -708,8 +783,6 @@ class Trainer:
         profile_dir = os.path.join(cfg.default_root_dir, "profile")
         captured: set = set()  # where this fit's profiler captures went
         t0 = time.time()
-        if self._tracer is not None:
-            self._fit_trace = self._tracer.new_trace_id()
         trigger = self._profiler_trigger
         self._bad_streak = 0
         self._rollbacks_this_fit = 0
@@ -785,8 +858,8 @@ class Trainer:
                         [jax.random.fold_in(rng, step_idx + i) for i in range(k_exec)]
                     )
                     block_t0 = time.perf_counter()
-                    with self._span(
-                        "trainer.step", step=step_idx, fused=k_exec,
+                    with self._dispatching(
+                        multi_step, step_idx, fused=k_exec,
                         measures="fenced" if trigger is not None else "dispatch",
                     ):
                         self.state, stacked_metrics = multi_step(self.state, stacked, rngs)
@@ -843,8 +916,8 @@ class Trainer:
                     # unfenced step span times async dispatch, and the device
                     # work it launched surfaces later under log_flush's value
                     # fetch — readers must not attribute it there
-                    with capture as capture_dir, self._span(
-                        "trainer.step", step=step_idx,
+                    with capture as capture_dir, self._dispatching(
+                        train_step, step_idx,
                         measures="fenced" if trigger is not None else "dispatch",
                     ):
                         self.state, metrics = train_step(self.state, batch, step_rng)

@@ -32,6 +32,8 @@ pytestmark = [pytest.mark.timeout(120), pytest.mark.observability]
 
 VOCAB, SEQ, LATENTS = 29, 16, 8
 WAITS = ("data_wait", "log_flush")
+#: what else has a reader since the start of a fit is counted by phase
+FIT_START = ("setup_state", "first_step", "first_step_lower", "first_step_backend")
 
 
 def _model():
@@ -249,7 +251,7 @@ def test_the_waits_are_counted_process_wide_without_a_tracer(tmp_path, clean_def
         # what nothing reads is not counted (the step's seconds are the
         # trainer_step_dispatch_ms histogram's sum)
         assert not {k for k in counters if k.endswith("_seconds_total")} - {
-            f"trainer_{phase}_seconds_total" for phase in WAITS}
+            f"trainer_{phase}_seconds_total" for phase in WAITS + FIT_START}
     assert trainer.registry is not default_registry()
     assert trainer.registry.counter("trainer_data_wait_seconds_total") == pytest.approx(
         default_registry().counter("trainer_data_wait_seconds_total"))
@@ -287,6 +289,7 @@ def test_a_capture_holds_the_phases_as_host_events_and_the_table_beside_it(
     if with_tracer:  # the spans are recorded as before, one per annotation and more
         assert len(tracer.spans(name="trainer.step")) == 4
         assert {s.name for s in tracer.spans()} == {
+            "trainer.setup_state", "trainer.first_step",
             "trainer.data_wait", "trainer.step", "trainer.log_flush"}
 
 
