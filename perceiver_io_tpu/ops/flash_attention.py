@@ -35,6 +35,15 @@ float32 — cheap because every Perceiver query length is the latent count,
 not the sequence length. Matmuls feed the MXU in the input dtype (bf16 in
 training) with float32 accumulation; softmax math is float32 on the VPU.
 
+The forward keeps the running max and sum lane-dense, ``(rows, 128)`` with
+every lane equal, and widens them to the score tile's lanes, and to the
+accumulator's where its width is a multiple of 128, with ``pltpu.repeat``:
+read as a ``(rows, 1)`` column and broadcast back, as they were, they cost 9
+to 43 % of the kernel on a v5e (the table above ``_forward``). A row's
+arithmetic is the same either way: the same maximum over the same keys, the
+same ``exp``, the same float32 sum and the same two products, so ``o`` and
+``lse`` are the same bits.
+
 The backward is one kernel, ``flash_bwd_dkv``, wherever the float32 dQ of one
 query head fits ``_DQ_VMEM_BUDGET_BYTES`` (16 MiB: 32,768 rows at up to 128
 channels). Its grid is ``(b, hk * slices, j_blocks, g * i_blocks)``: dK and dV
@@ -439,6 +448,28 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
 )
 
+# The forward alone on a v5e at every shape the benchmark's cells run (bfloat16,
+# ms a call, host clock over back-to-back calls, median of 7 interleaved
+# samples, each within 0.4 % but the 256 x 256 shape's, 4 %; PERF.md), with the
+# running max and sum kept as a (rows, 1) column and broadcast back, as before,
+# or lane-dense, (rows, 128); and two other bodies, lane-dense too: the q block
+# as 2 or 4 independent row slices unrolled in one grid step, and kv sub-blocks
+# of 256 keys each with its own online update:
+#   q x kv, heads x width, batch     column  lane-dense  2 / 4 row slices  kv 256
+#   1024 x 4608 causal, 8 x 64, 32    11.33     8.45       8.70 /  8.94       -
+#   1024 x 1024 causal, 8 x 64, 32     2.62     2.09       2.12 /  2.24       -
+#   256 x 256, 8 x 32 / 160, 32        0.43     0.39       0.38 /    -        -
+#   256 x 2048, 8 x 32 / 160, 32       2.29     2.02       2.12 /    -        -
+#   2048 x 256, 8 x 32 / 96, 32        3.06     2.34       2.41 /  2.54       -
+#   8192 causal, 32 on 8 x 64, 2      22.30    16.56      16.80 / 17.96   25.80
+#   8192 causal, 20 x 256, 1           8.25     7.00       7.08 /  7.66    6.56
+#   16,384 causal, 28 on 4 x 128      37.97    22.36      21.96 / 26.02   25.53
+#   16,384, 4096 window, same         16.13     9.21       9.06 / 10.37   10.58
+# The column was the cost: lane-dense m and l take 9 to 43 % off at every shape
+# and give the same bits. Row slices gain 1.6 to 1.8 % at 128-wide heads alone,
+# about 0.2 % of that cell's step, and lose elsewhere; sub-blocks
+# of 256 keys gain 6 % at 256-wide heads alone but round a row's sums in
+# another order. Neither is taken.
 def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, h, i, d = q.shape
     j, dv = k.shape[2], v.shape[3]
@@ -477,19 +508,22 @@ def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarra
             if allowed is not None:
                 s = jnp.where(allowed, s, _MASK)
 
-            m_prev = m_sc[:, :1]
+            # m and l stay lane-dense, (bi, 128) with every lane equal: as a
+            # (bi, 1) column broadcast back they cost 9 to 43 % of the kernel
+            m_prev = m_sc[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - pltpu.repeat(m_new, bj // LANES, 1))
             if allowed is not None:
                 p = jnp.where(allowed, p, 0.0)
             alpha = jnp.exp(m_prev - m_new)
-            l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-            acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
+            l_new = alpha * l_sc[:] + jnp.sum(p, axis=1, keepdims=True)
+            alpha_o = pltpu.repeat(alpha, dv // LANES, 1) if dv % LANES == 0 else alpha[:, :1]
+            acc_sc[:] = acc_sc[:] * alpha_o + jax.lax.dot_general(
                 p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
-            l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
+            m_sc[:] = m_new
+            l_sc[:] = l_new
 
         _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal, window), body)
 

@@ -591,3 +591,76 @@ def test_window_counter_counts_traced_window_calls_and_is_declared_by_any_call(m
     from perceiver_io_tpu.observability.exporters import HELP_TEXT
 
     assert "flash_window_call_total" in HELP_TEXT
+
+
+# -- the forward's lane-dense running max and sum, at 512-row blocks ----------
+WIDE_BLOCK_CASES = {
+    # (h, hk, i, j, d, dv, causal, window, padded keys): q blocks of 512 rows
+    "causal_two_q_blocks_offset": (1, 1, 1024, 1536, 64, 64, True, None, 0),
+    "window_scaled_down": (1, 1, 1536, 1536, 128, 128, True, 600, 0),  # as 4096 of 16,384: 3 kv blocks a band
+    "pad_and_dead_rows": (1, 1, 512, 1024, 64, 64, True, None, 600),  # rows 0..87 see padding alone
+    "grouped_heads_128": (2, 1, 512, 1024, 128, 128, True, None, 0),
+    "wide_256": (1, 1, 512, 1024, 256, 256, True, None, 0),
+    "decoder_widths": (1, 1, 1024, 256, 32, 96, False, None, 0),
+}
+
+
+def _wide_block_inputs(rng, case):
+    h, hk, i, j, d, dv, causal, window, padded = WIDE_BLOCK_CASES[case]
+    q = jnp.asarray(rng.standard_normal((1, h, i, d)), jnp.float32) * d**-0.5
+    k = jnp.asarray(rng.standard_normal((1, hk, j, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, hk, j, dv)), jnp.float32)
+    pad = jnp.zeros((1, j), bool).at[:, :padded].set(True) if padded else None
+    # a dead row (its whole window is padding) is zero here and uniform on the einsum path
+    alive = np.arange(i) + (j - i) >= padded
+    return q, k, v, pad, causal, window, alive
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_BLOCK_CASES))
+def test_forward_at_512_row_blocks_matches_einsum(rng, case):
+    """The forward's output at q blocks of 512 rows, with the running max and
+    sum kept lane-dense, against the einsum path at the file's tolerances."""
+    q, k, v, pad, causal, window, alive = _wide_block_inputs(rng, case)
+    out = flash_attention.flash_attention(q, k, v, pad_mask=pad, causal=causal, window=window)
+    expected = dot_product_attention(q, k, v, pad_mask=pad, causal=causal, window=window, impl="xla")
+    np.testing.assert_allclose(np.asarray(out)[:, :, alive], np.asarray(expected)[:, :, alive], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_BLOCK_CASES))
+def test_forward_lse_at_512_row_blocks_is_each_rows_logsumexp(rng, case):
+    """The log-sum-exp the backward reads, every one of its 128 lanes: each
+    live row's log-sum-exp over the keys it sees, from the scores in float64."""
+    q, k, v, pad, causal, window, alive = _wide_block_inputs(rng, case)
+    pad3 = None if pad is None else pad.astype(jnp.float32)[:, None, :]
+    _, lse = flash_attention._forward(q, k, v, pad3, causal, window)
+    lse = np.asarray(lse)
+    assert lse.shape == (*q.shape[:3], flash_attention.LANES)
+    np.testing.assert_array_equal(lse, np.broadcast_to(lse[..., :1], lse.shape))
+    i, j = q.shape[2], k.shape[2]
+    kk = np.repeat(np.asarray(k, np.float64), q.shape[1] // k.shape[1], axis=1)
+    s = np.einsum("bhid,bhjd->bhij", np.asarray(q, np.float64), kk)
+    rows, cols = np.arange(i)[:, None] + (j - i), np.arange(j)[None, :]
+    seen = np.ones((i, j), bool) if not causal else cols <= rows
+    if window is not None:
+        seen &= cols > rows - window
+    if pad is not None:
+        seen &= ~np.asarray(pad)[0][None, :]
+    s = np.where(seen, s, -np.inf)[:, :, alive]
+    top = s.max(axis=-1, keepdims=True)
+    expected = (top + np.log(np.exp(s - top).sum(axis=-1, keepdims=True)))[..., 0]
+    np.testing.assert_allclose(lse[:, :, alive, 0], expected, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["causal_two_q_blocks_offset", "window_scaled_down", "pad_and_dead_rows"])
+def test_gradients_through_the_wide_block_forward_match(rng, case):
+    """The backward reads the forward's log-sum-exp and output: at q blocks of
+    512 rows its gradients match the einsum path's at the file's tolerances."""
+    q, k, v, pad, causal, window, alive = _wide_block_inputs(rng, case)
+    cot = jnp.asarray(rng.standard_normal((*q.shape[:3], v.shape[3])), jnp.float32) * alive[:, None]
+    flash = _grads(lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, pad_mask=pad, causal=causal, window=window), q, k, v, cot)
+    einsum = _grads(lambda q, k, v: dot_product_attention(
+        q, k, v, pad_mask=pad, causal=causal, window=window, impl="xla"), q, k, v, cot)
+    for a, e, name in zip(flash, einsum, "qkv"):
+        a, e = np.asarray(a), np.asarray(e)
+        np.testing.assert_allclose(a, e, atol=1e-4 * max(1.0, np.abs(e).max()), rtol=1e-4, err_msg=f"d{name}")

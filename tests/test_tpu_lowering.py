@@ -554,3 +554,31 @@ def test_a_head_whose_dq_alone_is_over_the_budget_still_lowers_as_two_kernels():
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
     assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
     assert "scoped_memory_configs" not in text  # no ``vmem_limit_bytes``: each under the 16 MiB a kernel gets without asking
+
+
+@pytest.mark.parametrize(
+    "b,h,hk,i,j,d,dv,causal,window,pad",
+    [(32, 8, 8, 1024, 4608, 64, 64, True, None, True), (32, 8, 8, 1024, 1024, 64, 64, True, None, False),
+     (32, 8, 8, 256, 256, 32, 160, False, None, False), (32, 8, 8, 256, 2048, 32, 160, False, None, True),
+     (32, 8, 8, 2048, 256, 32, 96, False, None, False), (2, 32, 8, 8192, 8192, 64, 64, True, None, False),
+     (1, 20, 20, 8192, 8192, 256, 256, True, None, False),
+     (1, 28, 4, 16384, 16384, 128, 128, True, None, False),
+     (1, 28, 4, 16384, 16384, 128, 128, True, 4096, False)],
+    ids=["ar_cross_attention", "ar_self_attention", "mlm_self_attention", "mlm_encoder", "mlm_decoder",
+         "lfm2moe_cell", "glm47flash_cell", "smallthinker_global", "smallthinker_window"],
+)
+def test_forward_at_the_cells_shapes_lowers_as_flash_fwd_on_its_grid(b, h, hk, i, j, d, dv, causal, window, pad):
+    """Each forward the five cells run, lowered for the TPU through Mosaic:
+    one kernel named ``flash_fwd`` on the grid it has always had (every q
+    block, and every kv block or a band's), two products a grid step."""
+    q = jax.ShapeDtypeStruct((b, h, i, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, hk, j, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, hk, j, dv), jnp.bfloat16)
+    pad_mask = jax.ShapeDtypeStruct((b, 1, j), jnp.float32) if pad else None
+    call = lambda q, k, v, pad_mask: flash_attention._forward(q, k, v, pad_mask, causal, window)
+    assert _kernel_names(call, q, k, v, pad_mask) == (["flash_fwd"], {"flash_fwd"})
+    bi, bj = flash_attention._pick_block(i), flash_attention._pick_block(j)
+    steps = j // bj if window is None else flash_attention._Band(bi, bj, i // bi, j // bj, j - i, window).kv_blocks
+    jaxpr = str(jax.make_jaxpr(call)(q, k, v, pad_mask))
+    assert set(re.findall(r"grid=\(([^)]*)\)", jaxpr)) == {f"{b}, {h}, {i // bi}, {steps}"}
+    assert jaxpr.count("dot_general") == 2 * 2  # the Mosaic branch and the interpreter's
