@@ -85,11 +85,15 @@ def _remat_policy(offload: bool):
     pinned host memory — HBM holds no per-layer activations between forward
     and backward but the kernels' two."""
     from perceiver_io_tpu.ops.flash_attention import SAVED_NAMES
+    from perceiver_io_tpu.ops.sparse_attention import SELECTION_NAME
 
+    # a sparse layer's packed selection (32 MB at 16,384 positions) is kept
+    # too: its backward does not run the indexer's selection again
+    saved = (*SAVED_NAMES, SELECTION_NAME)
     if not offload:
-        return jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+        return jax.checkpoint_policies.save_only_these_names(*saved)
     return jax.checkpoint_policies.save_and_offload_only_these_names(
-        names_which_can_be_saved=list(SAVED_NAMES),
+        names_which_can_be_saved=list(saved),
         names_which_can_be_offloaded=["remat_layer_input"],
         offload_src="device",
         offload_dst="pinned_host",
@@ -328,6 +332,39 @@ class LatentAttention(nn.Module):
         with jax.named_scope("latent_assemble"):
             o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
         return dense(self.num_input_channels, "o_proj")(o)
+
+
+class Indexer(nn.Module):
+    """The lightning indexer of a learned sparse attention layer (the
+    DeepSeek-V3.2 form without a query latent; docs/lm.md): from ``u`` ``(b,
+    n, c)``, ``q_i = rope(u Wq)`` to ``num_heads`` heads of ``head_dim``,
+    ``k_i = rope(LN(u Wk))`` one head of ``head_dim`` shared by all, and the
+    heads' weights ``w = u Ww / sqrt(num_heads * head_dim)`` in float32. The
+    rotation is over the whole head. Returns ``(q_i (b, n, H, d), k_i (b, n,
+    d), w (b, n, H))``; ``ops/sparse_attention.py`` scores and selects from
+    them. The projections are named apart from the attention's (``wq``,
+    ``wk``, ``weights_proj``), so that no head-parallel sharding rule reads
+    them as the attention's heads (``parallel/partition.py``)."""
+
+    num_heads: int
+    head_dim: int
+    norm_eps: float = 1e-6
+    init_scale: float = 0.02
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u: jnp.ndarray, rot_pos_emb: Optional[RotaryEmbedding] = None):
+        b, n, _ = u.shape
+        h, d = self.num_heads, self.head_dim
+        q = _dense(h * d, False, self.init_scale, self.dtype, "wq")(u)
+        k = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype, name="k_norm",
+                         use_fast_variance=False)(_dense(d, False, self.init_scale, self.dtype, "wk")(u))
+        if rot_pos_emb is not None:
+            with jax.named_scope("rotary"):
+                q, k = rot_pos_emb.rotate(q, h), rot_pos_emb.rotate(k, 1)
+        # float32 out: the scores, and with them the selection, are float32
+        w = _dense(h, False, self.init_scale, jnp.float32, "weights_proj")(u) * (h * d) ** -0.5
+        return q.reshape(b, n, h, d), k, w
 
 
 class CrossAttention(nn.Module):

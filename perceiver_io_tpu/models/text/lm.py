@@ -2,11 +2,13 @@
 (docs/lm.md): every layer is ``h = h + operator(rms(h))`` then
 ``h = h + ffn(rms(h))``, where the operator is a gated short convolution
 (``"conv"``), causal grouped-query attention over every earlier position
-(``"full_attention"``) or over the ``sliding_window`` latest
-(``"window_attention"``), each with RMS-normed q and k unless ``qk_norm`` is
-off and with whole-head rotary if its kind is among ``rotary_layer_types``,
-or causal attention out of low-rank latents with one shared rotary key head
-(``"latent_attention"``), and the feed-forward is a gated MLP in the first
+(``"full_attention"``), over the ``sliding_window`` latest
+(``"window_attention"``) or over the ``index_topk`` earlier positions a
+lightning indexer selects for each query (``"sparse_attention"``, whose
+indexer has a loss of its own), each with RMS-normed q and k unless
+``qk_norm`` is off and with whole-head rotary if its kind is among
+``rotary_layer_types``, or causal attention out of low-rank latents with one
+shared rotary key head (``"latent_attention"``), and the feed-forward is a gated MLP in the first
 ``num_dense_layers`` layers and a layer of sparse experts, with
 ``num_shared_experts`` experts beside them that every token takes, in the
 others (their router scores by a sigmoid or by a softmax over the chosen
@@ -29,19 +31,25 @@ from jax.ad_checkpoint import checkpoint_name
 from perceiver_io_tpu.models.core.config import register_config
 from perceiver_io_tpu.models.core.hybrid import GatedMLP, ShortConv, SparseExperts
 from perceiver_io_tpu.models.core.modules import (
+    Indexer,
     LatentAttention,
     MultiHeadAttention,
     RMSNorm,
     _remat_policy,
 )
 from perceiver_io_tpu.models.sequence import TiedOutputAdapter
+from perceiver_io_tpu.ops import sparse_attention
+from perceiver_io_tpu.ops.attention import selected_attention
 from perceiver_io_tpu.ops.position import RotaryEmbedding, frequency_position_encoding, positions
 
-LAYER_TYPES = ("conv", "full_attention", "window_attention", "latent_attention")
+LAYER_TYPES = ("conv", "full_attention", "window_attention", "latent_attention", "sparse_attention")
 #: the operator kinds that are grouped-query attention over plain heads
-_HEAD_ATTENTION = ("full_attention", "window_attention")
+_HEAD_ATTENTION = ("full_attention", "window_attention", "sparse_attention")
 #: the named scope around each kind's attention (docs/observability.md)
-ATTENTION_SCOPES = {"full_attention": "global_attention", "window_attention": "window_attention"}
+ATTENTION_SCOPES = {"full_attention": "global_attention", "window_attention": "window_attention",
+                    "sparse_attention": "sparse_attention"}
+#: the named scope around a sparse layer's indexer: projections, selection, loss
+INDEXER_SCOPE = "indexer"
 
 
 @register_config
@@ -63,11 +71,16 @@ class DecoderLMConfig:
     ``num_nextn_predict_layers`` (0 or 1) adds the multi-token-prediction
     module, whose loss ``lm_loss_fn`` adds under ``mtp_loss_weight``.
 
-    ``full_attention`` and ``window_attention`` layers have ``num_heads``
-    query heads on ``num_kv_heads`` key-value heads of ``head_dim`` channels
-    (0: ``num_channels / num_heads``), RMS-normed q and k if ``qk_norm``; a
-    ``window_attention`` layer sees the ``sliding_window`` latest positions,
-    its own among them. ``rotary_layer_types`` names the operator kinds whose
+    ``full_attention``, ``window_attention`` and ``sparse_attention`` layers
+    have ``num_heads`` query heads on ``num_kv_heads`` key-value heads of
+    ``head_dim`` channels (0: ``num_channels / num_heads``), RMS-normed q and
+    k if ``qk_norm``; a ``window_attention`` layer sees the ``sliding_window``
+    latest positions, its own among them; in a ``sparse_attention`` layer
+    query ``t`` sees the ``min(t + 1, index_topk)`` earlier positions of
+    largest score by an indexer of ``index_n_heads`` heads of
+    ``index_head_dim`` channels (every earlier position where a row has no
+    more than ``index_topk``), and the layer's indexer loss is added to the
+    model's (``lm_loss_fn``). ``rotary_layer_types`` names the operator kinds whose
     q and k are rotated: a kind left out gets no position signal and no
     rotary table is built for it. Experts: ``router_score`` is ``sigmoid``
     (the chosen experts' sigmoid scores, normalised if ``norm_topk_prob``) or
@@ -110,10 +123,13 @@ class DecoderLMConfig:
     head_dim: int = 0
     qk_norm: bool = True
     sliding_window: int = 0
-    rotary_layer_types: Tuple[str, ...] = ("full_attention", "window_attention", "latent_attention")
+    rotary_layer_types: Tuple[str, ...] = ("full_attention", "window_attention", "latent_attention", "sparse_attention")
     router_score: str = "sigmoid"
     expert_activation: str = "silu"
     router_input: str = "ffn"
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         self.layer_types = tuple(self.layer_types)
@@ -128,6 +144,12 @@ class DecoderLMConfig:
                 raise ValueError("a rotated head has an even number of channels")
         if "window_attention" in self.layer_types and self.sliding_window < 1:
             raise ValueError("window_attention layers need sliding_window >= 1")
+        if "sparse_attention" in self.layer_types:
+            if min(self.index_n_heads, self.index_head_dim, self.index_topk) < 1 or self.index_head_dim % 2:
+                raise ValueError("sparse_attention layers need index_n_heads, index_head_dim (even) "
+                                 "and index_topk positive")
+            if self.num_nextn_predict_layers:
+                raise ValueError("the prediction module's layer cannot be a sparse_attention layer")
         if self.router_input not in ("ffn", "operator"):
             raise ValueError(f"router_input is 'ffn' or 'operator', not {self.router_input!r}")
         if "latent_attention" in self.layer_types:
@@ -150,6 +172,10 @@ class DecoderLMConfig:
         return self.head_dim or self.num_channels // self.num_heads
 
     @property
+    def has_indexer(self) -> bool:
+        return "sparse_attention" in self.layer_types
+
+    @property
     def has_experts(self) -> bool:
         return self.num_dense_layers < self.num_layers or self.num_nextn_predict_layers > 0
 
@@ -157,7 +183,8 @@ class DecoderLMConfig:
 class DecoderLayer(nn.Module):
     """One layer; returns ``(h, stats)`` with the expert layer's ``[pairs
     computed, load max over mean, ran on the row bound]`` (zeros in a dense
-    layer)."""
+    layer) and, in a model with an indexer, the layer's indexer loss after
+    them (0 in a layer without one)."""
 
     config: DecoderLMConfig
     layer_type: str
@@ -166,11 +193,15 @@ class DecoderLayer(nn.Module):
     attention_impl: str = "auto"
 
     @nn.compact
-    def __call__(self, h, pad_mask: Optional[jnp.ndarray], rot: Optional[RotaryEmbedding]):
+    def __call__(self, h, pad_mask: Optional[jnp.ndarray], rot: Optional[RotaryEmbedding],
+                 index_rot: Optional[RotaryEmbedding] = None):
         cfg = self.config
         h = checkpoint_name(h, "remat_layer_input")
         u = RMSNorm(cfg.norm_eps, self.dtype, name="operator_norm")(h)
-        if self.layer_type == "conv":
+        index_loss = jnp.zeros((), jnp.float32)
+        if self.layer_type == "sparse_attention":
+            op, index_loss = self._sparse_attention(u, pad_mask, rot, index_rot)
+        elif self.layer_type == "conv":
             op = ShortConv(
                 cfg.num_channels, cfg.conv_kernel_size, cfg.init_scale, self.dtype, name="conv"
             )(u, pad_mask)
@@ -183,19 +214,10 @@ class DecoderLayer(nn.Module):
                 dtype=self.dtype, attention_impl=self.attention_impl, name="attention",
             )(u, pad_mask, rot)
         else:
-            # the published head width, whatever num_channels / num_heads is;
-            # left unset where the two agree, as the module always was built
-            width = cfg.num_heads * cfg.head_dim if cfg.head_dim else None
             window = cfg.sliding_window if self.layer_type == "window_attention" else None
             with jax.named_scope(ATTENTION_SCOPES[self.layer_type]):
-                op = MultiHeadAttention(
-                    num_heads=cfg.num_heads, num_q_input_channels=cfg.num_channels,
-                    num_kv_input_channels=cfg.num_channels, num_qk_channels=width,
-                    causal_attention=True, qkv_bias=False, out_bias=False,
-                    init_scale=cfg.init_scale, dtype=self.dtype,
-                    attention_impl=self.attention_impl, num_kv_heads=cfg.num_kv_heads,
-                    qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps, window=window, name="attention",
-                )(u, u, pad_mask=pad_mask, rot_pos_emb_q=rot, rot_pos_emb_k=rot)
+                op = self._head_attention(window)(
+                    u, u, pad_mask=pad_mask, rot_pos_emb_q=rot, rot_pos_emb_k=rot)
         h = h + op
         seen = u if cfg.router_input == "operator" else None  # what the router reads, if not its own
         u = RMSNorm(cfg.norm_eps, self.dtype, name="ffn_norm")(h)
@@ -217,7 +239,55 @@ class DecoderLayer(nn.Module):
                 out = out + GatedMLP(
                     cfg.num_channels, cfg.num_shared_experts * cfg.expert_channels, cfg.init_scale,
                     self.dtype, name="shared_expert")(u)
+        if cfg.has_indexer:
+            stats = jnp.concatenate([stats, index_loss[None]])
         return h + out, stats
+
+    def _head_attention(self, window=None) -> MultiHeadAttention:
+        """The grouped-query attention module of the plain-head kinds."""
+        cfg = self.config
+        return MultiHeadAttention(
+            num_heads=cfg.num_heads, num_q_input_channels=cfg.num_channels,
+            num_kv_input_channels=cfg.num_channels,
+            # the published head width, whatever num_channels / num_heads is;
+            # left unset where the two agree, as the module always was built
+            num_qk_channels=cfg.num_heads * cfg.head_dim if cfg.head_dim else None,
+            causal_attention=True, qkv_bias=False, out_bias=False,
+            init_scale=cfg.init_scale, dtype=self.dtype,
+            attention_impl=self.attention_impl, num_kv_heads=cfg.num_kv_heads,
+            qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps, window=window, name="attention",
+        )
+
+    def _sparse_attention(self, u, pad_mask, rot, index_rot):
+        """The attention of a ``sparse_attention`` layer and its indexer's
+        loss. The indexer reads ``u`` detached and selects each query's keys
+        (exactly, ``ops/sparse_attention.py``) where a row has more positions
+        than ``index_topk``; the attention is the grouped-query attention of
+        the other kinds over those keys alone; the loss holds the indexer's
+        scores to the heads' mean of the attention's probabilities over them,
+        detached. So the LM loss trains all but the indexer, the indexer loss
+        the indexer alone."""
+        cfg = self.config
+        with jax.named_scope(INDEXER_SCOPE):
+            q_i, k_i, w = Indexer(
+                cfg.index_n_heads, cfg.index_head_dim, cfg.norm_eps, cfg.init_scale, self.dtype,
+                name="indexer")(jax.lax.stop_gradient(u), index_rot)
+            bits = None
+            if u.shape[1] > cfg.index_topk:
+                bits = checkpoint_name(
+                    sparse_attention.select(q_i, k_i, w, cfg.index_topk), sparse_attention.SELECTION_NAME)
+        with jax.named_scope(ATTENTION_SCOPES["sparse_attention"]):
+            attention = self._head_attention()
+            q = attention.project_q(u, rot)
+            k, v = attention.project_kv(u, rot)
+            if bits is None:  # every causal key is selected: the causal path
+                op, lse = attention.attend(q, k, v, pad_mask=pad_mask), None
+            else:
+                o, lse = selected_attention(q, k, v, bits, pad_mask=pad_mask, impl=self.attention_impl)
+                op = attention.project_out(o)
+        with jax.named_scope(INDEXER_SCOPE):
+            loss = sparse_attention.indexer_loss(q, k, lse, q_i, k_i, w, bits)
+        return op, loss
 
 
 def _layer_class(cfg: DecoderLMConfig):
@@ -259,8 +329,9 @@ class DecoderLM(nn.Module):
     ``return_stats`` also ``{"moe_assignments_held", "moe_expert_load_max_over_mean",
     "moe_layers_bounded"}``: token-expert pairs computed by the held experts,
     summed over the expert layers; the worst layer's fullest held expert over
-    its mean; and the expert layers whose held pairs fitted the row bound.
-    With ``next_ids`` ``(b, n)``, the token after each position, a model with
+    its mean; and the expert layers whose held pairs fitted the row bound. A
+    model with ``sparse_attention`` layers adds ``"indexer_loss"``, their
+    indexers' losses summed. With ``next_ids`` ``(b, n)``, the token after each position, a model with
     the prediction module returns ``(logits, mtp_logits)`` in the logits'
     place: ``mtp_logits[:, i]`` predicts the token after ``next_ids[:, i]``,
     and the module's expert layer counts in the stats."""
@@ -314,16 +385,22 @@ class DecoderLM(nn.Module):
         if set(_HEAD_ATTENTION) & set(cfg.layer_types):
             widths.update(dict.fromkeys(_HEAD_ATTENTION, cfg.attention_head_dim))
         tables, rots = {}, {}
+
+        def table(width):
+            if width not in tables:
+                tables[width] = RotaryEmbedding(frequency_position_encoding(pos, width, cfg.rope_theta))
+            return tables[width]
+
         for kind, width in widths.items():
             if kind in cfg.layer_types and kind in cfg.rotary_layer_types:
-                if width not in tables:
-                    tables[width] = RotaryEmbedding(
-                        frequency_position_encoding(pos, width, cfg.rope_theta))
-                rots[kind] = tables[width]
+                rots[kind] = table(width)
+        # an indexer's heads are rotated with its layer's kind
+        index_rot = table(cfg.index_head_dim) if "sparse_attention" in rots else None
         h = self.embed(x).astype(self.dtype)
         stats = []
         for layer, kind in zip(self.layers, cfg.layer_types):
-            h, s = layer(h, pad_mask, rots.get(kind))
+            extra = (index_rot,) if kind == "sparse_attention" else ()
+            h, s = layer(h, pad_mask, rots.get(kind), *extra)
             stats.append(s)
         logits = self._logits(self.out_norm(h))
         if next_ids is not None:
@@ -336,8 +413,11 @@ class DecoderLM(nn.Module):
         if not return_stats:
             return logits
         stats = jnp.stack(stats)
-        return logits, {
+        out = {
             "moe_assignments_held": stats[:, 0].sum(),
             "moe_expert_load_max_over_mean": stats[:, 1].max(),
             "moe_layers_bounded": stats[:, 2].sum(),
         }
+        if cfg.has_indexer:
+            out["indexer_loss"] = stats[:, 3].sum()
+        return logits, out

@@ -85,6 +85,7 @@ def dot_product_attention(
     """
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window} needs causal=True and at least one key")
+    _declare_selection_counter()
     if impl == "ring":
         if dropout_rate > 0.0:
             raise ValueError("ring attention does not support attention dropout")
@@ -147,6 +148,50 @@ def dot_product_attention(
     return jnp.concatenate(chunks, axis=1)
 
 
+def selected_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    bits: jnp.ndarray,
+    *,
+    pad_mask: Optional[jnp.ndarray] = None,
+    impl: str = "auto",
+):
+    """Causal attention of each query over the keys a learned selection
+    keeps (``ops/sparse_attention.py``), and its log-sum-exp: ``(o, lse)``,
+    ``lse`` ``(b, h, i)`` float32 without a gradient. ``bits`` is the packed
+    ``(b, i / 32, j)`` selection. The flash kernels take it where they take
+    the shapes (``impl`` as in :func:`dot_product_attention`); the einsum
+    path holds the ``(b, h, i, j)`` scores whole. Counted at trace time in
+    ``sparse_attention_call_total``."""
+    from perceiver_io_tpu.observability import default_registry
+    from perceiver_io_tpu.ops import sparse_attention
+
+    _declare_selection_counter()
+    default_registry().inc(sparse_attention.SELECTION_COUNTER)
+    if impl == "flash" or (impl == "auto" and _flash_eligible(0.0)):
+        from perceiver_io_tpu.ops import flash_attention
+
+        if (flash_attention.supported(q, k, v, causal=True)
+                and flash_attention.selection_blocks_fit(q.shape[2])):
+            return _flash_over_mesh(q, k, v, pad_mask, True, selection=bits)
+        if impl == "flash":
+            raise ValueError(
+                f"flash attention with a selection is unsupported for shapes q={q.shape} k={k.shape}")
+        if q.shape[2] >= flash_attention.LANES:
+            _count_einsum_fallback(q, k, v, True)
+    return sparse_attention.attention_xla(q, k, v, bits, pad_mask)
+
+
+def _declare_selection_counter() -> None:
+    """Trace time: every attention call declares the selection counter, so a
+    program whose layers all ran dense exports it at 0."""
+    from perceiver_io_tpu.observability import default_registry
+    from perceiver_io_tpu.ops.sparse_attention import SELECTION_COUNTER
+
+    default_registry().declare_counters(SELECTION_COUNTER)
+
+
 def _ambient_mesh():
     """The mesh of the enclosing ``jax.set_mesh`` /
     ``jax.sharding.use_abstract_mesh`` context, or None outside one."""
@@ -154,18 +199,21 @@ def _ambient_mesh():
     return None if mesh.empty else mesh
 
 
-def _flash_over_mesh(q, k, v, pad_mask, causal, window=None):
+def _flash_over_mesh(q, k, v, pad_mask, causal, window=None, selection=None):
     """The flash kernel, inside ``shard_map`` when the ambient mesh has more
     than one device: batch over the ``data``/``fsdp`` axes, heads over
     ``model``. A dim its axes do not divide stays replicated (a batch-1
-    prefill on a data-sharded serving mesh)."""
+    prefill on a data-sharded serving mesh). With ``selection`` (packed bits,
+    by batch) the selected kernels and ``(o, lse)``."""
     from jax.sharding import PartitionSpec as P
 
-    from perceiver_io_tpu.ops.flash_attention import flash_attention
+    from perceiver_io_tpu.ops.flash_attention import flash_attention, flash_attention_selected
     from perceiver_io_tpu.parallel.mesh import AXIS_MODEL, AXIS_SEQ, BATCH_AXES
 
     mesh = _ambient_mesh()
     if mesh is None or mesh.size == 1:
+        if selection is not None:
+            return flash_attention_selected(q, k, v, selection, pad_mask=pad_mask)
         return flash_attention(q, k, v, pad_mask=pad_mask, causal=causal, window=window)
     if mesh.shape.get(AXIS_SEQ, 1) > 1:
         raise ValueError(
@@ -186,6 +234,16 @@ def _flash_over_mesh(q, k, v, pad_mask, causal, window=None):
     args, in_specs = (q, k, v), (qkv_spec,) * 3
     if pad_mask is not None:
         args, in_specs = args + (pad_mask,), in_specs + (P(batch_ax, None),)
+    if selection is not None:
+        args, in_specs = args + (selection,), in_specs + (P(batch_ax, None, None),)
+
+        def body(q_, k_, v_, *rest):
+            pad_ = rest[0] if pad_mask is not None else None
+            return flash_attention_selected(q_, k_, v_, rest[-1], pad_mask=pad_)
+
+        out_specs = (qkv_spec, P(batch_ax, head_ax, None))
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                             check_vma=False)(*args)
 
     def body(q_, k_, v_, pad_=None):
         return flash_attention(q_, k_, v_, pad_mask=pad_, causal=causal, window=window)

@@ -26,6 +26,15 @@ Perceiver specifics the stock kernels don't cover:
   adds the band's first block, and what little of that grid lies outside the
   band is skipped with its block index clamped, so it fetches nothing. A call
   without a window compiles to the kernels it always did.
+- **a selection of keys per query** (learned sparse attention,
+  :func:`flash_attention_selected`): query ``t`` sees the keys whose bits are
+  set in its row of a packed ``(b, i / 32, j)`` mask
+  (:mod:`perceiver_io_tpu.ops.sparse_attention` lays the bits out for the
+  kernels' row blocks), a subset of the causal ones. The three kernels take
+  the bits block by block and, through scalar prefetch, one flag a block
+  pair: a pair that holds no selected key is skipped and costs no products.
+  Every other pair costs what a causal pair costs. A call without a selection
+  compiles to the kernels it always did.
 
 Layout notes (mirroring what Mosaic compiles well): grid is
 ``(b, h, i_blocks, j_blocks)`` with the kv dimension innermost and
@@ -252,9 +261,14 @@ def _flash_fwd(q, k, v, pad, causal, window):
 
 
 def _flash_bwd(causal, window, res, do):
+    q, k, v, pad, o, lse = res
+    return _backward(q, k, v, pad, o, lse, do, causal, window)
+
+
+def _backward(q, k, v, pad, o, lse, do, causal, window, sel=None):
+    """``(dq, dk, dv, dpad)``: one kernel or, past the budget, two."""
     from perceiver_io_tpu.observability import default_registry
 
-    q, k, v, pad, o, lse = res
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, LANES))
     default_registry().declare_counters(_TWO_CALL_COUNTER, _SLICED_COUNTER)
@@ -262,17 +276,64 @@ def _flash_bwd(causal, window, res, do):
     if heads:
         if heads < q.shape[1] // k.shape[1]:
             default_registry().inc(_SLICED_COUNTER)  # trace time, as below
-        dk, dv, dq = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window, dq_heads=heads)
+        dk, dv, dq = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window, dq_heads=heads, sel=sel)
     else:
         # trace time, so once per traced backward; correct, only slower: no warning
         default_registry().inc(_TWO_CALL_COUNTER)
-        dq = _backward_dq(q, k, v, pad, lse, delta, do, causal, window)
-        dk, dv = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window)
+        dq = _backward_dq(q, k, v, pad, lse, delta, do, causal, window, sel=sel)
+        dk, dv = _backward_dkv(q, k, v, pad, lse, delta, do, causal, window, sel=sel)
     dpad = None if pad is None else jnp.zeros_like(pad)
     return dq, dk, dv, dpad
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention_selected(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    bits: jnp.ndarray,
+    *,
+    pad_mask: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Causal attention of each query over the keys its row of ``bits``
+    selects, and the rows' log-sum-exp.
+
+    :param bits: ``(b, i / 32, j)`` int32, the selection packed for the
+        kernels' row blocks (``sparse_attention.pack``); a subset of the
+        causal mask with at least one key a row (``i == j``).
+    :return: ``o`` ``(b, h, i, dv)`` and ``lse`` ``(b, h, i)`` float32 over
+        the selected keys. ``lse`` carries no gradient: it is for a reader
+        under ``stop_gradient`` (the indexer's loss).
+
+    Block pairs whose flag (:func:`block_flags`) is 0 cost nothing, forward
+    and backward. Under a mesh the caller wraps this in ``shard_map``, as
+    for :func:`flash_attention`."""
+    pad = None if pad_mask is None else pad_mask.astype(jnp.float32)[:, None, :]
+    o, lse = _flash_selected(q, k, v, pad, bits, block_flags(bits, k.shape[2]))
+    return o, lse[..., 0]
+
+
+@jax.custom_vjp
+def _flash_selected(q, k, v, pad, bits, flags):
+    return _forward(q, k, v, pad, True, sel=(bits, flags))
+
+
+def _flash_selected_fwd(q, k, v, pad, bits, flags):
+    o, lse = _forward(q, k, v, pad, True, sel=(bits, flags))
+    o = checkpoint_name(o, SAVED_NAMES[0])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
+    return (o, lse), (q, k, v, pad, bits, flags, o, lse)
+
+
+def _flash_selected_bwd(res, cts):
+    q, k, v, pad, bits, flags, o, lse = res
+    # the log-sum-exp's cotangent is dropped: it carries no gradient
+    return (*_backward(q, k, v, pad, o, lse, cts[0], True, None, sel=(bits, flags)), None, None)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
 
 
 def _resident_heads(q, k) -> int:
@@ -387,6 +448,59 @@ class _Band:
         return jnp.minimum(self.first_i(j_idx) + step, self.ni - 1)
 
 
+def _selected_block(words, bi: int):
+    """Boolean ``(bi, bj)``: the selection of one block pair, from its
+    ``(bi / 32, bj)`` words. Row ``r`` of the block is bit ``r // (bi / 32)``
+    of word ``r % (bi / 32)`` (``sparse_attention.pack``): the words stacked
+    32 times over give every row its word, and one shift a row its bit."""
+    per = bi // 32
+    rows = jnp.concatenate([words] * 32, axis=0)
+    shift = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0) >> (per.bit_length() - 1)
+    return (jnp.right_shift(rows, shift) & 1) != 0
+
+
+def selection_blocks_fit(i: int) -> bool:
+    """Whether the kernels can take a selection at ``i`` query rows: a block's
+    words, ``(bi / 32, bj)``, are whole sublane tiles or the whole array."""
+    bi = _pick_block(i)
+    return bi is not None and (bi // 32 % 8 == 0 or bi == i)
+
+
+def block_flags(bits: jnp.ndarray, j: int) -> jnp.ndarray:
+    """``(b * ni * nj,)`` int32: whether block pair ``(i, j)`` of row ``b``
+    holds a selected key, for the kernels' scalar prefetch."""
+    b, words, _ = bits.shape
+    bi, bj = _pick_block(words * 32), _pick_block(j)
+    blocks = bits.reshape(b, words * 32 // bi, bi // 32, j // bj, bj)
+    return jnp.any(blocks != 0, axis=(2, 4)).astype(jnp.int32).reshape(-1)
+
+
+def _flagged(flags_ref, i_idx, j_idx, ni: int, nj: int):
+    """The flag of block pair ``(i_idx, j_idx)`` of the grid's batch row."""
+    return flags_ref[(pl.program_id(0) * ni + i_idx) * nj + j_idx] != 0
+
+
+def _pallas(kernel, args, flags, *, name: str, grid, in_specs, out_specs, scratch_shapes, **spec):
+    """``pallas_call_on_lowering_platform`` as every kernel here makes it, or,
+    with ``flags``, the same kernel under a grid whose index maps and body
+    also receive the flags by scalar prefetch (the body's first ref)."""
+    if flags is None:
+        return pallas_call_on_lowering_platform(
+            kernel, *args, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes, **spec)
+
+    def prefetched(block):
+        return pl.BlockSpec(block.block_shape, lambda *idx: block.index_map(*idx[:-1]))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid, in_specs=[prefetched(b) for b in in_specs],
+        out_specs=(prefetched(out_specs) if isinstance(out_specs, pl.BlockSpec)
+                   else [prefetched(b) for b in out_specs]),
+        scratch_shapes=scratch_shapes,
+    )
+    return pallas_call_on_lowering_platform(kernel, flags, *args, name=name, grid_spec=grid_spec, **spec)
+
+
 def _maybe_when(run, body):
     if run is None:
         body()
@@ -470,7 +584,7 @@ _DIM_SEMANTICS = pltpu.CompilerParams(
 # about 0.2 % of that cell's step, and lose elsewhere; sub-blocks
 # of 256 keys gain 6 % at 256-wide heads alone but round a row's sums in
 # another order. Neither is taken.
-def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _forward(q, k, v, pad, causal, window=None, sel=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, h, i, d = q.shape
     j, dv = k.shape[2], v.shape[3]
     group = h // k.shape[1]
@@ -480,13 +594,18 @@ def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarra
     has_pad = pad is not None
     band = None if window is None else _Band(bi, bj, i // bi, nj, offset, window)
     steps = nj if band is None else band.kv_blocks  # grid dim 3
+    has_sel = sel is not None  # (bits, flags): the selection is a subset of the causal mask
 
-    def kernel(q_ref, k_ref, v_ref, *rest):
+    def kernel(*refs):
+        flags_ref = pad_ref = bits_ref = None
+        if has_sel:
+            flags_ref, *refs = refs
+        q_ref, k_ref, v_ref, *rest = refs
         if has_pad:
-            pad_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
-        else:
-            o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
-            pad_ref = None
+            pad_ref, *rest = rest
+        if has_sel:
+            bits_ref, *rest = rest
+        o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
         i_idx, step = pl.program_id(2), pl.program_id(3)
         j_idx = step if band is None else band.first_j(i_idx) + step
 
@@ -502,9 +621,12 @@ def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarra
                 preferred_element_type=jnp.float32,
             )
             allowed = _block_mask(
-                i_idx, j_idx, bi, bj, offset, causal,
+                i_idx, j_idx, bi, bj, offset, causal and not has_sel,
                 pad_ref[0] if has_pad else None, window,
             )
+            if has_sel:
+                chosen = _selected_block(bits_ref[0], bi)
+                allowed = chosen if allowed is None else jnp.logical_and(allowed, chosen)
             if allowed is not None:
                 s = jnp.where(allowed, s, _MASK)
 
@@ -525,7 +647,9 @@ def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarra
             m_sc[:] = m_new
             l_sc[:] = l_new
 
-        _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal, window), body)
+        run = (_flagged(flags_ref, i_idx, j_idx, i // bi, nj) if has_sel
+               else _run_block(i_idx, j_idx, bi, bj, offset, causal, window))
+        _maybe_when(run, body)
 
         @pl.when(step == steps - 1)
         def _():
@@ -538,10 +662,14 @@ def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarra
 
     args = [q, k, v] + ([pad] if has_pad else [])
     in_specs = _q_grid_specs(bi, bj, d, dv, group, has_pad, band)
+    if has_sel:
+        args.append(sel[0])
+        in_specs.append(pl.BlockSpec((1, bi // 32, bj), lambda b_, h_, x_, y_: (b_, x_, y_)))
 
-    out = pallas_call_on_lowering_platform(
+    out = _pallas(
         kernel,
-        *args,
+        args,
+        sel[1] if has_sel else None,
         name="flash_fwd",
         grid=(b, h, i // bi, steps),
         in_specs=in_specs,
@@ -563,7 +691,7 @@ def _forward(q, k, v, pad, causal, window=None) -> Tuple[jnp.ndarray, jnp.ndarra
     return out[0], out[1]
 
 
-def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
+def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None, sel=None):
     b, h, i, d = q.shape
     j, dv = k.shape[2], v.shape[3]
     group = h // k.shape[1]
@@ -573,13 +701,18 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
     has_pad = pad is not None
     band = None if window is None else _Band(bi, bj, i // bi, nj, offset, window)
     steps = nj if band is None else band.kv_blocks  # grid dim 3
+    has_sel = sel is not None
 
-    def kernel(q_ref, k_ref, v_ref, *rest):
+    def kernel(*refs):
+        flags_ref = pad_ref = bits_ref = None
+        if has_sel:
+            flags_ref, *refs = refs
+        q_ref, k_ref, v_ref, *rest = refs
         if has_pad:
-            pad_ref, lse_ref, delta_ref, do_ref, dq_ref, dq_sc = rest
-        else:
-            lse_ref, delta_ref, do_ref, dq_ref, dq_sc = rest
-            pad_ref = None
+            pad_ref, *rest = rest
+        if has_sel:
+            bits_ref, *rest = rest
+        lse_ref, delta_ref, do_ref, dq_ref, dq_sc = rest
         i_idx, step = pl.program_id(2), pl.program_id(3)
         j_idx = step if band is None else band.first_j(i_idx) + step
 
@@ -594,9 +727,12 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
                 preferred_element_type=jnp.float32,
             )
             allowed = _block_mask(
-                i_idx, j_idx, bi, bj, offset, causal,
+                i_idx, j_idx, bi, bj, offset, causal and not has_sel,
                 pad_ref[0] if has_pad else None, window,
             )
+            if has_sel:
+                chosen = _selected_block(bits_ref[0], bi)
+                allowed = chosen if allowed is None else jnp.logical_and(allowed, chosen)
             p = jnp.exp(s - lse_ref[0, 0][:, :1])
             if allowed is not None:
                 p = jnp.where(allowed, p, 0.0)
@@ -610,7 +746,9 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
                 preferred_element_type=jnp.float32,
             )
 
-        _maybe_when(_run_block(i_idx, j_idx, bi, bj, offset, causal, window), body)
+        run = (_flagged(flags_ref, i_idx, j_idx, i // bi, nj) if has_sel
+               else _run_block(i_idx, j_idx, bi, bj, offset, causal, window))
+        _maybe_when(run, body)
 
         @pl.when(step == steps - 1)
         def _():
@@ -618,6 +756,9 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
 
     args = [q, k, v] + ([pad] if has_pad else [])
     in_specs = _q_grid_specs(bi, bj, d, dv, group, has_pad, band)
+    if has_sel:
+        args.append(sel[0])
+        in_specs.append(pl.BlockSpec((1, bi // 32, bj), lambda b_, h_, x_, y_: (b_, x_, y_)))
     in_specs += [
         _qk_spec(bi, LANES, by_dim2=True),
         _qk_spec(bi, LANES, by_dim2=True),
@@ -625,9 +766,10 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
     ]
     args += [lse, delta, do]
 
-    return pallas_call_on_lowering_platform(
+    return _pallas(
         kernel,
-        *args,
+        args,
+        sel[1] if has_sel else None,
         name="flash_bwd_dq",
         grid=(b, h, i // bi, steps),
         in_specs=in_specs,
@@ -638,7 +780,7 @@ def _backward_dq(q, k, v, pad, lse, delta, do, causal, window=None):
     )
 
 
-def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: int = 0):
+def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: int = 0, sel=None):
     """dK and dV, and with ``dq_heads`` dQ as a third output of the same
     kernel: ``dq_heads`` is how many of a key-value head's query heads keep
     their float32 dQ in VMEM at a time (:func:`_resident_heads`). The whole
@@ -674,10 +816,17 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: i
     # step's q block exists; past the last one it is held there and skipped),
     # and a q block's rows of dQ are zeroed by the first kv block of its band
     # and rounded by the last.
-    def kernel(q_ref, k_ref, v_ref, *rest):
-        pad_ref = None
+    has_sel = sel is not None
+
+    def kernel(*refs):
+        flags_ref = pad_ref = bits_ref = None
+        if has_sel:
+            flags_ref, *refs = refs
+        q_ref, k_ref, v_ref, *rest = refs
         if has_pad:
             pad_ref, *rest = rest
+        if has_sel:
+            bits_ref, *rest = rest
         if with_dq:
             lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dq_ref, dk_sc, dv_sc, dq_sc = rest
         else:
@@ -712,9 +861,12 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: i
                 preferred_element_type=jnp.float32,
             )
             allowed = _block_mask(
-                i_idx, j_idx, bi, bj, offset, causal,
+                i_idx, j_idx, bi, bj, offset, causal and not has_sel,
                 pad_ref[0] if has_pad else None, window,
             )
+            if has_sel:
+                chosen = _selected_block(bits_ref[0], bi)
+                allowed = chosen if allowed is None else jnp.logical_and(allowed, chosen)
             p = jnp.exp(s - lse_ref[0, 0][:, :1])
             if allowed is not None:
                 p = jnp.where(allowed, p, 0.0)
@@ -737,7 +889,10 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: i
                     preferred_element_type=jnp.float32,
                 )
 
-        run = _run_block(i_idx, j_idx, bi, bj, offset, causal, window)
+        if has_sel:
+            run = _flagged(flags_ref, i_idx, j_idx, ni, nj)
+        else:
+            run = _run_block(i_idx, j_idx, bi, bj, offset, causal, window)
         _maybe_when(run if band is None else inside & run, body)
 
         @pl.when(t_idx == walk * nq - 1)
@@ -770,6 +925,9 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: i
     if has_pad:
         in_specs.append(_pad_spec(bj, by_dim2=True))
         args.append(pad)
+    if has_sel:  # the bits of grid step y_'s q block against kv block x_
+        in_specs.append(pl.BlockSpec((1, bi // 32, bj), lambda b_, h_, x_, y_: (b_, y_ % ni, x_)))
+        args.append(sel[0])
     in_specs += [q_side(LANES), q_side(LANES), q_side(dv)]
     args += [lse, delta, do]
 
@@ -799,9 +957,10 @@ def _backward_dkv(q, k, v, pad, lse, delta, do, causal, window=None, dq_heads: i
                 bi, bj, d, dv, walk * i, q.dtype.itemsize, out_shape[0].dtype.itemsize),
         )
 
-    out = pallas_call_on_lowering_platform(
+    out = _pallas(
         kernel,
-        *args,
+        args,
+        sel[1] if has_sel else None,
         name="flash_bwd_dkv",
         grid=(b, hk * slices, nj, walk * nq),
         in_specs=in_specs,

@@ -58,6 +58,9 @@ _TP_KERNEL_RULES: Tuple[Tuple[str, int], ...] = (
     # never the expert dimension
     (r"moe/(gate|up)$", 2),
     (r"moe/down$", 1),
+    # no rule names a sparse layer's indexer (``indexer/wq``, ``wk``,
+    # ``weights_proj``): its scores sum over all of its heads, so it stays
+    # whole across ``model`` and only FSDP splits it
 )
 
 # Stacked expert weights: dim 0 counts experts, and a shard of it would be

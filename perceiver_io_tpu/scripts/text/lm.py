@@ -1,11 +1,16 @@
 """Decoder-only language model CLI: layers declared one by one (gated short
-convolutions, grouped-query attention over every earlier position or a
-sliding window, latent attention, sparse experts with shared ones beside
-them, a multi-token-prediction module; docs/lm.md):
+convolutions, grouped-query attention over every earlier position, a sliding
+window or the keys a learned indexer selects, latent attention, sparse
+experts with shared ones beside them, a multi-token-prediction module;
+docs/lm.md):
 
     python -m perceiver_io_tpu.scripts.text.lm fit --data=wikitext \
         --data.dataset_dir=.cache/wikitext --trainer.max_steps=10000 \
         --model.layer_types=conv,conv,full_attention,conv
+
+A ``sparse_attention`` layer takes its indexer from ``--model.index_n_heads``,
+``--model.index_head_dim`` and ``--model.index_topk``; the indexer's loss is
+the second term of the loss (``trainer_indexer_loss``).
 
 ``serve`` does not take this family yet and says so.
 """

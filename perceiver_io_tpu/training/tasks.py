@@ -73,7 +73,13 @@ def lm_loss_fn(model) -> Callable:
     the token after them, so its labels are the labels shifted by one more; a
     row's last position has none, nor has a position whose own label is
     ignored. ``loss = lm_loss + mtp_loss_weight * mtp_loss``, each a mean over
-    its own labels, and the metrics carry both terms."""
+    its own labels, and the metrics carry both terms.
+
+    A model with ``sparse_attention`` layers adds their indexers' loss
+    (``indexer_loss``, the layers' KL terms summed) with weight 1, and the
+    metrics carry ``lm_loss`` and ``indexer_loss``: the trainer's gauge
+    ``trainer_indexer_loss``. No gradient of the one reaches the other's
+    leaves (``models/text/lm.py``)."""
     cfg = model.config
 
     def loss_fn(params, batch, rng):
@@ -86,6 +92,8 @@ def lm_loss_fn(model) -> Callable:
                 {"params": params}, batch["input_ids"], pad_mask=pad_mask, return_stats=True
             )
             loss = masked_cross_entropy(logits, labels)
+            if cfg.has_indexer:
+                return loss + stats["indexer_loss"], {**stats, "lm_loss": loss}
             return loss, stats if cfg.has_experts else {}
         (logits, mtp_logits), stats = model.apply(
             {"params": params}, batch["input_ids"], pad_mask=pad_mask, return_stats=True,

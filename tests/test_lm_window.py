@@ -57,7 +57,7 @@ def test_config_round_trips_with_the_new_fields():
     back = config_from_dict(DecoderLMConfig, config_to_dict(cfg))
     assert back == cfg and back.attention_head_dim == 8
     assert DecoderLMConfig().attention_head_dim == 512 // 8
-    assert set(ATTENTION_SCOPES) == {"full_attention", "window_attention"}
+    assert set(ATTENTION_SCOPES) == {"full_attention", "window_attention", "sparse_attention"}
 
 
 def test_head_width_of_its_own_and_no_qk_norm_shape_the_tree():

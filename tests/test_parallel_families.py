@@ -325,3 +325,53 @@ def test_lm_window_model_shards_its_own_head_width_and_matches_one_device(axes):
             out.append((float(metrics["loss"]), float(metrics["moe_assignments_held"])))
         losses[name] = out
     np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=2e-4)
+
+
+def _lm_sparse_model():
+    from perceiver_io_tpu.models.text.lm import DecoderLM, DecoderLMConfig
+
+    cfg = DecoderLMConfig(
+        vocab_size=64, max_seq_len=64, num_channels=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        layer_types=("sparse_attention", "sparse_attention"), rotary_layer_types=("sparse_attention",),
+        index_n_heads=2, index_head_dim=16, index_topk=8, num_dense_layers=0, expert_channels=24,
+        router_width=16, num_experts=8, experts_per_token=3, use_expert_bias=False,
+        router_score="softmax_topk", tie_word_embeddings=False,
+    )
+    return DecoderLM(cfg, attention_impl="xla")
+
+
+def test_lm_sparse_model_keeps_its_indexer_whole_over_model_and_matches_one_device():
+    """Sparse layers (8 keys of up to 32 a query) on ``data=2 x model=2 x
+    fsdp=2``: the attention's projections split by head over ``model`` as in
+    the other kinds, the indexer's (``wq``, ``wk``, ``weights_proj``) not, so
+    its scores are whole heads; two steps of the LM and indexer losses on the
+    mesh are the steps on one device."""
+    from jax.sharding import PartitionSpec as P
+
+    from perceiver_io_tpu.training.tasks import lm_loss_fn
+
+    model = _lm_sparse_model()
+    init = lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.int32))["params"]
+    axes = dict(data=2, model=2, fsdp=2)
+    specs = infer_param_specs(jax.eval_shape(init), make_mesh(MeshConfig(**axes)), min_fsdp_size=0)
+    layer = specs["layers_0"]
+    assert layer["attention"]["q_proj"]["kernel"][1] == "model"
+    for leaf in ("wq", "wk", "weights_proj"):
+        assert "model" not in tuple(layer["indexer"][leaf]["kernel"]), leaf
+    assert isinstance(layer["indexer"]["k_norm"]["scale"], P)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 64, size=(8, 33)).astype(np.int32)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:], "pad_mask": np.zeros((8, 32), bool)}
+    losses = {}
+    for name, mesh_axes in (("one", dict(data=1)), ("mesh", axes)):
+        devices = jax.devices()[:1] if name == "one" else None
+        mesh = make_mesh(MeshConfig(**mesh_axes), devices=devices)
+        state, shardings = create_train_state(init, optax.adamw(1e-2), mesh, min_fsdp_size=0)
+        step = make_train_step(lm_loss_fn(model), mesh, shardings)
+        out = []
+        for i in range(2):
+            state, metrics = step(state, shard_batch(batch, mesh), jax.random.PRNGKey(i))
+            out.append((float(metrics["loss"]), float(metrics["indexer_loss"])))
+        losses[name] = out
+    assert losses["one"][0][1] > 0.0
+    np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=2e-4)

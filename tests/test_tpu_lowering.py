@@ -582,3 +582,94 @@ def test_forward_at_the_cells_shapes_lowers_as_flash_fwd_on_its_grid(b, h, hk, i
     jaxpr = str(jax.make_jaxpr(call)(q, k, v, pad_mask))
     assert set(re.findall(r"grid=\(([^)]*)\)", jaxpr)) == {f"{b}, {h}, {i // bi}, {steps}"}
     assert jaxpr.count("dot_general") == 2 * 2  # the Mosaic branch and the interpreter's
+
+
+#: sha256 (the first 16 hex digits) of each flash kernel's own Mosaic text,
+#: without debug info, lowered for the TPU at the five cells' shapes: the
+#: forward and the backward of the cells' calls (AR cross-attention with its
+#: pad mask, AR self-attention, the MLM decoder, the three ``lm`` cells' calls,
+#: the SmallThinker window). The kernels' text from before they could take a
+#: selection, the same on both commits: a call without a selection must lower
+#: to exactly these (a change of the installed JAX changes them all).
+CELL_KERNELS = {
+    "ar_cross": ((32, 8, 8, 1024, 4608, 64, 64, None, True, True), ("28362704260d363a", "1ef1552ddbd465e6")),
+    "ar_self": ((32, 8, 8, 1024, 1024, 64, 64, None, False, True), ("f5c92938797012fc", "ba67658608542a68")),
+    "mlm_decoder": ((32, 8, 8, 2048, 256, 32, 96, None, False, False), ("090d1e0e45a46141", "eb423fbc945aee67")),
+    "lfm2moe": ((2, 32, 8, 8192, 8192, 64, 64, None, False, True), ("f0c0439426311b7e", "708d5b3613ce5d6c")),
+    "glm47flash": ((1, 20, 20, 8192, 8192, 256, 256, None, False, True), ("bbebe97608101a7c", "c2cb4d8d22c57db0")),
+    "smallthinker_global": ((1, 28, 4, 16384, 16384, 128, 128, None, False, True),
+                            ("823a8f6088edbfbd", "158173d939c8643b")),
+    "smallthinker_window": ((1, 28, 4, 16384, 16384, 128, 128, 4096, False, True),
+                            ("fd10c845b089cbb8", "8a1ed9ed974c602b")),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_KERNELS))
+def test_a_call_without_a_selection_lowers_to_the_kernels_the_cells_always_ran(cell, monkeypatch):
+    import hashlib
+
+    import jax._src.tpu_custom_call as tpu_custom_call
+
+    (b, h, hk, i, j, d, dv, window, pad, causal), want = CELL_KERNELS[cell]
+    seen, lower = [], tpu_custom_call._lower_mosaic_module_to_asm
+
+    def hashing(module, **kw):
+        text = module.operation.get_asm(enable_debug_info=False)
+        seen.append(hashlib.sha256(text.encode()).hexdigest()[:16])
+        return lower(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", hashing)
+    q = jax.ShapeDtypeStruct((b, h, i, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, hk, j, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, hk, j, dv), jnp.bfloat16)
+    mask = jnp.zeros((b, j), bool) if pad else None
+    loss = lambda q, k, v: jnp.sum(flash_attention.flash_attention(
+        q, k, v, pad_mask=mask, causal=causal, window=window).astype(jnp.float32))
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, v).lower(lowering_platforms=("tpu",))
+    assert tuple(seen) == want
+
+
+@pytest.mark.parametrize("b,h,hk,n,d", [(1, 32, 4, 16384, 128), (2, 4, 2, 256, 64), (1, 8, 8, 1024, 64)],
+                         ids=["keyevl2_cell", "small_grouped_b2", "ungrouped_1024"])
+def test_flash_kernels_with_a_selection_lower_through_mosaic(b, h, hk, n, d):
+    """The selection path's forward and backward lowered for the TPU: the
+    bits come in blocks of ``(bi / 32, bj)`` words and the flags by scalar
+    prefetch; at the cell's shape (32 query heads on 4 of 128, 16,384 rows)
+    the one-kernel backward keeps two heads' dQ a slice, as without a
+    selection."""
+    q = jax.ShapeDtypeStruct((b, h, n, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, hk, n, d), jnp.bfloat16)
+    bits = jax.ShapeDtypeStruct((b, n // 32, n), jnp.int32)
+    assert flash_attention.selection_blocks_fit(n)
+    loss = lambda q, k, v, bits: jnp.sum(
+        flash_attention.flash_attention_selected(q, k, v, bits)[0].astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k, bits).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_fwd"]
+    if n == 16384:
+        assert flash_attention._resident_heads(q, k) == 2
+    # a row block of 128 would give words in blocks of 4 rows: no kernel takes those
+    assert not flash_attention.selection_blocks_fit(384)
+
+
+def test_selected_flash_call_lowers_under_a_mesh_with_heads_over_model(devices):
+    """The selection path through ``selected_attention`` on ``data=2 x
+    model=2``: the call shard_maps itself (batch over ``data`` for q, k, v
+    and the bits, the key-value heads over ``model``) and returns ``(o, lse)``
+    by shard; forward and a one-kernel backward."""
+    from perceiver_io_tpu.ops.attention import selected_attention
+
+    mesh = make_mesh(MeshConfig(data=2, model=2), devices=devices[:4])
+    q = jax.ShapeDtypeStruct((2, 8, 256, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, 2, 256, 64), jnp.bfloat16)
+    bits = jax.ShapeDtypeStruct((2, 8, 256), jnp.int32)
+
+    def loss(q, k, v, bits):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            o, lse = selected_attention(q, k, v, bits, impl="flash")
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(jax.lax.stop_gradient(lse))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k, bits).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["flash_bwd_dkv", "flash_fwd"]
+    assert "tensor<1x4x256x64xbf16>" in text and "tensor<1x8x256xi32>" in text  # a shard's heads and bits
