@@ -85,11 +85,12 @@ def _remat_policy(offload: bool):
     pinned host memory — HBM holds no per-layer activations between forward
     and backward but the kernels' two."""
     from perceiver_io_tpu.ops.flash_attention import SAVED_NAMES
-    from perceiver_io_tpu.ops.sparse_attention import SELECTION_NAME
+    from perceiver_io_tpu.ops.sparse_attention import KL_GRAD_NAMES, SELECTION_NAME
 
     # a sparse layer's packed selection (32 MB at 16,384 positions) is kept
-    # too: its backward does not run the indexer's selection again
-    saved = (*SAVED_NAMES, SELECTION_NAME)
+    # too, and the float32 gradients its indexer loss's kernels form in the
+    # forward (72 MB): its backward runs neither the selection nor the kernels
+    saved = (*SAVED_NAMES, SELECTION_NAME, *KL_GRAD_NAMES)
     if not offload:
         return jax.checkpoint_policies.save_only_these_names(*saved)
     return jax.checkpoint_policies.save_and_offload_only_these_names(

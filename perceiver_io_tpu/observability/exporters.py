@@ -75,6 +75,7 @@ HELP_TEXT = {
     "attention_einsum_fallback_total": "Traced attention shapes that impl='auto' on a TPU left to the einsum path because the flash kernel refused them.",
     "flash_backward_two_call_total": "Traced flash-attention backwards that run as two kernels (flash_bwd_dq beside flash_bwd_dkv) because the float32 dQ of one query head alone is over the one-kernel backward's VMEM budget (16 MiB).",
     "flash_backward_sliced_total": "Traced flash-attention backwards that run as one kernel over slices of a key-value head's query heads, because the whole group's float32 dQ is over the VMEM budget and a divisor of the group is within it: each slice writes its dK and dV in float32 and they are summed outside the kernel. Declared at the first traced flash backward, so a program without such shapes exports 0.",
+    "indexer_kl_kernel_total": "Traced indexer losses of learned sparse attention that ran the KL kernels (indexer_kl, indexer_kl_grad) over the selected causal block pairs; declared by every indexer loss, so a program whose losses ran as XLA's blocked loop exports 0.",
     "flash_window_call_total": "Traced flash-attention forward calls that carried a sliding window (the kernels' grids then walk the band alone); declared at the first flash call, so a program without window layers exports 0.",
     "hbm_bytes_in_use": "Live device memory from memory_stats() (absent on CPU).",
     "kv_cache_resident_bytes": "Live slot-KV bytes: allocated pages + latent-stack caches under the paged layout; equals capacity when dense.",

@@ -10,6 +10,7 @@ scaffolding that talked to the chip through a proxy.)
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 import warnings
@@ -114,6 +115,33 @@ def test_one_kernel_flash_backward_compiles_for_a_v5e_with_the_vmem_it_asks_for(
 
     text = jax.jit(backward).lower(q, k, v, lse, delta, do).compile().as_text()
     assert text.count("tpu_custom_call") == 1 and "flash_bwd_dkv" in text
+
+
+def test_indexer_kl_kernels_compile_for_a_v5e_at_the_keyevl2_cell_in_the_vmem_they_ask_for():
+    """The indexer loss's two kernels, both of which a training step runs in
+    its forward pass, compiled by the real TPU compiler on a described v5e at
+    ``keyevl2-train-16k``'s layer (16,384 rows, 32 query heads on 4 of 128,
+    16 indexer heads of 64): they fit the ``vmem_limit_bytes`` that
+    ``_kl_vmem_bytes`` works out from their shapes, and lower under their own
+    names, none of them ``flash_*``."""
+    pytest.importorskip("libtpu")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from perceiver_io_tpu.ops import sparse_attention
+
+    one_chip = SingleDeviceSharding(topologies.get_topology_desc("v5e:2x2", "tpu").devices[0])
+    n = 16384
+    shapes = [((1, 32, n, 128), jnp.bfloat16), ((1, 4, n, 128), jnp.bfloat16), ((1, 32, n), jnp.float32),
+              ((1, n, 16, 64), jnp.bfloat16), ((1, n, 64), jnp.bfloat16), ((1, n, 16), jnp.float32),
+              ((1, n // 32, n), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=one_chip) for s, dtype in shapes]
+    assert sparse_attention._kl_kernels_fit(*args[:2], args[3])
+    lowered = jax.jit(sparse_attention._kl_fwd).lower(*args)
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', lowered.as_text())) == ["indexer_kl", "indexer_kl_grad"]
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 2 and not re.search(r"%flash_\w*(\.\w+)* = ", text)
+    assert len(re.findall(r"%indexer_kl(_grad)?(\.\w+)* = ", text)) == 2
 
 
 def test_ledger_lets_a_compile_error_raise():

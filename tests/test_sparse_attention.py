@@ -131,16 +131,39 @@ def test_a_block_pair_without_a_selected_key_is_skipped():
     np.testing.assert_allclose(o_f, o_x, atol=1e-5)
 
 
-@pytest.mark.parametrize("n", [256, 80], ids=["256", "80_rows_padded_to_words"])
-def test_indexer_loss_in_blocks_is_the_kl_at_once(n):
-    """The blocked loss against the KL written out whole, and its gradient
-    reaching the indexer's inputs alone; at 80 rows the blocks hold 16 rows
-    of padding, which add nothing."""
+def _block_diagonal_bits(n, rows=512):
+    """Every query keeps itself and the earlier keys of its own block of
+    ``rows``: the block pairs under the diagonal hold no selected key."""
+    at = jnp.arange(n)
+    chosen = (at[None, :] <= at[:, None]) & (at[None, :] // rows == at[:, None] // rows)
+    parts = [sa.pack(chosen[None, r:r + 128], r, rows) for r in range(0, n, 128)]
+    per = rows // 128
+    blocks = [sum(jax.lax.bitcast_convert_type(p, jnp.uint32) for p in parts[i:i + per])
+              for i in range(0, len(parts), per)]
+    return jax.lax.bitcast_convert_type(jnp.concatenate(blocks, axis=1), jnp.int32)
+
+
+@pytest.mark.parametrize("n,topk", [(256, 40), (80, 40), (512, 512), (1024, None)],
+                         ids=["256", "80_rows_padded_to_words", "512_every_causal_key", "1024_empty_block_pair"])
+def test_indexer_loss_in_blocks_is_the_kl_at_once(n, topk):
+    """The loss against the KL written out whole and its gradient reaching
+    the indexer's inputs alone, through the KL kernels (interpreted) where
+    the rows are whole blocks and through XLA's blocked loop, which the
+    kernels' numbers are held to as well, at 80 rows (whose blocks hold 16
+    rows of padding, which add nothing). At 512 rows every causal key is
+    selected; at 1024 the pair under the diagonal holds none (its flag is 0)."""
     b, h, hk, d = 1, 4, 2, 32
     q, k, _, _ = _attention_inputs(n)
     q_i, k_i, w = _indexer(jax.random.PRNGKey(3), b, n)
-    bits = jax.jit(sa.select, static_argnums=3)(q_i, k_i, w, 40)
+    bits = _block_diagonal_bits(n) if topk is None else jax.jit(sa.select, static_argnums=3)(q_i, k_i, w, topk)
     _, lse = sa.attention_xla(q, k, k, bits)
+    kernels = sa._kl_kernels_fit(q, k, q_i)
+    assert kernels == (n != 80)
+    # heads whose row block alone is over the kernels' VMEM cap: XLA's loss
+    wide = jax.ShapeDtypeStruct((b, 128, n, 512), jnp.bfloat16)
+    assert n == 80 or not sa._kl_kernels_fit(wide, wide, q_i)
+    if topk is None:
+        assert np.asarray(flash_attention.block_flags(bits, n)).tolist() == [1, 0, 0, 1]
 
     def whole(q_i, k_i, w, q, k):
         chosen = sa.unpack_all(bits)
@@ -151,11 +174,15 @@ def test_indexer_loss_in_blocks_is_the_kl_at_once(n):
         return jnp.sum(jnp.where(chosen, jax.scipy.special.xlogy(p, p) - p * log_q, 0.0)) / (b * n)
 
     blocked = lambda q_i, k_i, w, q, k: sa.indexer_loss(q, k, lse, q_i, k_i, w, bits)
-    got, g_got = jax.value_and_grad(blocked, argnums=(0, 1, 2, 3, 4))(q_i, k_i, w, q, k)
-    want, g_want = jax.value_and_grad(whole, argnums=(0, 1, 2, 3, 4))(q_i, k_i, w, q, k)
+    in_xla = lambda q_i, k_i, w, q, k: sa.indexer_loss_xla(q, k, lse, q_i, k_i, w, bits)
+    each = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4))(q_i, k_i, w, q, k)
+    (got, g_got), (want, g_want), (xla, g_xla) = jax.jit(
+        lambda: (each(blocked), each(whole), each(in_xla) if kernels else each(blocked)))()
     assert float(got) == pytest.approx(float(want), rel=1e-5) and float(got) > 0.0
-    for a, c in zip(g_got[:3], g_want[:3]):
+    assert float(got) == pytest.approx(float(xla), rel=1e-5)
+    for a, c, x in zip(g_got[:3], g_want[:3], g_xla[:3]):
         np.testing.assert_allclose(a, c, atol=1e-6)
+        np.testing.assert_allclose(a, x, atol=1e-6)
     assert not np.asarray(g_got[3]).any() and not np.asarray(g_got[4]).any()
     # every causal key selected, the log-sum-exp taken from the scores
     dense = sa.indexer_loss(q, k, None, q_i, k_i, w, None)

@@ -652,6 +652,30 @@ def test_flash_kernels_with_a_selection_lower_through_mosaic(b, h, hk, n, d):
     assert not flash_attention.selection_blocks_fit(384)
 
 
+def test_indexer_kl_kernels_lower_under_a_mesh_by_batch_shard(devices):
+    """The indexer loss's KL kernels under ``data=2 x model=2``: the loss
+    shard_maps itself (the batch over ``data``, the attention's heads whole
+    on each device, as every head is in ``p``), and its gradient reaches the
+    indexer's inputs through the two kernels, a shard's row each."""
+    from perceiver_io_tpu.ops import sparse_attention
+
+    mesh = make_mesh(MeshConfig(data=2, model=2), devices=devices[:4])
+    b, n = 2, 256
+    shapes = [((b, 8, n, 64), jnp.bfloat16), ((b, 2, n, 64), jnp.bfloat16), ((b, 8, n), jnp.float32),
+              ((b, n, 4, 64), jnp.bfloat16), ((b, n, 64), jnp.bfloat16), ((b, n, 4), jnp.float32),
+              ((b, n // 32, n), jnp.int32)]
+    q, k, lse, q_i, k_i, w, bits = (jax.ShapeDtypeStruct(s, dtype) for s, dtype in shapes)
+
+    def loss(q_i, k_i, w, q, k, lse, bits):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return sparse_attention.indexer_loss(q, k, lse, q_i, k_i, w, bits)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q_i, k_i, w, q, k, lse, bits).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == ["indexer_kl", "indexer_kl_grad"]
+    assert "tensor<1x8x256x64xbf16>" in text and "tensor<1x8x256xi32>" in text  # a shard's row, every head
+
+
 def test_selected_flash_call_lowers_under_a_mesh_with_heads_over_model(devices):
     """The selection path through ``selected_attention`` on ``data=2 x
     model=2``: the call shard_maps itself (batch over ``data`` for q, k, v
